@@ -1,14 +1,15 @@
 #!/usr/bin/env python
-"""bench_diff — the perf-regression watchdog over the bench ladder.
+"""bench_diff — the perf-regression watchdog over bench.py records.
 
-The repo banks every round's headline bench lines as ``BENCH_r0*.json``
-(``{"parsed": {...}, "tail": ...}`` envelopes whose ``parsed`` record
-is one ``bench.py`` stdout line: metric / value / unit / mfu /
-sec_per_step / device_kind / ...).  Until now those files were an
-archive; this script makes them a GATE: compare a fresh ``bench.py``
-run (or any saved JSONL of its stdout lines) against the banked
-envelope per stage and exit non-zero on any regression beyond the
-tolerance.
+A *bank* is one or more JSON files of ``bench.py`` stdout records
+(metric / value / unit / mfu / sec_per_step / device_kind / ...), raw
+or wrapped in ``{"parsed": {...}}`` envelopes.  This script is the
+GATE over one: compare a fresh ``bench.py`` run (or any saved JSONL of
+its stdout lines) against the bank per metric and exit non-zero on any
+regression beyond the tolerance.  The repo ships no bank of its own —
+name one with ``--banked`` — only the small SYNTHETIC envelope
+``tests/fixtures/bench_envelope.json`` that ``--selftest`` validates
+the comparator against.
 
 Comparison model, per metric name (records are matched by ``metric``
 AND ``device_kind`` — a CPU smoke is never judged against a banked TPU
@@ -38,8 +39,7 @@ Usage::
     python scripts/bench_diff.py --selftest                 # CI self-test
     python scripts/bench_diff.py --fresh - < run.jsonl      # stdin
 
-``--banked`` defaults to the repo's ``BENCH_r0*.json`` set; when
-several banked records share a (metric, device kind), the NEWEST (by
+When several banked records share a (metric, device kind), the NEWEST (by
 in-band ``ts``, falling back to file order) wins — the envelope is the
 latest accepted performance, not the best-ever (hardware sessions
 differ; the newest banked line is the one the current code was
@@ -54,12 +54,15 @@ TPU-only bank without faking numbers).
 """
 
 import argparse
-import glob
 import json
 import os
 import sys
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: the synthetic envelope ``--selftest`` runs over
+SELFTEST_ENVELOPE = os.path.join(REPO_ROOT, "tests", "fixtures",
+                                 "bench_envelope.json")
 
 #: unit substrings that mean lower-is-better for ``value`` — checked
 #: only after the rate forms ("images/sec", "tokens/s") claim
@@ -116,8 +119,8 @@ def value_direction(record):
 
 def iter_records(payload):
     """Yield bench stdout records (dicts with a ``metric`` key) from
-    any of the shapes the repo stores them in: a raw record, a
-    ``BENCH_r0*.json`` envelope (``parsed``), or a list of either."""
+    any of the shapes they are stored in: a raw record, a
+    ``{"parsed": ...}`` envelope, or a list of either."""
     if isinstance(payload, list):
         for item in payload:
             yield from iter_records(item)
@@ -178,13 +181,8 @@ def _bank_lookup(banked, metric, device_kind, ignore_device=False):
 
 def load_fresh(stream):
     """Bench stdout lines (JSONL; non-JSON lines are bench chatter and
-    skipped) → list of records.  Records tagged ``"banked": true``
-    are DROPPED: bench.py re-emits the banked lines verbatim on a
-    dead/degraded session, and gating an echo of the bank against the
-    bank would pass a run that measured nothing (the 'nothing gated'
-    warning exists for exactly that case)."""
+    skipped) → list of records."""
     records = []
-    echoes = 0
     for line in stream:
         line = line.strip()
         if not line or not line.startswith("{"):
@@ -193,15 +191,7 @@ def load_fresh(stream):
             payload = json.loads(line)
         except ValueError:
             continue
-        for record in iter_records(payload):
-            if record.get("banked"):
-                echoes += 1
-                continue
-            records.append(record)
-    if echoes:
-        print("bench_diff: %d banked echo record(s) in the fresh run "
-              "ignored (not live measurements)" % echoes,
-              file=sys.stderr)
+        records.extend(iter_records(payload))
     return records
 
 
@@ -282,7 +272,7 @@ def run_bench(stages=None):
 
 
 def selftest(banked_paths, tolerance):
-    """The CI self-test over the real banked files:
+    """The CI self-test over the synthetic fixture envelope:
 
     1. banked-vs-banked must report ZERO regressions (the gate would
        otherwise fail every honest re-run);
@@ -343,12 +333,13 @@ def selftest(banked_paths, tolerance):
 def main(argv=None):
     parser = argparse.ArgumentParser(
         prog="bench_diff",
-        description="gate a bench.py run against the banked "
-                    "BENCH_r0*.json envelope")
+        description="gate a bench.py run against a bank of "
+                    "earlier bench.py records")
     parser.add_argument("--banked", nargs="*", default=None,
                         metavar="FILE",
-                        help="banked envelope files (default: the "
-                             "repo's BENCH_r0*.json)")
+                        help="banked envelope files (required except "
+                             "for --selftest, which defaults to "
+                             "tests/fixtures/bench_envelope.json)")
     parser.add_argument("--fresh", metavar="FILE",
                         help="a saved bench.py stdout (JSONL); '-' "
                              "reads stdin")
@@ -365,12 +356,14 @@ def main(argv=None):
                              "numbers; you were warned)")
     parser.add_argument("--selftest", action="store_true",
                         help="validate the comparator against the "
-                             "banked files (CI)")
+                             "synthetic fixture envelope (CI)")
     ns = parser.parse_args(argv)
-    banked_paths = ns.banked if ns.banked else sorted(
-        glob.glob(os.path.join(REPO_ROOT, "BENCH_r0*.json")))
     if ns.selftest:
-        return selftest(banked_paths, ns.tolerance)
+        return selftest(ns.banked or [SELFTEST_ENVELOPE], ns.tolerance)
+    if not ns.banked:
+        parser.error("--banked FILE... is required (the repo ships no "
+                     "bank of measurements)")
+    banked_paths = ns.banked
     if ns.run:
         fresh = run_bench(ns.stages)
     elif ns.fresh == "-":

@@ -99,10 +99,10 @@ timeout -k 10 240 env JAX_PLATFORMS=cpu python -m veles_tpu.watch --smoke
 echo "== ops smoke (kernel parity + autotune + zero-recompile gate) =="
 timeout -k 10 240 env JAX_PLATFORMS=cpu python -m veles_tpu.ops --smoke
 # bench_diff self-test: the perf-regression watchdog's comparator
-# validated against the banked BENCH_r0*.json envelope — banked vs
-# banked clean, synthetically degraded copies caught on every field,
-# cross-device lines skipped (the bench ladder is a GATE now, not an
-# archive: gate a fresh run with scripts/bench_diff.py --fresh)
+# validated against the synthetic tests/fixtures/bench_envelope.json —
+# banked vs banked clean, synthetically degraded copies caught on every
+# field, cross-device lines skipped (gate a fresh run against a named
+# bank with scripts/bench_diff.py --banked FILE --fresh RUN)
 echo "== bench_diff self-test (perf-regression watchdog) =="
 python scripts/bench_diff.py --selftest
 # pod smoke: an 8-shard CPU session (one pod = one pjit'd stitched

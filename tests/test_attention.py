@@ -237,7 +237,6 @@ def test_pallas_bwd_under_shard_map():
     shard_map (the transformer's head-sharded _attend wrapper): grads
     via the interpret-mode Pallas path on a 1-axis CPU mesh match
     autodiff of the dense reference."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from veles_tpu.config import root
     from veles_tpu.parallel.mesh import make_mesh
@@ -254,10 +253,10 @@ def test_pallas_bwd_under_shard_map():
         q, k, v)
 
     spec = P(None, None, "model", None)
-    att = shard_map(
+    att = jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, True, 8, 8, True),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
 
     def loss(q, k, v):
         return (att(q, k, v) ** 2).sum()

@@ -45,11 +45,11 @@ def test_cpu_put_get_sync():
     assert numpy.allclose(dev.get(dev_arr), arr)
 
 
-def test_auto_device_picks_best_existing():
+def test_auto_device_is_the_cpu_when_the_process_asked_for_it():
+    # conftest pins jax_platforms="cpu" (what JAX_PLATFORMS=cpu does):
+    # the one case in which "auto" means the CPU
     dev = AutoDevice()
-    # No TPU under the forced-CPU test env → CPU (priority 20) wins
-    # over numpy (priority 10).
-    assert dev.BACKEND in ("tpu", "cpu")
+    assert isinstance(dev, CPUDevice) and dev.exists
 
 
 def test_make_device_by_name():
@@ -58,9 +58,11 @@ def test_make_device_by_name():
         make_device("opencl")
 
 
-def test_tpu_device_absent_under_cpu_env():
-    dev = TPUDevice()
-    assert not dev.exists
+def test_tpu_device_absent_under_cpu_env_raises():
+    """No such platform is an error at construction, not a device
+    object over an empty list that fails later in put()."""
+    with pytest.raises(RuntimeError, match="tpu"):
+        TPUDevice()
 
 
 def test_device_pickle_roundtrip():

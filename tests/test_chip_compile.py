@@ -1,0 +1,245 @@
+"""AOT compiles for a DESCRIBED TPU v5e: what interpret mode cannot show.
+
+Every Pallas kernel ``veles_tpu/ops/`` exports, at the widths the
+``chip_smoke.py`` phases run them, plus the AlexNet fused step and the
+LM decode programs, compiled by the TPU's own compiler for a ``v5e:2x2``
+topology that is described and not attached.  A kernel that passes every
+interpret-mode parity test can still be refused here — a block that breaks
+the (8, 128) rule, a cast Mosaic does not implement, a scratch that
+overflows VMEM — and was, until PR 21 (flash forward/backward, chunk
+attention, the PRNG fill, the u8 gather+normalize).
+
+Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside the module-scoped ``topo`` fixture and
+nowhere else (only one process at a time may load the TPU's library: a
+call at import would make xdist workers collect different tests), every
+compile happens in this test's own process, and this stays ONE file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - any failure means "skip"
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % exc)
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", saved)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` -> a ShapeDtypeStruct on the first described
+    chip; ``chip.tree(shapes)`` places a whole pytree of structs."""
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+    struct.tree = lambda tree: jax.tree.map(
+        lambda leaf: struct(leaf.shape, leaf.dtype), tree)
+    return struct
+
+
+# -- the cases: name -> (build(chip) -> Lowered, expects a Pallas kernel) ----
+
+def _flash_fwd(d, chip):
+    from veles_tpu.ops import attention
+    q = chip((8, 2048, 8, d), bf16)
+    return jax.jit(lambda q, k, v: attention._flash_fwd(
+        q, k, v, causal=True)).lower(q, q, q)
+
+
+def _flash_bwd(d, chip):
+    from veles_tpu.ops.attention import flash_attention
+    q = chip((8, 2048, 8, d), bf16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, None,
+                               True).astype(f32).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+
+
+def _chunk_attention(chip):
+    from veles_tpu.ops.attention import chunk_attention
+    q, kv = chip((1, 256, 16, 64), bf16), chip((1, 2048, 16, 64), bf16)
+    return jax.jit(lambda q, k, v, start: chunk_attention(
+        q, k, v, start, use_pallas=True, interpret=False)).lower(
+            q, kv, kv, chip((), i32))      # start is TRACED
+
+
+def _decode(rows, chip):
+    from veles_tpu.ops import attention
+    fn = attention.decode_attention if rows == 1 \
+        else attention.verify_attention
+    q, kv = chip((8, rows, 16, 64), bf16), chip((8, 2048, 16, 64), bf16)
+    return jax.jit(lambda q, k, v, lengths: fn(
+        q, k, v, lengths, use_pallas=True, interpret=False)).lower(
+            q, kv, kv, chip((8,), i32))
+
+
+def _paged_decode(chip):
+    from veles_tpu.ops.attention import paged_decode_attention
+    q, pool = chip((8, 1, 16, 64), bf16), chip((1025, 16, 16, 64), bf16)
+    return jax.jit(lambda q, k, v, tables, lengths: paged_decode_attention(
+        q, k, v, tables, lengths, use_pallas=True, interpret=False)).lower(
+            q, pool, pool, chip((8, 128), i32), chip((8,), i32))
+
+
+def _gemm(chip):
+    from veles_tpu.ops.gemm import _matmul_pallas
+    a = chip((4096, 4096), bf16)
+    return _matmul_pallas.lower(a, a, None)
+
+
+def _gemm_int8(chip):
+    from veles_tpu.ops.qgemm import _qmatmul_pallas
+    return _qmatmul_pallas.lower(
+        chip((256, 4096), bf16), chip((4096, 4096), jnp.int8),
+        chip((4096,), f32), None)
+
+
+def _gd_fused(batch, fan_in, neurons, chip):
+    from veles_tpu.ops.gemm import gd_fused_pallas
+    x, y = chip((batch, fan_in), f32), chip((batch, neurons), f32)
+    w, b = chip((fan_in, neurons), f32), chip((neurons,), f32)
+
+    def fn(x, y, err, w, b, vw, vb):
+        return gd_fused_pallas(x, y, err, w, b, vw, vb, 0.03, 0.03, 5e-4,
+                               0.0, 0.9, 0.9, activation="tanh")
+
+    return jax.jit(fn).lower(x, y, y, w, b, w, b)
+
+
+def _gather(chip):
+    from veles_tpu.ops.gather import _gather_pallas
+    return _gather_pallas.lower(chip((60000, 784), jnp.uint8),
+                                chip((100,), i32))
+
+
+def _gather_norm(chip):
+    from veles_tpu.ops.gather import _gather_norm_pallas
+    row = chip((1, 784), f32)
+    return _gather_norm_pallas.lower(chip((60000, 784), jnp.uint8),
+                                     chip((100,), i32), row, row)
+
+
+def _prng_fill(chip):
+    from veles_tpu.ops.random import _uniform_pallas_tpu
+    return _uniform_pallas_tpu.lower(chip((), i32), shape=(4096, 4096))
+
+
+def _alexnet_step(chip):
+    """The fused AlexNet train step chip_smoke.py's ``alexnet_train``
+    runs: batch 256, u8 input normalized in-step, bf16 compute, f32
+    master weights.  The chip's ratings DB disables the space-to-depth
+    conv rewrite; the CPU this test runs on has no DB row and would
+    take the heuristic's other branch, so the test pins the chip's."""
+    from veles_tpu.config import root
+    from veles_tpu.samples import alexnet
+    from veles_tpu.znicz.fused_graph import lower_specs
+    saved = root.common.engine.get("s2d_conv", "auto")
+    root.common.engine.s2d_conv = False
+    try:
+        params, step_fn, _eval, _apply = lower_specs(
+            alexnet.LAYERS, alexnet.INPUT_SHAPE, compute_dtype=bf16,
+            input_norm=(numpy.float32(1.0 / 255.0), numpy.float32(0.0)))
+        return jax.jit(step_fn, donate_argnums=(0,)).lower(
+            chip.tree(jax.tree.map(jnp.asarray, params)),
+            chip((256,) + tuple(alexnet.INPUT_SHAPE), jnp.uint8),
+            chip((256,), i32))
+    finally:
+        root.common.engine.s2d_conv = saved
+
+
+def _lm_decode(paged, chip):
+    """``samples.transformer.CONFIG``'s ONE decode-step program as the
+    GenerativeEngine compiles it (8 slots, 2048 positions, bf16, cache
+    donated), from ``jax.eval_shape`` shapes — no weights exist."""
+    from veles_tpu.gen import TransformerGenModel
+    from veles_tpu.samples import transformer
+    # on the chip auto dispatch takes the Pallas decode kernels; here
+    # jax.devices() is the CPU, so the test asks for them by name
+    model = TransformerGenModel(transformer.CONFIG, compute_dtype=bf16,
+                                use_pallas=True)
+    params = chip.tree(transformer.param_shapes(transformer.CONFIG, bf16))
+    slots = chip((8,), i32)
+    active = chip((8,), jnp.bool_)
+    if paged:
+        cache = chip.tree(jax.eval_shape(functools.partial(
+            model.init_paged_cache, 8 * 128 + 1, 16)))
+        args = (params, cache, chip((8, 128), i32), slots, slots, active)
+        fn = model.paged_decode
+    else:
+        cache = chip.tree(jax.eval_shape(functools.partial(
+            model.init_cache, 8, 2048)))
+        args = (params, cache, slots, slots, active)
+        fn = model.decode
+    return jax.jit(fn, donate_argnums=(1,)).lower(*args)
+
+
+CASES = {
+    "flash_fwd_d64": functools.partial(_flash_fwd, 64),
+    "flash_fwd_d128": functools.partial(_flash_fwd, 128),
+    "flash_bwd_d64": functools.partial(_flash_bwd, 64),
+    "flash_bwd_d128": functools.partial(_flash_bwd, 128),
+    "chunk_attention_traced_start": _chunk_attention,
+    "decode_attention": functools.partial(_decode, 1),
+    "verify_attention_5_rows": functools.partial(_decode, 5),
+    "paged_decode_attention_block16": _paged_decode,
+    "gemm_4096_bf16": _gemm,
+    "gemm_int8": _gemm_int8,
+    "gd_fused_4096x4096_f32": functools.partial(_gd_fused, 256, 4096,
+                                                4096),
+    "gd_fused_mnist_784x100": functools.partial(_gd_fused, 100, 784, 100),
+    "gather_dma_60000x784": _gather,
+    "gather_norm_u8_60000x784": _gather_norm,
+    "prng_fill": _prng_fill,
+    "lm_config_decode_step": functools.partial(_lm_decode, False),
+    "lm_config_paged_decode_step": functools.partial(_lm_decode, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_pallas_kernel_compiles_for_v5e(case, chip):
+    compiled = CASES[case](chip).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "%s compiled for the v5e without a Pallas kernel in it" % case
+
+
+def test_alexnet_fused_step_compiles_for_v5e_and_fits(chip, topo):
+    """No Pallas kernel rides this program (conv and fc are XLA's): the
+    claim is that the whole batch-256 bf16 step compiles for one v5e
+    and fits its HBM."""
+    from veles_tpu.backends import device_hbm_bytes
+    compiled = _alexnet_step(chip).compile()
+    hbm = device_hbm_bytes(topo.devices[0].device_kind)
+    assert hbm == 16 << 30, \
+        "device kind %r must resolve to the v5e row" \
+        % topo.devices[0].device_kind
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < used < hbm, "AlexNet step needs %d bytes of %d" % (used,
+                                                                  hbm)

@@ -89,12 +89,22 @@ def test_mesh_from_topology_typed_errors():
         mesh_from_topology("banana")
 
 
-def test_mesh_from_topology_single_device_fallback():
+@pytest.mark.parametrize("topology", [None, "auto", 1, {"data": -1}])
+def test_mesh_from_topology_one_device_runs_auto_and_one(topology):
     import jax
-    one = jax.devices()[:1]
-    mesh = mesh_from_topology({"data": 8}, devices=one)
-    assert mesh.shape == {"data": 1}, \
-        "one device must fall back transparently, whatever the knob"
+    mesh = mesh_from_topology(topology, devices=jax.devices()[:1],
+                              require=("data", "model"))
+    assert mesh.shape == {"data": 1, "model": 1}
+
+
+@pytest.mark.parametrize("topology", [{"data": 8}, 4, "2x2",
+                                      {"data": -1, "model": 2}])
+def test_mesh_from_topology_one_device_refuses_more(topology):
+    """Asking for N devices with one attached is an error, never a
+    silent 1-device mesh on the first chip."""
+    import jax
+    with pytest.raises(MeshTopologyError, match="1 is attached"):
+        mesh_from_topology(topology, devices=jax.devices()[:1])
 
 
 # -- Vector shardings --------------------------------------------------------
@@ -453,8 +463,7 @@ def test_inference_engine_mesh_parity_and_fallback():
                                   rtol=0, atol=1e-5)
     # single-device fallback: no pjit wrapper at all
     import jax
-    one_mesh = mesh_from_topology({"data": 8},
-                                  devices=jax.devices()[:1])
+    one_mesh = mesh_from_topology("auto", devices=jax.devices()[:1])
     fallback = InferenceEngine.from_workflow(
         wf, max_batch_size=8, mesh=one_mesh)
     assert fallback.mesh is None

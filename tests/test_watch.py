@@ -812,7 +812,7 @@ def _bench_diff():
 
 def test_bench_diff_gate_pass_and_regress(tmp_path, capsys):
     bd = _bench_diff()
-    banked_path = str(tmp_path / "BENCH_r01.json")
+    banked_path = str(tmp_path / "bank.json")
     with open(banked_path, "w") as fout:
         json.dump({"parsed": {
             "metric": "m1", "value": 1000.0, "unit": "images/sec",
@@ -862,11 +862,16 @@ def test_bench_diff_device_kind_and_direction_rules(tmp_path):
     assert compared == 1 and len(regs) == 1
 
 
-def test_bench_diff_selftest_on_real_banked_files():
-    """The CI self-test must hold against the repo's committed
-    BENCH_r0*.json set."""
+def test_bench_diff_selftest_on_the_synthetic_fixture(capsys):
+    """The CI self-test runs over tests/fixtures/bench_envelope.json (a
+    synthetic envelope — the repo ships no bank of measurements), and
+    gating a run without naming a bank is a usage error."""
     bd = _bench_diff()
     assert bd.main(["--selftest"]) == 0
+    assert "3 banked envelope line(s)" in capsys.readouterr().out
+    with pytest.raises(SystemExit) as exc:
+        bd.main(["--fresh", "-"])
+    assert exc.value.code == 2
 
 
 def test_bench_diff_newest_banked_record_wins_per_device(tmp_path):

@@ -35,7 +35,8 @@ RULES = {
 KNOB_REGISTRY = {
     # engine — compilation / execution core
     "root.common.engine.backend":
-        "preferred JAX platform (tpu | gpu | cpu) for AutoDevice",
+        "device backend (auto | tpu | cpu | numpy); auto = the TPU or "
+        "an error, the CPU only under JAX_PLATFORMS=cpu",
     "root.common.engine.interpret":
         "run units interpreted (NumpyDevice semantics) instead of jit",
     "root.common.engine.trace":
@@ -73,8 +74,6 @@ KNOB_REGISTRY = {
         "use the Pallas GEMM kernel where shapes allow (on | off)",
     "root.common.engine.pallas_gather":
         "use the Pallas gather kernel for embedding lookups",
-    "root.common.engine.pallas_reduce":
-        "use the Pallas fused-reduce kernel for norms/softmax",
     "root.common.engine.s2d_conv":
         "space-to-depth conv input transform (on | off)",
     "root.common.engine.seed":

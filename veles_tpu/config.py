@@ -134,7 +134,8 @@ def _default_dirs():
 root.common.update({
     "dirs": _default_dirs(),
     "engine": {
-        # "tpu" | "cpu" | "numpy"; AutoDevice resolves by PRIORITY.
+        # "auto" | "tpu" | "cpu" | "numpy"; auto = the TPU or an error
+        # (the CPU only under JAX_PLATFORMS=cpu).
         "backend": "auto",
         # Compute dtype for operands: "float32" or "bfloat16" (MXU-native).
         "precision_type": "float32",

@@ -32,6 +32,8 @@ class _EnsembleBase(Logger):
         self.evaluate = evaluate   # in-process hook (tests/embedding)
         #: CLI args every member inherits (-d, --fused, overrides)
         self.extra_args = tuple(extra_args)
+        #: member runs that exited non-zero
+        self.child_failures = 0
 
     def _spawn(self, overrides, extra_args=()):
         """One child training/testing run; returns its results dict
@@ -48,11 +50,16 @@ class _EnsembleBase(Logger):
             cmd += list(extra_args)
             cmd += ["%s=%s" % (path, json.dumps(value))
                     for path, value in overrides.items()]
+            from veles_tpu.backends import assert_backend_untouched
+            assert_backend_untouched("ensemble member run")
             self.info("spawning: %s", " ".join(cmd))
             proc = subprocess.run(cmd, capture_output=True, text=True)
             if proc.returncode != 0:
-                self.warning("member failed (rc=%d): %s",
-                             proc.returncode, proc.stderr[-2000:])
+                # the member is lost AND the run's exit code says so
+                # at the end (Main._run_ensemble)
+                self.child_failures += 1
+                self.error("member failed (rc=%d): %s",
+                           proc.returncode, proc.stderr[-2000:])
                 return None
             with open(result_path, "r") as fin:
                 return json.load(fin)

@@ -99,6 +99,8 @@ class GeneticsOptimizer(Logger):
         self.population.chromosomes[0].genes[:] = \
             tune.default_genome(self.tuneables)
         self._inflight = {}   # slave_id → chromosome (distributed mode)
+        #: child runs that exited non-zero (standalone subprocess mode)
+        self.child_failures = 0
 
     # -- shared -------------------------------------------------------------
     def overrides_for(self, chromo):
@@ -135,11 +137,16 @@ class GeneticsOptimizer(Logger):
             cmd += list(self.extra_args)
             cmd += ["%s=%s" % (path, json.dumps(value))
                     for path, value in overrides.items()]
+            from veles_tpu.backends import assert_backend_untouched
+            assert_backend_untouched("genetics child run")
             self.info("spawning: %s", " ".join(cmd))
             proc = subprocess.run(cmd, capture_output=True, text=True)
             if proc.returncode != 0:
-                self.warning("child failed (rc=%d): %s", proc.returncode,
-                             proc.stderr[-2000:])
+                # the candidate fails (-inf) AND the run's exit code
+                # says so at the end (Main._run_optimization)
+                self.child_failures += 1
+                self.error("child failed (rc=%d): %s", proc.returncode,
+                           proc.stderr[-2000:])
                 return float("-inf")
             with open(result_path, "r") as fin:
                 results = json.load(fin)

@@ -302,8 +302,15 @@ class Launcher(Logger, metaclass=CommandLineArgumentsRegistry):
                             [p.returncode for p in self._spawned_]))
         finally:
             self._server.print_stats()
+            # reap BEFORE the server goes away: a bootstrapped slave
+            # that is still starting up must hear "no more jobs" and
+            # exit 0, not time out against a vanished master
+            failed = self._reap_spawned()
             self._server.stop()
-            self._reap_spawned()
+        if failed:
+            raise RuntimeError(
+                "%d bootstrapped slave(s) exited non-zero (rc=%r)"
+                % (len(failed), failed))
 
     # -- remote bootstrap (ref launch_remote_progs launcher.py:617-660) -----
     def _master_endpoint(self):
@@ -352,6 +359,8 @@ class Launcher(Logger, metaclass=CommandLineArgumentsRegistry):
         return shlex.join(out)
 
     def _spawn_remote_slaves(self):
+        from veles_tpu.backends import assert_backend_untouched
+        assert_backend_untouched("master's slave bootstrap")
         cmd = self._build_slave_command()
         for nhost, nport, count in parse_nodes(self.nodes):
             prefix = shlex.split(self.slave_launch_transform
@@ -363,9 +372,14 @@ class Launcher(Logger, metaclass=CommandLineArgumentsRegistry):
                 # would pass it to the remote shell
                 self._spawned_.append(subprocess.Popen(prefix + [cmd]))
 
-    def _reap_spawned(self, timeout=10.0):
+    def _reap_spawned(self, timeout=30.0):
+        """Wait for (then terminate, then kill) every spawned slave;
+        returns the exit codes of those that FAILED on their own — a
+        slave this cleanup had to terminate after the run is not a
+        failed child."""
         deadline = time.time() + timeout
-        for proc in self._spawned_:
+        spawned, self._spawned_ = self._spawned_, []
+        for proc in spawned:
             try:
                 proc.wait(max(0.1, deadline - time.time()))
                 continue
@@ -380,7 +394,8 @@ class Launcher(Logger, metaclass=CommandLineArgumentsRegistry):
                              "killing", proc.pid)
                 proc.kill()
                 proc.wait(2.0)
-        self._spawned_ = []
+        return [proc.returncode for proc in spawned
+                if proc.returncode and proc.returncode > 0]
 
     def _run_slave(self):
         from veles_tpu.parallel.jobs import JobClient

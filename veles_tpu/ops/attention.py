@@ -30,12 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-#: jax renamed TPUCompilerParams -> CompilerParams across releases;
-#: the decode path resolves whichever this jax ships via the ONE
-#: shared shim (the training kernels above predate the rename and
-#: keep the new-name spelling)
-from veles_tpu.ops.util import COMPILER_PARAMS as _COMPILER_PARAMS
-
 
 def _round_up(x, mult):
     return (x + mult - 1) // mult * mult
@@ -53,6 +47,17 @@ def _bhsd(x, b, h, d, block):
     d_pad = _round_up(d, 128)
     return jnp.pad(x, ((0, 0), (0, s_pad - x.shape[1]),
                        (0, d_pad - d)))
+
+
+def _row_stat_spec(bq, index_map):
+    """Block of a per-query-row statistic (``lse``, ``delta``): the
+    arrays ride as ``(b·h, s_pad, 1)`` COLUMNS so a ``(1, bq, 1)`` block
+    obeys Mosaic's (8, 128) rule — bq is a sublane multiple and the
+    lane dim equals the array's — and lands in VMEM in the same
+    ``(bq, 1)`` layout as the running ``m``/``l`` scratch, broadcasting
+    against ``(bq, bk)`` scores with no relayout.  (A ``(1, bq)`` block
+    over ``(b·h, s_pad)`` is refused by the chip's compiler.)"""
+    return pl.BlockSpec((1, bq, 1), index_map)
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -109,7 +114,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[...] + jnp.log(l))[:, 0]
+        lse_ref[0] = m_ref[...] + jnp.log(l)           # (bq, 1)
 
 
 def _attn_kernel_dyn(offs_ref, *args, kernel, **kw):
@@ -158,18 +163,18 @@ def _flash_fwd(q, k, v, causal=False, block_q=128, block_k=128,
     ]
     out_specs = [
         pl.BlockSpec((1, bq, d_p), lambda bh, qi, kk: (bh, qi, 0)),
-        pl.BlockSpec((1, bq), lambda bh, qi, kk: (bh, qi)),
+        _row_stat_spec(bq, lambda bh, qi, kk: (bh, qi, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
-        jax.ShapeDtypeStruct((b * h, sq_p), jnp.float32),
+        jax.ShapeDtypeStruct((b * h, sq_p, 1), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((bq, d_p), jnp.float32),
         pltpu.VMEM((bq, 1), jnp.float32),
         pltpu.VMEM((bq, 1), jnp.float32),
     ]
-    params = _COMPILER_PARAMS(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     kw = dict(n_k=n_k, scale=scale, causal=causal, block_q=bq,
               block_k=bk, seq_k=sk)
@@ -196,7 +201,7 @@ def _flash_fwd(q, k, v, causal=False, block_q=128, block_k=128,
             interpret=interpret,
         )(offs, q3, k3, v3)
     out = out[:, :sq, :d].reshape(b, h, sq, d)
-    return jnp.moveaxis(out, 1, 2), lse[:, :sq].reshape(b, h, sq)
+    return jnp.moveaxis(out, 1, 2), lse[:, :sq, 0].reshape(b, h, sq)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -231,11 +236,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, scores.shape, 0)
             mask = mask & (k_off + k_pos <= q_off + q_pos)
-        p = jnp.where(mask, jnp.exp(scores - lse_ref[0][:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(scores - lse_ref[0]), 0.0)
         dp = jax.lax.dot_general(
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bq, bk)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         acc_ref[...] += jax.lax.dot_general(
             ds.astype(q.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bq, d)
@@ -277,7 +282,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q_pos = qj * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, scores.shape, 0)
             mask = mask & (k_off + k_pos <= q_off + q_pos)
-        p = jnp.where(mask, jnp.exp(scores - lse_ref[0][:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(scores - lse_ref[0]), 0.0)
         p_mm = p.astype(q.dtype)
         dv_acc[...] += jax.lax.dot_general(
             p_mm, do, (((0,), (0,)), ((), ())),
@@ -285,7 +290,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(
             do, v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bq, bk)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bk, d)
@@ -313,10 +318,10 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
     bq = min(block_q, _round_up(sq, 8))
     bk = min(block_k, _round_up(sk, 8))
 
-    def bhs(x, block):    # (b, h, s) → (b·h, s_pad)
-        x = x.reshape(b * h, x.shape[2]).astype(jnp.float32)
+    def bhs(x, block):    # (b, h, s) → (b·h, s_pad, 1) row-stat columns
+        x = x.reshape(b * h, x.shape[2], 1).astype(jnp.float32)
         s_pad = _round_up(x.shape[1], block)
-        return jnp.pad(x, ((0, 0), (0, s_pad - x.shape[1])))
+        return jnp.pad(x, ((0, 0), (0, s_pad - x.shape[1]), (0, 0)))
 
     # delta = rowsum(do ⊙ o): one cheap bandwidth-bound pass outside
     # the kernels (the standard flash-backward preprocessing); ring
@@ -338,8 +343,8 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
         pl.BlockSpec((1, bk, d_p), lambda bh, qi, kk: (bh, kk, 0)),
         pl.BlockSpec((1, bk, d_p), lambda bh, qi, kk: (bh, kk, 0)),
         pl.BlockSpec((1, bq, d_p), lambda bh, qi, kk: (bh, qi, 0)),
-        pl.BlockSpec((1, bq), lambda bh, qi, kk: (bh, qi)),
-        pl.BlockSpec((1, bq), lambda bh, qi, kk: (bh, qi)),
+        _row_stat_spec(bq, lambda bh, qi, kk: (bh, qi, 0)),
+        _row_stat_spec(bq, lambda bh, qi, kk: (bh, qi, 0)),
     ]
     dq_out_spec = pl.BlockSpec((1, bq, d_p),
                                lambda bh, qi, kk: (bh, qi, 0))
@@ -350,8 +355,8 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
         pl.BlockSpec((1, bk, d_p), lambda bh, kk, qj: (bh, kk, 0)),
         pl.BlockSpec((1, bk, d_p), lambda bh, kk, qj: (bh, kk, 0)),
         pl.BlockSpec((1, bq, d_p), lambda bh, kk, qj: (bh, qj, 0)),
-        pl.BlockSpec((1, bq), lambda bh, kk, qj: (bh, qj)),
-        pl.BlockSpec((1, bq), lambda bh, kk, qj: (bh, qj)),
+        _row_stat_spec(bq, lambda bh, kk, qj: (bh, qj, 0)),
+        _row_stat_spec(bq, lambda bh, kk, qj: (bh, qj, 0)),
     ]
     dkv_out_specs = [
         pl.BlockSpec((1, bk, d_p), lambda bh, kk, qj: (bh, kk, 0)),
@@ -363,7 +368,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
     ]
     dkv_scratch = [pltpu.VMEM((bk, d_p), jnp.float32),
                    pltpu.VMEM((bk, d_p), jnp.float32)]
-    params = _COMPILER_PARAMS(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     dq_kw = dict(n_k=n_k, scale=scale, causal=causal, block_q=bq,
                  block_k=bk, seq_k=sk)
@@ -531,7 +536,7 @@ def _decode_pallas(q, k, v, lengths, block_k=128, interpret=False,
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_spec, scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((b * h, 8, d_p), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(lengths, jnp.int32), q3, k3, v3)
@@ -696,7 +701,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, tables, lengths,
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
             out_specs=out_spec, scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((b * h, 8, d_p), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(lengths, jnp.int32),
@@ -962,18 +967,13 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
 
 
 def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    from veles_tpu.ops import on_tpu
+    return on_tpu()
 
 
 def _db_choice(dtype, shape=None, kernel="flash_attention"):
-    try:
-        from veles_tpu.ops.benchmark import gemm_choice
-        return gemm_choice(dtype, kernel=kernel, shape=shape)
-    except Exception:
-        return None
+    from veles_tpu.ops.benchmark import gemm_choice   # deferred: cycle
+    return gemm_choice(dtype, kernel=kernel, shape=shape)
 
 
 def _resolve_blocks(block_q, block_k, dtype, shape=None):

@@ -21,56 +21,47 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _use_pallas(data, use_pallas):
+    """Resolve the gather backend.  Priority: explicit ``use_pallas``
+    arg > ``root.common.engine.pallas_gather`` (True/False force; a
+    config force also honors ``engine.interpret`` so CPU tests can pin
+    the in-scan composition through the Pallas interpreter) > the
+    device DB's measured A/B (``autotune_gather``) on the TPU > XLA.
+
+    Whatever this resolves to RUNS: a kernel the chip's compiler
+    refuses raises at the compile of the enclosing program instead of
+    being swapped for the XLA gather behind the caller's back."""
+    if data.ndim < 2:
+        return False
+    if use_pallas is not None:
+        return bool(use_pallas)
+    from veles_tpu.config import root   # deferred: import cycle
+    from veles_tpu.ops import on_tpu
+    forced = root.common.engine.get("pallas_gather", None)
+    if isinstance(forced, bool):
+        interp = bool(root.common.engine.get("interpret", False))
+        return forced and (on_tpu() or interp)
+    from veles_tpu.ops.benchmark import gather_choice
+    # the verdict only transfers to the ROW SIZE it was measured at:
+    # the kernel's win is not generic
+    measured = gather_choice(str(jnp.dtype(data.dtype)),
+                             row_elems=int(numpy.prod(data.shape[1:])))
+    return bool(measured) and on_tpu()
+
+
+def _interpret():
+    from veles_tpu.config import root   # deferred: import cycle
+    return bool(root.common.engine.get("interpret", False))
+
+
 def take_rows(data, indices, use_pallas=None):
     """``data[indices]`` along axis 0.  Negative indices (the reference's
-    "empty slot" marker for short batches) produce zero rows.
-
-    Backend dispatch (when ``use_pallas`` is None):
-    ``root.common.engine.pallas_gather`` (True/False force) → the
-    device DB's measured A/B (``autotune_gather``) → the XLA path.
-    The compiled Pallas DMA kernel runs on TPU only; a config FORCE
-    additionally honors ``engine.interpret`` so CPU tests can pin the
-    in-scan composition through the Pallas interpreter."""
-    from veles_tpu.config import root   # deferred: import cycle
-    auto = use_pallas is None
-    if auto:
-        from veles_tpu.ops import on_tpu
-        forced = root.common.engine.get("pallas_gather", None)
-        if isinstance(forced, bool):
-            # a forced kernel also honors interpret mode (the Pallas
-            # interpreter runs on any backend — how CPU tests pin the
-            # in-scan composition the TPU path executes)
-            interp = bool(root.common.engine.get("interpret", False))
-            use_pallas = forced and (on_tpu() or interp)
-            auto = False          # explicit config force: never mask
-        else:
-            from veles_tpu.ops.benchmark import gather_choice
-            f = int(numpy.prod(data.shape[1:])) if data.ndim >= 2 \
-                else None
-            # the verdict only transfers to the ROW SIZE it was
-            # measured at: the kernel's shape support (and its win)
-            # is not generic, and a Mosaic rejection of an unmeasured
-            # shape would surface at COMPILE time of the enclosing
-            # program, far from any fallback
-            measured = gather_choice(str(jnp.dtype(data.dtype)),
-                                     row_elems=f)
-            use_pallas = bool(measured) and on_tpu()
-    key = (data.shape[1:], str(jnp.dtype(data.dtype)))
-    if use_pallas and data.ndim >= 2 \
-            and (not auto or key not in _PALLAS_REJECTED):
-        try:
-            flat = data.reshape(data.shape[0], -1)
-            out = _gather_pallas(
-                flat, indices,
-                interpret=bool(root.common.engine.get("interpret",
-                                                      False)))
-            return out.reshape((indices.shape[0],) + data.shape[1:])
-        except Exception:
-            if not auto:
-                raise     # forced callers want the kernel error
-            # auto-dispatch degrades to XLA, negative-cached per
-            # (row shape, dtype) so the retry cost is paid once
-            _PALLAS_REJECTED.add(key)
+    "empty slot" marker for short batches) produce zero rows.  Backend
+    dispatch: :func:`_use_pallas`."""
+    if _use_pallas(data, use_pallas):
+        out = _gather_pallas(data.reshape(data.shape[0], -1), indices,
+                             interpret=_interpret())
+        return out.reshape((indices.shape[0],) + data.shape[1:])
     return _gather_jnp(data, indices)
 
 
@@ -89,39 +80,14 @@ def take_rows_norm(data, indices, norm, use_pallas=None):
     flat per-feature arrays.  Dispatch mirrors :func:`take_rows` (the
     gather A/B verdict transfers: the epilogue adds two VPU ops to a
     DMA-bound kernel)."""
-    from veles_tpu.config import root   # deferred: import cycle
     scale, shift = norm
-    auto = use_pallas is None
-    if auto:
-        from veles_tpu.ops import on_tpu
-        forced = root.common.engine.get("pallas_gather", None)
-        if isinstance(forced, bool):
-            interp = bool(root.common.engine.get("interpret", False))
-            use_pallas = forced and (on_tpu() or interp)
-            auto = False
-        else:
-            from veles_tpu.ops.benchmark import gather_choice
-            f = int(numpy.prod(data.shape[1:])) if data.ndim >= 2 \
-                else None
-            measured = gather_choice(str(jnp.dtype(data.dtype)),
-                                     row_elems=f)
-            use_pallas = bool(measured) and on_tpu()
-    key = ("norm", data.shape[1:], str(jnp.dtype(data.dtype)))
-    if use_pallas and data.ndim >= 2 \
-            and (not auto or key not in _PALLAS_REJECTED):
-        try:
-            flat = data.reshape(data.shape[0], -1)
-            f = flat.shape[1]
-            out = _gather_norm_pallas(
-                flat, indices,
-                _norm_row(scale, f), _norm_row(shift, f),
-                interpret=bool(root.common.engine.get("interpret",
-                                                      False)))
-            return out.reshape((indices.shape[0],) + data.shape[1:])
-        except Exception:
-            if not auto:
-                raise
-            _PALLAS_REJECTED.add(key)
+    if _use_pallas(data, use_pallas):
+        flat = data.reshape(data.shape[0], -1)
+        f = flat.shape[1]
+        out = _gather_norm_pallas(
+            flat, indices, _norm_row(scale, f), _norm_row(shift, f),
+            interpret=_interpret())
+        return out.reshape((indices.shape[0],) + data.shape[1:])
     return _gather_norm_jnp(data, indices,
                             jnp.asarray(scale, jnp.float32),
                             jnp.asarray(shift, jnp.float32))
@@ -149,7 +115,11 @@ def _gather_norm_kernel(idx_ref, data_ref, scale_ref, shift_ref, o_ref):
 
     @pl.when(valid)
     def _copy():
-        o_ref[:] = (data_ref[:].astype(jnp.float32)
+        x = data_ref[:]
+        if jnp.issubdtype(x.dtype, jnp.integer):
+            # Mosaic has no direct uint8 -> float32 cast; widen first
+            x = x.astype(jnp.int32)
+        o_ref[:] = (x.astype(jnp.float32)
                     * scale_ref[:].reshape(1, 1, -1)
                     + shift_ref[:].reshape(1, 1, -1))
 
@@ -186,16 +156,10 @@ def _gather_norm_pallas(data, indices, scale, shift, interpret=False):
     return out.reshape(b, f)
 
 
-#: (row shape, dtype) pairs the Pallas kernel rejected at trace time
-#: this process (auto-dispatch only; forced callers see the error)
-_PALLAS_REJECTED = set()
-
-
 @jax.jit
 def _gather_jnp(data, indices):
-    # jitted: the eager form is 3 separate op dispatches per minibatch,
-    # which a high-latency transport (tunneled PJRT) pays 3 round trips
-    # for; one compiled program per (shape, dtype) serves every batch
+    # jitted: the eager form is 3 separate op dispatches per minibatch;
+    # one compiled program per (shape, dtype) serves every batch
     taken = jnp.take(data, jnp.maximum(indices, 0), axis=0)
     mask = (indices >= 0).reshape((-1,) + (1,) * (data.ndim - 1))
     return jnp.where(mask, taken, 0)

@@ -74,7 +74,6 @@ def _matmul_kernel(a_ref, b_ref, bias_ref, o_ref, acc_ref, *, n_k,
         o_ref[:] = acc.astype(o_ref.dtype)
 
 
-from veles_tpu.ops.util import COMPILER_PARAMS as _COMPILER_PARAMS
 from veles_tpu.ops.util import pad_axis as _pad_to_impl, round_up
 
 
@@ -114,7 +113,7 @@ def _matmul_pallas(a, b, bias, activation=None, tiles=None, out_dtype=None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_p, b_p, bias_p)
@@ -403,7 +402,7 @@ def gd_fused_pallas(x, y, err_output, w, b, vw, vb, lr, lr_bias, decay,
             out_specs=pl.BlockSpec((bk, bf), lambda i, j, kk: (i, j)),
             out_shape=jax.ShapeDtypeStruct((bp, fp), jnp.float32),
             scratch_shapes=[pltpu.VMEM((bk, bf), jnp.float32)],
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
@@ -426,7 +425,7 @@ def gd_fused_pallas(x, y, err_output, w, b, vw, vb, lr, lr_bias, decay,
                    jax.ShapeDtypeStruct(vw_p.shape, vw.dtype)],
         scratch_shapes=[pltpu.VMEM(acc_shape, jnp.float32)],
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_p, eo_p, y_p, w_p, vw_p, hp)
@@ -454,7 +453,7 @@ def gd_fused_pallas(x, y, err_output, w, b, vw, vb, lr, lr_bias, decay,
                        jax.ShapeDtypeStruct(vb_p.shape, vb.dtype)],
             scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
             input_output_aliases={2: 0, 3: 1},
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(eo_p, y_p, b_p, vb_p, hp)
@@ -465,30 +464,42 @@ def gd_fused_pallas(x, y, err_output, w, b, vw, vb, lr, lr_bias, decay,
     return w_new, b_new, vw_new, vb_new, err_input
 
 
+@functools.lru_cache(maxsize=None)
+def _log_interpret_once(backend):
+    import logging
+    logging.getLogger("veles_tpu.ops.gemm").warning(
+        "engine.kernels=pallas on the %r backend: the fused GD kernels "
+        "run in Pallas INTERPRET mode (parity/debug only; the compiled "
+        "kernels need the TPU)", backend)
+
+
 def gd_kernel_choice(dtype=jnp.float32, shape=None, db_path=None):
     """Resolve the training-kernel backend for the fused GD stage —
     ``(backend, tiles, interpret)``.
 
     ``root.common.engine.kernels``: ``xla`` forces the dense reference
     (``_gd_math``); ``pallas`` forces the fused kernels — compiled on
-    TPU, interpret-mode Pallas elsewhere (parity/debug; slow); ``auto``
-    (default) takes the autotune DB's measured winner on TPU
+    TPU (interpreted there only when the caller set
+    ``root.common.engine.interpret``), interpret-mode Pallas elsewhere
+    (parity/debug; slow; logged once); ``auto`` (default) takes the
+    autotune DB's measured winner on TPU
     (``ops.benchmark.autotune_gd``) and the dense reference elsewhere.
     Runs at stage-build/trace time only, so the DB lookup costs nothing
     per step and the resolved backend never retraces."""
     from veles_tpu.config import root
     from veles_tpu.ops import on_tpu
     mode = str(root.common.engine.get("kernels", "auto") or "auto")
-    tpu = on_tpu()
-    tiles = None
-    if tpu and mode != "xla":
-        from veles_tpu.ops.benchmark import gemm_choice
-        choice = gemm_choice(dtype, db_path, kernel="gd", shape=shape)
-        tiles = tuple(choice[1]) if choice and choice[1] else None
-        if mode != "pallas" and (choice is None or choice[0] != "pallas"):
-            return "xla", None, False
-    elif mode != "pallas":
-        return "xla", None, False
     if mode == "xla":
         return "xla", None, False
-    return "pallas", tiles, not tpu
+    if not on_tpu():
+        if mode != "pallas":
+            return "xla", None, False
+        _log_interpret_once(jax.default_backend())
+        return "pallas", None, True
+    from veles_tpu.ops.benchmark import gemm_choice
+    choice = gemm_choice(dtype, db_path, kernel="gd", shape=shape)
+    if mode != "pallas" and (choice is None or choice[0] != "pallas"):
+        return "xla", None, False
+    tiles = tuple(choice[1]) if choice and choice[1] else None
+    return "pallas", tiles, bool(root.common.engine.get("interpret",
+                                                        False))

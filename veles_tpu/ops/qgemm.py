@@ -39,7 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from veles_tpu.ops.gemm import _ACTIVATIONS as _GEMM_ACTIVATIONS
 from veles_tpu.ops.gemm import _precision
-from veles_tpu.ops.util import COMPILER_PARAMS as _COMPILER_PARAMS
 from veles_tpu.ops.util import pad_axis as _pad_to, round_up
 
 #: fallback tiles when neither the caller nor the autotune DB supplies
@@ -118,7 +117,7 @@ def _qmatmul_pallas(a, q, scale, bias, activation=None, tiles=None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_p, q_p, scale_p, bias_p)

@@ -36,16 +36,17 @@ def normal(key, shape, dtype=jnp.float32, mean=0.0, stddev=1.0):
 def _uniform_kernel(seed_ref, o_ref, *, low, high):
     # Distinct stream per grid cell: golden-ratio hash of the program id
     # keeps (seed, block) pairs from colliding across *consecutive* seeds
-    # the way plain ``seed + i`` would.  uint32 math — the constant
-    # overflows int32.
-    mixed = (pl.program_id(0).astype(jnp.uint32)
-             * jnp.uint32(0x9E3779B9)) \
-        ^ pltpu.bitcast(seed_ref[0], jnp.uint32)
-    pltpu.prng_seed(pltpu.bitcast(mixed, jnp.int32))
-    bits = pltpu.bitcast(pltpu.prng_random_bits(o_ref.shape), jnp.uint32)
+    # the way plain ``seed + i`` would.  All in int32: the multiply
+    # wraps to the same bits as the uint32 product, and Mosaic has no
+    # scalar bitcast (0x9E3779B9 is this constant, signed).
+    pltpu.prng_seed((pl.program_id(0) * jnp.int32(-1640531527))
+                    ^ seed_ref[0])
+    bits = pltpu.prng_random_bits(o_ref.shape)      # int32
     # 24 high bits → [0, 1) float32 (the reference maps its 64-bit output
-    # the same way, ocl/random.cl:96-110)
-    u01 = (bits >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / (1 << 24))
+    # the same way, ocl/random.cl:96-110); the logical shift leaves a
+    # non-negative int32 the VPU converts exactly
+    u01 = jax.lax.shift_right_logical(bits, 8).astype(jnp.float32) \
+        * (1.0 / (1 << 24))
     o_ref[:] = (u01 * (high - low) + low).astype(o_ref.dtype)
 
 
@@ -53,14 +54,16 @@ def uniform_pallas(seed, shape, dtype=jnp.float32, low=0.0, high=1.0):
     """Uniform fill via the TPU hardware PRNG.  ``seed`` is an int32
     scalar; same (seed, shape) → same bits.
 
-    The hardware PRNG has no interpret-mode lowering, so off-TPU this
-    transparently falls back to threefry (different bits, same
-    distribution) — callers get one API everywhere."""
+    TPU only: the hardware PRNG has no interpret-mode lowering (the
+    Pallas interpreter returns zeros), so off the TPU this raises
+    instead of handing back bits from another generator — use
+    :func:`uniform` there."""
     from veles_tpu.ops import on_tpu
     if not on_tpu():
-        key = jax.random.fold_in(jax.random.key(0), jnp.asarray(
-            seed, jnp.int32))
-        return uniform(key, shape, dtype=dtype, low=low, high=high)
+        raise NotImplementedError(
+            "uniform_pallas needs the TPU core PRNG and this process "
+            "runs on %r; use ops.random.uniform (threefry) instead"
+            % jax.default_backend())
     return _uniform_pallas_tpu(seed, shape, dtype, low, high)
 
 

@@ -4,106 +4,18 @@ along rows or columns with an ``A_COL`` switch and a ``REDUCE_SIZE``
 workgroup tree).
 
 On TPU the VPU's (8, 128) lanes make XLA's own reduction codegen
-excellent; the Pallas path exists for the fused cases (reduce of a
-function of the input without materializing it) and as the autotune
-benchmark's second kernel.  Both paths accumulate in float32.
+excellent, so the template maps to one fused ``jnp`` reduction that
+accumulates in float32.  (A Pallas twin existed until PR 21: no path
+turned it on, and its whole-row VMEM scratch did not fit the v5e's
+16 MiB scoped VMEM at a float32 (8192, 4096) input.)
 """
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from veles_tpu.ops.util import pad_axis, round_up as _round_up
 
 
-def matrix_reduce(a, axis=0, op="sum", use_pallas=None):
+def matrix_reduce(a, axis=0, op="sum"):
     """Reduce a 2D matrix along ``axis`` (0: over rows → per-column
     result, like the reference's default; 1: over columns → per-row)."""
-    if use_pallas is None:
-        from veles_tpu.config import root
-        from veles_tpu.ops import on_tpu
-        use_pallas = bool(root.common.engine.get("pallas_reduce", False)) \
-            and on_tpu()
-    if use_pallas:
-        from veles_tpu.config import root
-        return _reduce_pallas(
-            a, axis=axis, op=op,
-            interpret=bool(root.common.engine.get("interpret", False)))
-    return _reduce_jnp(a, axis=axis, op=op)
-
-
-def _reduce_jnp(a, axis, op):
-    acc = a.astype(jnp.float32)
+    a = jnp.asarray(a)
     fn = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min}[op]
-    return fn(acc, axis=axis).astype(a.dtype)
-
-
-def _reduce_kernel(a_ref, o_ref, acc_ref, *, n_blocks, axis, op):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _zero():
-        if op == "sum":
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-        elif op == "max":
-            acc_ref[:] = jnp.full_like(acc_ref, -jnp.inf)
-        else:
-            acc_ref[:] = jnp.full_like(acc_ref, jnp.inf)
-
-    block = a_ref[:].astype(jnp.float32)
-    if op == "sum":
-        acc_ref[:] += jnp.sum(block, axis=axis, keepdims=True)
-    elif op == "max":
-        acc_ref[:] = jnp.maximum(acc_ref[:],
-                                 jnp.max(block, axis=axis, keepdims=True))
-    else:
-        acc_ref[:] = jnp.minimum(acc_ref[:],
-                                 jnp.min(block, axis=axis, keepdims=True))
-
-    @pl.when(i == n_blocks - 1)
-    def _done():
-        o_ref[:] = acc_ref[:].astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("axis", "op", "interpret"))
-def _reduce_pallas(a, axis=0, op="sum", interpret=False):
-    m, n = a.shape
-    if axis == 0:
-        # march down the rows in blocks; result (1, n)
-        bm = min(512, _round_up(m, 8))
-        a_p = _pad_value(a, bm, 0, op)
-        n_blocks = a_p.shape[0] // bm
-        out = pl.pallas_call(
-            functools.partial(_reduce_kernel, n_blocks=n_blocks, axis=0,
-                              op=op),
-            grid=(n_blocks,),
-            in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((1, n), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, n), a.dtype),
-            scratch_shapes=[pltpu.VMEM((1, n), jnp.float32)],
-            interpret=interpret,
-        )(a_p)
-        return out[0]
-    # axis == 1: march across columns; result (m, 1)
-    bn = min(512, _round_up(n, 128))
-    a_p = _pad_value(a, bn, 1, op)
-    n_blocks = a_p.shape[1] // bn
-    out = pl.pallas_call(
-        functools.partial(_reduce_kernel, n_blocks=n_blocks, axis=1,
-                          op=op),
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((m, bn), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((m, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, 1), a.dtype),
-        scratch_shapes=[pltpu.VMEM((m, 1), jnp.float32)],
-        interpret=interpret,
-    )(a_p)
-    return out[:, 0]
-
-
-def _pad_value(a, mult, axis, op):
-    value = {"sum": 0.0, "max": -jnp.inf, "min": jnp.inf}[op]
-    return pad_axis(a, mult, axis, value=value)
+    return fn(a.astype(jnp.float32), axis=axis).astype(a.dtype)

@@ -2,17 +2,6 @@
 
 import jax.numpy as jnp
 
-#: jax renamed TPUCompilerParams -> CompilerParams across releases;
-#: THE one shim every Pallas kernel module resolves (attention and
-#: qgemm import it instead of keeping per-module copies)
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-    COMPILER_PARAMS = (getattr(_pltpu, "CompilerParams", None)
-                       or getattr(_pltpu, "TPUCompilerParams", None))
-except ImportError:        # pragma: no cover - pallas-less jax
-    COMPILER_PARAMS = None
-
-
 def round_up(x, mult):
     return ((x + mult - 1) // mult) * mult
 

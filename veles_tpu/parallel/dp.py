@@ -226,8 +226,6 @@ def data_parallel_epoch_local(step_fn_reduced, mesh, n_local,
     Returns ``epoch_fn(params, data, labels, key)`` compiled for the
     mesh; metrics are the globally-reduced per-minibatch values.
     """
-    from jax.experimental.shard_map import shard_map
-
     from veles_tpu.znicz.fused_graph import epoch_runner
 
     epoch_local = epoch_runner(step_fn_reduced, n_local, batch_local)
@@ -237,12 +235,12 @@ def data_parallel_epoch_local(step_fn_reduced, mesh, n_local,
         return epoch_local(params, data_local, labels_local,
                            jax.random.fold_in(key, shard))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(batch_axis), P(batch_axis), P()),
         # params leave replicated BY CONSTRUCTION (pmean'd grads =>
         # identical updates); metrics are globally reduced in-step.
-        # check_rep can't see through the collectives, hence False.
+        # check_vma can't see through the collectives, hence False.
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded, donate_argnums=(0,))

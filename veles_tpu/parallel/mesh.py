@@ -8,8 +8,8 @@ An axis size of -1 absorbs all remaining devices (mirrors
 :func:`mesh_from_topology` is the knob-driven entry point
 (``root.common.engine.pod.topology``) the pod runtime, the gen engine
 and tests share, so none of them hand-rolls mesh construction — with
-typed errors (:class:`MeshTopologyError`) for non-divisible axis
-products and a transparent single-device fallback.
+typed errors (:class:`MeshTopologyError`) for axis products the
+attached devices cannot hold.
 """
 
 import jax
@@ -101,9 +101,12 @@ def mesh_from_topology(topology=None, devices=None, require=None):
       remainder instead of silently mis-gridding; an explicit product
       smaller than the device count is a deliberate sub-mesh (the
       leading devices), a wildcard absorbs ``devices // fixed``;
-    * one attached device falls back to a transparent ``{"data": 1}``
-      mesh whatever the knob says — single-device development configs
-      run unchanged (``require`` axes are still present).
+    * on one attached device, ``None``/``"auto"``/``1`` (and any
+      wildcard) give the transparent all-ones mesh — single-device
+      development configs run unchanged (``require`` axes are still
+      present) — but a topology that ASKS for more than one device
+      raises :class:`MeshTopologyError`: four chips' worth of work is
+      never quietly run on the first one.
 
     ``require``: axis names that must exist in the result (added with
     size 1 when the topology omits them).
@@ -118,9 +121,18 @@ def mesh_from_topology(topology=None, devices=None, require=None):
     for name in require or ():
         axes.setdefault(name, 1)
     if n <= 1:
-        # transparent single-device fallback: the caller's program
-        # compiles for a 1-sized mesh, which GSPMD lowers to the plain
-        # single-device executable
+        asked = int(numpy.prod([size for size in axes.values()
+                                if size > 0] or [1]))
+        if asked > 1:
+            raise MeshTopologyError(
+                "pod topology %r asks for %d devices and %d is "
+                "attached (%s) — attach the devices, or spell the "
+                "topology as auto / 1 to run on one"
+                % (axes, asked, n,
+                   ", ".join(str(d) for d in devices) or "none"))
+        # auto / 1 on one device: the caller's program compiles for a
+        # 1-sized mesh, which GSPMD lowers to the plain single-device
+        # executable
         axes = {name: 1 for name in axes} or {"data": 1}
         return Mesh(numpy.array(devices or jax.devices()[:1]).reshape(
             [1] * len(axes)), tuple(axes))
@@ -157,19 +169,6 @@ def mesh_from_topology(topology=None, devices=None, require=None):
     shape = tuple(axes[name] for name in names)
     grid = numpy.array(devices[:int(numpy.prod(shape))]).reshape(shape)
     return Mesh(grid, names)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check=False):
-    """``jax.shard_map`` across JAX versions: the public alias where it
-    exists (``check_vma`` spelling), the experimental module otherwise
-    (``check_rep`` spelling) — the one wrapper the collective modules
-    (moe/pp/ring) share so none of them pins a JAX version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as fn
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check)
 
 
 def make_mesh(axes=None, devices=None):

@@ -110,13 +110,12 @@ def moe_mlp(x, params, mesh, expert_axis="model", batch_axis="data",
     # tokens are sharded over the expert axis too (sequence dim) —
     # replicating them would make every expert device route and ship
     # n_dev identical copies
-    from veles_tpu.parallel.mesh import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axis, expert_axis, None), P(None, None),
                   espec, espec, espec, espec),
         out_specs=P(batch_axis, expert_axis, None),
-        check=False)
+        check_vma=False)
     return fn(x, params["router"], params["w1"], params["b1"],
               params["w2"], params["b2"])
 

@@ -89,12 +89,11 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, n_micro,
     # keep the last stage's block
     out_spec = P(pipe_axis, *data, *([None] * (x.ndim - 2)))
 
-    from veles_tpu.parallel.mesh import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_pipeline_local, stage_fn=stage_fn,
                           axis_name=pipe_axis),
         mesh=mesh, in_specs=(p_spec, x_spec), out_specs=out_spec,
-        check=False)
+        check_vma=False)
     outs = fn(stacked_params, x_stack)          # [n_stages*n_micro, mb, ...]
     last = outs[(n_stages - 1) * n_micro:]
     return last.reshape(x.shape)

@@ -267,11 +267,10 @@ def ring_attention(q, k, v, mesh, causal=False, seq_axis="seq",
     the only path whose backward is pure autodiff)."""
     spec = P(batch_axis, seq_axis, head_axis, None)
     body = _ring_flash_local if use_flash else _ring_attention_local
-    from veles_tpu.parallel.mesh import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(body, axis_name=seq_axis, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check=False)
+        check_vma=False)
     return fn(q, k, v)
 
 
@@ -310,10 +309,9 @@ def ulysses_attention(q, k, v, mesh, causal=False, seq_axis="seq",
             "ulysses needs heads (%d) divisible by seq axis (%d)"
             % (q.shape[2], mesh.shape[seq_axis]))
     spec = P(batch_axis, seq_axis, None, None)
-    from veles_tpu.parallel.mesh import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_local, axis_name=seq_axis,
                           causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check=False)
+        check_vma=False)
     return fn(q, k, v)

@@ -136,16 +136,15 @@ def _attend(q, k, v, mesh, seq_axis):
         return _mha_jnp(q, k, v, True)[0]
     if mesh is None:
         return flash_attention(q, k, v, True)
-    from jax.experimental.shard_map import shard_map
     data = "data" if mesh.shape.get("data", 1) > 1 else None
     model = "model" if mesh.shape.get("model", 1) > 1 else None
     if data is None and model is None:
         return flash_attention(q, k, v, True)
     spec = P(data, None, model, None)
-    return shard_map(
+    return jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, True),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)(q, k, v)
+        check_vma=False)(q, k, v)
 
 
 def _block(h, blk, mesh, seq_axis, compute_dtype):

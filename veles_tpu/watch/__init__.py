@@ -20,9 +20,8 @@ platform's live-plotting operator surface (PAPER.md §0):
    ``python -m veles_tpu.watch <endpoint>`` renders a live terminal
    dashboard and ``--record file.ndjson`` persists a session.
 3. **A perf-regression watchdog** — ``scripts/bench_diff.py``
-   compares a fresh ``bench.py`` run against the banked
-   ``BENCH_r0*.json`` envelope per stage and exits non-zero on
-   regression, turning the bench ladder into a gate.
+   compares a fresh ``bench.py`` run against a named bank of
+   earlier records per metric and exits non-zero on regression.
 
 Disabled path contract (the PR 5 rule): with no bus configured,
 :func:`publish` is one attribute check; with ``health=off`` the
