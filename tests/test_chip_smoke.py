@@ -248,12 +248,41 @@ def config_updates(monkeypatch):
     return calls
 
 
+def _directories_set(config_updates):
+    return [(key, value) for key, value in config_updates
+            if "dir" in key]
+
+
+def test_cache_is_keyed_on_the_programs_names(monkeypatch,
+                                              config_updates):
+    """The names a device trace shows are HLO metadata: the key holds
+    them (JAX's default leaves them out, and a cached executable then
+    shows the names it was compiled with), and the checkout's own path
+    is taken out of the source files so that checkouts share entries."""
+    import re
+    for env in ("/somewhere", None):
+        del config_updates[:]
+        if env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR",
+                               raising=False)
+        backends.enable_compilation_cache(platform="tpu")
+        updates = dict(config_updates)
+        assert updates[
+            "jax_compilation_cache_include_metadata_in_key"] is True
+        pattern = updates["jax_hlo_source_file_canonicalization_regex"]
+        here = os.path.join(REPO_ROOT, "veles_tpu", "backends.py")
+        assert re.sub(pattern, "", here) == os.path.join(
+            "veles_tpu", "backends.py")
+
+
 def test_cache_dir_from_the_environment_means_the_code_sets_nothing(
         monkeypatch, config_updates, tmp_path):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
     assert backends.enable_compilation_cache(platform="tpu") \
         == str(tmp_path / "env")
-    assert config_updates == []
+    assert _directories_set(config_updates) == []
     assert not (tmp_path / "env").exists()     # JAX makes it, not we
 
 
@@ -264,7 +293,8 @@ def test_cache_dir_unset_is_one_fixed_path_inside_the_checkout(
     assert backends.COMPILE_CACHE_DIR == fixed
     for _ in range(2):                         # same answer every time
         assert backends.enable_compilation_cache(platform="tpu") == fixed
-    assert config_updates == [("jax_compilation_cache_dir", fixed)] * 2
+    assert _directories_set(config_updates) == [
+        ("jax_compilation_cache_dir", fixed)] * 2
     home = os.path.expanduser("~")
     assert not fixed.startswith(home + os.sep) or REPO_ROOT.startswith(
         home + os.sep)

@@ -275,3 +275,187 @@ def test_fused_lm_train_step_beats_xla_baseline_on_cpu():
     assert rec["recompiles"] == 0, rec
     assert rec["vs_baseline"] >= 1.2, \
         "fused LM train step below the 1.2x CPU floor: %r" % (rec,)
+
+
+# ---------------------------------------------------------------------------
+# 4. the device's work carries the program's names (docs/observability.md)
+# ---------------------------------------------------------------------------
+
+def _pallas_names(fn, *args):
+    """Names of the ``pallas_call`` equations in ``fn``'s jaxpr, nested
+    programs included (tracing only: nothing is lowered or run)."""
+    import jax
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for value in eqn.params.values():
+                inner = getattr(value, "jaxpr", None)
+                if inner is not None:
+                    yield from walk(getattr(inner, "jaxpr", inner))
+
+    return sorted(set(walk(jax.make_jaxpr(fn)(*args).jaxpr)))
+
+
+def _kernel_cases():
+    from veles_tpu.ops import attention, gather, gemm, qgemm
+    from veles_tpu.ops import random as ops_random
+    f32 = jnp.float32
+    q = jnp.zeros((1, 16, 2, 8), f32)           # (b, s, h, d)
+    lse = jnp.zeros((1, 2, 16), f32)
+    q1 = jnp.zeros((2, 1, 2, 8), f32)           # one decode row a slot
+    cache = jnp.zeros((2, 16, 2, 8), f32)
+    pool = jnp.zeros((5, 8, 2, 8), f32)         # (blocks, bs, h, d)
+    tables = jnp.zeros((2, 2), jnp.int32)
+    lengths = jnp.ones(2, jnp.int32)
+    a = jnp.zeros((8, 16), f32)
+    w = jnp.zeros((16, 8), f32)
+    rows = jnp.zeros((6, 128), f32)
+    idx = jnp.arange(4, dtype=jnp.int32)
+    gd = (a, jnp.zeros((8, 8), f32), jnp.zeros((8, 8), f32), w,
+          jnp.zeros(8, f32), jnp.zeros_like(w), jnp.zeros(8, f32))
+
+    def gd_fused(*args):
+        return gemm.gd_fused_pallas(*args, *_HP, interpret=True)
+
+    return {
+        "veles_flash_fwd": (lambda q: attention._flash_fwd(
+            q, q, q, causal=True, interpret=True), (q,)),
+        "veles_flash_bwd_dq": (lambda q, lse: attention._flash_bwd(
+            q, q, q, q, lse, q, causal=True, interpret=True),
+            (q, lse)),
+        "veles_flash_bwd_dkv": (lambda q, lse: attention._flash_bwd(
+            q, q, q, q, lse, q, causal=True, interpret=True),
+            (q, lse)),
+        "veles_attn_decode": (lambda q, c, n: attention._decode_pallas(
+            q, c, c, n, block_k=8, interpret=True),
+            (q1, cache, lengths)),
+        "veles_attn_paged_decode": (
+            lambda q, p, t, n: attention._paged_decode_pallas(
+                q, p, p, t, n, interpret=True),
+            (q1, pool, tables, lengths)),
+        "veles_matmul": (lambda a, w: gemm._matmul_pallas(
+            a, w, None, interpret=True), (a, w)),
+        "veles_qmatmul": (lambda a, w, s: qgemm._qmatmul_pallas(
+            a, w.astype(jnp.int8), s, None, interpret=True),
+            (a, w, jnp.ones(8, f32))),
+        "veles_gd_err_input": (gd_fused, gd),
+        "veles_gd_update_w": (gd_fused, gd),
+        "veles_gd_update_b": (gd_fused, gd),
+        "veles_gather": (lambda d, i: gather._gather_pallas(
+            d, i, interpret=True), (rows, idx)),
+        "veles_gather_norm": (lambda d, i: gather._gather_norm_pallas(
+            d, i, jnp.ones((1, 128), f32), jnp.zeros((1, 128), f32),
+            interpret=True), (rows, idx)),
+        "veles_uniform": (lambda s: ops_random._uniform_pallas_tpu(
+            s, (8, 128)), (jnp.int32(1),)),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "veles_flash_fwd", "veles_flash_bwd_dq", "veles_flash_bwd_dkv",
+    "veles_attn_decode", "veles_attn_paged_decode", "veles_matmul",
+    "veles_qmatmul", "veles_gd_err_input", "veles_gd_update_w",
+    "veles_gd_update_b", "veles_gather", "veles_gather_norm",
+    "veles_uniform"])
+def test_every_pallas_call_carries_its_kernels_name(name):
+    """The name a device trace shows for a Pallas kernel is the one
+    its ``pallas_call`` was given: one name a kernel."""
+    fn, args = _kernel_cases()[name]
+    names = _pallas_names(fn, *args)
+    assert name in names, names
+    assert all(n.startswith("veles_") for n in names), names
+
+
+def test_no_pallas_call_site_without_a_name():
+    import os
+    import re
+
+    import veles_tpu.ops as ops
+    root_dir = os.path.dirname(ops.__file__)
+    sites = 0
+    for fname in sorted(os.listdir(root_dir)):
+        if not fname.endswith(".py"):
+            continue
+        with open(os.path.join(root_dir, fname)) as handle:
+            text = handle.read()
+        for match in re.finditer(r"pl\.pallas_call\(", text):
+            sites += 1
+            head = text[match.end():match.end() + 400]
+            assert re.search(r'\bname="veles_[a-z_]+"', head), \
+                (fname, text[:match.start()].count("\n") + 1)
+    assert sites == 16
+
+
+def _scope_names(lowered):
+    """``(every veles. scope, the locations)`` in the lowered text."""
+    import re
+    locs = set(re.findall(r'loc\("([^"]*)"',
+                          lowered.as_text(debug_info=True)))
+    scopes = {name for loc in locs
+              for name in re.findall(r"veles\.[a-z_.0-9]+", loc)}
+    return scopes, locs
+
+
+def test_fused_step_lowers_with_layer_and_update_scopes():
+    import jax
+
+    from veles_tpu.znicz.fused_graph import lower_specs
+    solver = {"learning_rate": 0.05, "gradient_moment": 0.9}
+    params, step_fn, eval_fn, _apply = lower_specs(
+        [{"type": "all2all_tanh", "->": {"output_sample_shape": 8},
+          "<-": solver},
+         {"type": "softmax", "->": {"output_sample_shape": 10},
+          "<-": solver}], (16,), input_norm=(0.5, -1.0))
+    x = jnp.zeros((4, 16), jnp.uint8)
+    labels = jnp.zeros(4, jnp.int32)
+    scopes, locs = _scope_names(jax.jit(step_fn).lower(params, x,
+                                                       labels))
+    assert {"veles.layer.00.all2all_tanh", "veles.layer.01.softmax",
+            "veles.ingest", "veles.loss", "veles.update"} <= scopes
+    joined = "\n".join(locs)
+    # forward and backward of one layer are told apart by JAX's own
+    # wrapping of the scope
+    assert "jvp(veles.layer.00." in joined
+    assert "transpose(jvp(veles.layer.00." in joined
+    assert "transpose(jvp(veles.layer.01." in joined
+    assert not any("veles.update" in loc and "jvp(" in loc
+                   for loc in locs)
+    scopes, _locs = _scope_names(jax.jit(eval_fn).lower(params, x,
+                                                        labels))
+    assert {"veles.layer.00.all2all_tanh", "veles.layer.01.softmax",
+            "veles.ingest"} <= scopes
+    assert "veles.update" not in scopes
+
+
+def test_decode_lowers_with_gpt_scopes_and_none_on_the_scan():
+    import jax
+
+    from veles_tpu.gen import TransformerGenModel
+    from veles_tpu.samples.transformer import TINY
+    model = TransformerGenModel(dict(TINY, seq_len=64))
+    lowered = jax.jit(model.decode).lower(
+        model.init_params(0), model.init_cache(3, 48),
+        jnp.zeros(3, jnp.int32), jnp.ones(3, jnp.int32),
+        jnp.ones(3, bool))
+    scopes, locs = _scope_names(lowered)
+    assert scopes == {"veles.gpt." + part for part in (
+        "embed", "qkv", "kv_write", "attn", "proj", "mlp", "readout")}
+    # the scan, and what it adds to move the cache through the layers
+    # (the slices of xs, the write-back of ys), stay outside every name
+    loops = [loc for loc in locs if "while" in loc or "scan" in loc]
+    assert any(loc.endswith("/while") for loc in loops), loops
+    assert any(loc.endswith("/dynamic_update_slice") for loc in loops)
+    assert not any("veles." in loc for loc in loops), loops
+
+
+def test_loader_gathers_lower_with_their_scope():
+    from veles_tpu.ops import gather
+    data = jnp.zeros((6, 4, 4, 3), jnp.uint8)
+    idx = jnp.arange(4, dtype=jnp.int32)
+    scopes, _locs = _scope_names(gather._gather_jnp.lower(data, idx))
+    assert scopes == {"veles.loader.take_rows"}
+    scopes, _locs = _scope_names(gather._gather_norm_jnp.lower(
+        data, idx, jnp.float32(0.5), jnp.float32(-1.0)))
+    assert scopes == {"veles.loader.take_rows_norm"}

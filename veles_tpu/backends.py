@@ -25,6 +25,7 @@ TPU re-design (BASELINE.json north star: "TPU as a first-class Device"):
 
 import json
 import os
+import re
 
 import numpy
 
@@ -74,13 +75,13 @@ DEVICE_HBM_BYTES = (
 )
 
 
+#: the checkout this package lives in
+CHECKOUT_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: the ONE compile-cache location when the environment names none: a
 #: fixed directory inside the checkout (git-ignored).  The path is part
 #: of the cache key, so it never carries a home directory, a temporary
 #: name, a pid or a timestamp.
-COMPILE_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    ".cache", "xla")
+COMPILE_CACHE_DIR = os.path.join(CHECKOUT_DIR, ".cache", "xla")
 
 
 def _requested_platforms():
@@ -100,10 +101,18 @@ def enable_compilation_cache(platform=None):
     that compiles through this framework (devices, the timing harness,
     the autotuner, the profiler, ``chip_smoke.py``, ``bench.py``)
     shares one on-disk cache.  ONE rule: if ``JAX_COMPILATION_CACHE_DIR``
-    is set, JAX reads it itself and this function sets nothing in code;
-    otherwise the cache lives at :data:`COMPILE_CACHE_DIR`.  Safe to
-    call any number of times, before or after backend init (only
+    is set, JAX reads it itself and this function sets no directory in
+    code; otherwise the cache lives at :data:`COMPILE_CACHE_DIR`.  Safe
+    to call any number of times, before or after backend init (only
     programs compiled afterwards are cached).
+
+    The cache is keyed on the program WITH its metadata: the scope and
+    kernel names a device trace shows (``docs/observability.md``) are
+    HLO metadata, JAX's default key leaves metadata out, and an
+    executable loaded under such a key shows the names it was compiled
+    with (my chip run, PR 26: none at all, for the step compiled before
+    the scopes existed).  The checkout's own path is taken out of the
+    source files first, so two checkouts of one tree share entries.
 
     Non-CPU platforms only: CPU compiles are cheap, and an AOT CPU
     executable cached under one machine-feature detection can SIGILL
@@ -116,6 +125,10 @@ def enable_compilation_cache(platform=None):
         platform = _requested_platforms()
     if str(platform).lower() == "cpu":
         return None
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(CHECKOUT_DIR + os.sep))
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

@@ -980,16 +980,19 @@ class GenerativeEngine(Logger):
                             {"bucket": bucket, "slot": slot, "len": n,
                              "engine": self.prof_name}), role="server"):
             tic = time.perf_counter_ns()
-            if self._pool is not None:
-                self._cache, tok = exe(self._params, self._cache,
-                                       jnp.asarray(padded[None]),
-                                       jnp.asarray(block_ids),
-                                       jnp.int32(n))
-            else:
-                self._cache, tok = exe(self._params, self._cache,
-                                       jnp.asarray(padded[None]),
-                                       jnp.int32(slot), jnp.int32(n))
-            tok = int(tok)
+            with trace.span("gen", "prefill_dispatch"):
+                if self._pool is not None:
+                    self._cache, tok = exe(
+                        self._params, self._cache,
+                        jnp.asarray(padded[None]),
+                        jnp.asarray(block_ids), jnp.int32(n))
+                else:
+                    self._cache, tok = exe(
+                        self._params, self._cache,
+                        jnp.asarray(padded[None]),
+                        jnp.int32(slot), jnp.int32(n))
+            with trace.span("gen", "prefill_fetch"):
+                tok = int(tok)
             prof.ledger.record_dispatch(
                 entry, time.perf_counter_ns() - tic, items=n)
         self.slot_len[slot] = n
@@ -1118,19 +1121,6 @@ class GenerativeEngine(Logger):
         active = self.slot_active & (self.slot_len < self.max_seq)
         if not active.any():
             return None
-        if self._pool is not None:
-            # fused block append, host half: make sure every decoding
-            # row owns the page its write position lands in (raises
-            # PoolExhausted — the scheduler preempts first via
-            # decode_block_deficit, so this only fires on direct use)
-            for slot in numpy.nonzero(active)[0]:
-                self._pool.append(int(slot), int(self.slot_len[slot]))
-        positions = numpy.where(active, self.slot_len, 0
-                                ).astype(numpy.int32)
-        toks = numpy.where(active, self.slot_token, 0
-                           ).astype(numpy.int32)
-        exe, entry = self._decode_executable()
-        self.decode_calls += 1
         n_active = int(active.sum())
         decode_args = {"active": n_active, "engine": self.prof_name}
         if trace.enabled():
@@ -1142,19 +1132,36 @@ class GenerativeEngine(Logger):
             if traces:
                 decode_args["traces"] = traces
         with trace.span("gen", "decode", decode_args, role="server"):
+            with trace.span("gen", "decode_prepare"):
+                if self._pool is not None:
+                    # fused block append, host half: make sure every
+                    # decoding row owns the page its write position
+                    # lands in (raises PoolExhausted — the scheduler
+                    # preempts first via decode_block_deficit, so this
+                    # only fires on direct use)
+                    for slot in numpy.nonzero(active)[0]:
+                        self._pool.append(int(slot),
+                                          int(self.slot_len[slot]))
+                positions = numpy.where(active, self.slot_len, 0
+                                        ).astype(numpy.int32)
+                toks = numpy.where(active, self.slot_token, 0
+                                   ).astype(numpy.int32)
+                exe, entry = self._decode_executable()
+            self.decode_calls += 1
             tic = time.perf_counter_ns()
-            if self._pool is not None:
-                self._cache, out = exe(self._params, self._cache,
-                                       jnp.asarray(self._pool.tables),
-                                       jnp.asarray(toks),
-                                       jnp.asarray(positions),
-                                       jnp.asarray(active))
-            else:
-                self._cache, out = exe(self._params, self._cache,
-                                       jnp.asarray(toks),
-                                       jnp.asarray(positions),
-                                       jnp.asarray(active))
-            out = numpy.asarray(out)
+            with trace.span("gen", "decode_dispatch"):
+                if self._pool is not None:
+                    self._cache, out = exe(
+                        self._params, self._cache,
+                        jnp.asarray(self._pool.tables),
+                        jnp.asarray(toks), jnp.asarray(positions),
+                        jnp.asarray(active))
+                else:
+                    self._cache, out = exe(
+                        self._params, self._cache, jnp.asarray(toks),
+                        jnp.asarray(positions), jnp.asarray(active))
+            with trace.span("gen", "decode_fetch"):
+                out = numpy.asarray(out)
             prof.ledger.record_dispatch(
                 entry, time.perf_counter_ns() - tic, items=n_active)
         self.slot_len[active] += 1

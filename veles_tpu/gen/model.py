@@ -187,6 +187,43 @@ class TransformerGenModel(object):
                        bias, activation, use_pallas=self.use_pallas,
                        out_dtype=x2.dtype)
 
+    def _mlp(self, h, blk):
+        """The block's MLP half (second layernorm included), before
+        the residual add."""
+        cd = self.compute_dtype
+        b_, s_ = h.shape[0], h.shape[1]
+        x = _layernorm(h, blk["ln2_g"], blk["ln2_b"])
+        w1_q = isinstance(blk["w1"], dict)
+        w2_q = isinstance(blk["w2"], dict)
+        if w1_q or w2_q:
+            # bias + gelu fused into the up-projection epilogue,
+            # bias into the down-projection's — the whole MLP is
+            # two quantized dispatches.  The halves branch
+            # independently so the calibration blame probe (one
+            # key quantized at a time) traces cleanly.
+            x2 = x.reshape(b_ * s_, -1).astype(cd)
+            if w1_q:
+                up_act = self._qmm(x2, blk["w1"], 1,
+                                   bias=blk["b1"].astype(cd),
+                                   activation="gelu")
+            else:
+                up_act = jax.nn.gelu(
+                    x2 @ blk["w1"].astype(cd)
+                    + blk["b1"].astype(cd))
+            if w2_q:
+                down = self._qmm(up_act, blk["w2"], 1,
+                                 bias=blk["b2"].astype(cd))
+            else:
+                down = (up_act @ blk["w2"].astype(cd)
+                        + blk["b2"].astype(cd))
+            down = down.reshape(b_, s_, -1)
+        else:
+            up = (x.astype(cd) @ blk["w1"].astype(cd)
+                  + blk["b1"].astype(cd))
+            down = (jax.nn.gelu(up) @ blk["w2"].astype(cd)
+                    + blk["b2"].astype(cd))
+        return down
+
     def _run_layers(self, params, cache, h, kv_hook):
         """Scan the block stack with the ONE shared layer body.
         ``kv_hook(kc, vc, q, k, v) -> (kc', vc', att)`` is the only
@@ -206,62 +243,39 @@ class TransformerGenModel(object):
         def layer(h, xs):
             blk, kc, vc = xs
             b_, s_ = h.shape[0], h.shape[1]
-            x = _layernorm(h, blk["ln1_g"], blk["ln1_b"])
-            if isinstance(blk["wqkv"], dict):
-                qkv = self._qmm(
-                    x.reshape(b_ * s_, -1).astype(cd),
-                    blk["wqkv"], 1).reshape(
-                        b_, s_, 3, self.heads, self.head_dim)
-            else:
-                qkv = jnp.einsum("bsd,dchx->bschx", x.astype(cd),
-                                 blk["wqkv"].astype(cd))
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            with jax.named_scope("veles.gpt.qkv"):
+                x = _layernorm(h, blk["ln1_g"], blk["ln1_b"])
+                if isinstance(blk["wqkv"], dict):
+                    qkv = self._qmm(
+                        x.reshape(b_ * s_, -1).astype(cd),
+                        blk["wqkv"], 1).reshape(
+                            b_, s_, 3, self.heads, self.head_dim)
+                else:
+                    qkv = jnp.einsum("bsd,dchx->bschx", x.astype(cd),
+                                     blk["wqkv"].astype(cd))
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             kc, vc, att = kv_hook(kc, vc, q, k, v)
-            if isinstance(blk["wo"], dict):
-                proj = self._qmm(
-                    att.reshape(b_ * s_, -1).astype(cd),
-                    blk["wo"], 2).reshape(b_, s_, -1)
-            else:
-                proj = jnp.einsum("bshx,hxd->bsd", att.astype(cd),
-                                  blk["wo"].astype(cd))
-            h = h + proj.astype(h.dtype)
-            x = _layernorm(h, blk["ln2_g"], blk["ln2_b"])
-            w1_q = isinstance(blk["w1"], dict)
-            w2_q = isinstance(blk["w2"], dict)
-            if w1_q or w2_q:
-                # bias + gelu fused into the up-projection epilogue,
-                # bias into the down-projection's — the whole MLP is
-                # two quantized dispatches.  The halves branch
-                # independently so the calibration blame probe (one
-                # key quantized at a time) traces cleanly.
-                x2 = x.reshape(b_ * s_, -1).astype(cd)
-                if w1_q:
-                    up_act = self._qmm(x2, blk["w1"], 1,
-                                       bias=blk["b1"].astype(cd),
-                                       activation="gelu")
+            with jax.named_scope("veles.gpt.proj"):
+                if isinstance(blk["wo"], dict):
+                    proj = self._qmm(
+                        att.reshape(b_ * s_, -1).astype(cd),
+                        blk["wo"], 2).reshape(b_, s_, -1)
                 else:
-                    up_act = jax.nn.gelu(
-                        x2 @ blk["w1"].astype(cd)
-                        + blk["b1"].astype(cd))
-                if w2_q:
-                    down = self._qmm(up_act, blk["w2"], 1,
-                                     bias=blk["b2"].astype(cd))
-                else:
-                    down = (up_act @ blk["w2"].astype(cd)
-                            + blk["b2"].astype(cd))
-                down = down.reshape(b_, s_, -1)
-            else:
-                up = (x.astype(cd) @ blk["w1"].astype(cd)
-                      + blk["b1"].astype(cd))
-                down = (jax.nn.gelu(up) @ blk["w2"].astype(cd)
-                        + blk["b2"].astype(cd))
-            h = h + down.astype(h.dtype)
+                    proj = jnp.einsum("bshx,hxd->bsd", att.astype(cd),
+                                      blk["wo"].astype(cd))
+                h = h + proj.astype(h.dtype)
+            with jax.named_scope("veles.gpt.mlp"):
+                h = h + self._mlp(h, blk).astype(h.dtype)
             return h, (kc, vc)
 
+        # the scan itself gets NO scope: what it adds to move the cache
+        # through the layers stays outside every name, and is counted
+        # as what is left over (docs/observability.md)
         h, (ks, vs) = jax.lax.scan(
             layer, h, (params["blocks"], cache["k"], cache["v"]))
-        return (_layernorm(h, params["lnf_g"], params["lnf_b"]),
-                {"k": ks, "v": vs})
+        with jax.named_scope("veles.gpt.readout"):
+            h = _layernorm(h, params["lnf_g"], params["lnf_b"])
+        return h, {"k": ks, "v": vs}
 
     def calibration_logits(self, params, tokens):
         """Last-position logits of ONE prompt through the same shared
@@ -273,19 +287,24 @@ class TransformerGenModel(object):
         s = tokens.shape[1]
         cd = self.compute_dtype
         embed = jnp.asarray(params["embed"])
-        h = embed[tokens] + jnp.asarray(params["pos"])[:s]
+        with jax.named_scope("veles.gpt.embed"):
+            h = embed[tokens] + jnp.asarray(params["pos"])[:s]
 
         def kv_hook(kc, vc, q, k, v):
-            return kc, vc, self._attend_prefill(q, k, v)
+            with jax.named_scope("veles.gpt.attn"):
+                att = self._attend_prefill(q, k, v)
+            return kc, vc, att
 
         cache = {"k": jnp.zeros((self.layers, 1, 1, self.heads,
                                  self.head_dim), cd),
                  "v": jnp.zeros((self.layers, 1, 1, self.heads,
                                  self.head_dim), cd)}
         h, _cache = self._run_layers(params, cache, h, kv_hook)
-        return jnp.einsum("d,vd->v", h[0, -1].astype(cd),
-                          embed.astype(cd)).astype(jnp.float32)
+        with jax.named_scope("veles.gpt.readout"):
+            return jnp.einsum("d,vd->v", h[0, -1].astype(cd),
+                              embed.astype(cd)).astype(jnp.float32)
 
+    @jax.named_scope("veles.gpt.readout")
     def _greedy_at(self, params, h, index):
         """h (1, S, d) -> the greedy token of row ``index`` (traced)
         through the tied readout."""
@@ -297,6 +316,7 @@ class TransformerGenModel(object):
                             ).astype(jnp.float32)
         return jnp.argmax(logits).astype(jnp.int32)
 
+    @jax.named_scope("veles.gpt.readout")
     def _greedy_rows(self, params, h):
         """h (slots, 1, d) -> one greedy token per row."""
         cd = self.compute_dtype
@@ -305,6 +325,7 @@ class TransformerGenModel(object):
                             ).astype(jnp.float32)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+    @jax.named_scope("veles.gpt.readout")
     def _greedy_grid(self, params, h):
         """h (slots, K+1, d) -> the greedy token of EVERY row — the
         verify step's readout.  Per-(slot, row) the contraction is the
@@ -325,14 +346,17 @@ class TransformerGenModel(object):
         cache but stays masked (and is progressively overwritten) by
         the decode step's length mask."""
         bucket = tokens.shape[1]
-        h = params["embed"][tokens] + params["pos"][:bucket]
+        with jax.named_scope("veles.gpt.embed"):
+            h = params["embed"][tokens] + params["pos"][:bucket]
 
         def kv_hook(kc, vc, q, k, v):
-            att = self._attend_prefill(q, k, v)
-            kc = jax.lax.dynamic_update_slice(
-                kc, k[0].astype(kc.dtype)[None], (slot, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v[0].astype(vc.dtype)[None], (slot, 0, 0, 0))
+            with jax.named_scope("veles.gpt.attn"):
+                att = self._attend_prefill(q, k, v)
+            with jax.named_scope("veles.gpt.kv_write"):
+                kc = jax.lax.dynamic_update_slice(
+                    kc, k[0].astype(kc.dtype)[None], (slot, 0, 0, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, v[0].astype(vc.dtype)[None], (slot, 0, 0, 0))
             return kc, vc, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
@@ -349,20 +373,23 @@ class TransformerGenModel(object):
         while the slot is still decode-inactive, so an unmasked
         ride-along write would corrupt position 0 of a live prompt."""
         slots = tokens.shape[0]
-        h = (params["embed"][tokens]
-             + params["pos"][positions])[:, None, :]   # (slots, 1, d)
+        with jax.named_scope("veles.gpt.embed"):
+            h = (params["embed"][tokens]
+                 + params["pos"][positions])[:, None, :]   # (slots, 1, d)
         idx = jnp.arange(slots)
         keep = active[:, None, None]
 
         def kv_hook(kc, vc, q, k, v):
-            kc = kc.at[idx, positions].set(
-                jnp.where(keep, k[:, 0].astype(kc.dtype),
-                          kc[idx, positions]))
-            vc = vc.at[idx, positions].set(
-                jnp.where(keep, v[:, 0].astype(vc.dtype),
-                          vc[idx, positions]))
-            att = decode_attention(q, kc, vc, positions + 1,
-                                   use_pallas=self.use_pallas)
+            with jax.named_scope("veles.gpt.kv_write"):
+                kc = kc.at[idx, positions].set(
+                    jnp.where(keep, k[:, 0].astype(kc.dtype),
+                              kc[idx, positions]))
+                vc = vc.at[idx, positions].set(
+                    jnp.where(keep, v[:, 0].astype(vc.dtype),
+                              vc[idx, positions]))
+            with jax.named_scope("veles.gpt.attn"):
+                att = decode_attention(q, kc, vc, positions + 1,
+                                       use_pallas=self.use_pallas)
             return kc, vc, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
@@ -380,16 +407,19 @@ class TransformerGenModel(object):
         bucket = tokens.shape[1]
         n_blk = block_ids.shape[0]
         bs = bucket // n_blk
-        h = params["embed"][tokens] + params["pos"][:bucket]
+        with jax.named_scope("veles.gpt.embed"):
+            h = params["embed"][tokens] + params["pos"][:bucket]
 
         def kv_hook(kc, vc, q, k, v):
-            att = self._attend_prefill(q, k, v)
-            kc = kc.at[block_ids].set(
-                k[0].astype(kc.dtype).reshape(
-                    n_blk, bs, self.heads, self.head_dim))
-            vc = vc.at[block_ids].set(
-                v[0].astype(vc.dtype).reshape(
-                    n_blk, bs, self.heads, self.head_dim))
+            with jax.named_scope("veles.gpt.attn"):
+                att = self._attend_prefill(q, k, v)
+            with jax.named_scope("veles.gpt.kv_write"):
+                kc = kc.at[block_ids].set(
+                    k[0].astype(kc.dtype).reshape(
+                        n_blk, bs, self.heads, self.head_dim))
+                vc = vc.at[block_ids].set(
+                    v[0].astype(vc.dtype).reshape(
+                        n_blk, bs, self.heads, self.head_dim))
             return kc, vc, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
@@ -407,18 +437,21 @@ class TransformerGenModel(object):
         state."""
         slots = tokens.shape[0]
         bs = cache["k"].shape[2]               # [L, NB, BS, h, dh]
-        h = (params["embed"][tokens]
-             + params["pos"][positions])[:, None, :]   # (slots, 1, d)
+        with jax.named_scope("veles.gpt.embed"):
+            h = (params["embed"][tokens]
+                 + params["pos"][positions])[:, None, :]   # (slots, 1, d)
         idx = jnp.arange(slots)
         blk_idx = jnp.where(active, tables[idx, positions // bs], 0)
         blk_off = jnp.where(active, positions % bs, 0)
 
         def kv_hook(kc, vc, q, k, v):
-            kc = kc.at[blk_idx, blk_off].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[blk_idx, blk_off].set(v[:, 0].astype(vc.dtype))
-            att = paged_decode_attention(q, kc, vc, tables,
-                                         positions + 1,
-                                         use_pallas=self.use_pallas)
+            with jax.named_scope("veles.gpt.kv_write"):
+                kc = kc.at[blk_idx, blk_off].set(k[:, 0].astype(kc.dtype))
+                vc = vc.at[blk_idx, blk_off].set(v[:, 0].astype(vc.dtype))
+            with jax.named_scope("veles.gpt.attn"):
+                att = paged_decode_attention(q, kc, vc, tables,
+                                             positions + 1,
+                                             use_pallas=self.use_pallas)
             return kc, vc, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
@@ -446,8 +479,9 @@ class TransformerGenModel(object):
         slots, kp1 = tokens.shape
         offs = jnp.arange(kp1)
         gpos = positions[:, None] + offs[None, :]     # (slots, K+1)
-        h = (params["embed"][tokens]
-             + params["pos"][jnp.clip(gpos, 0, self.seq_limit - 1)])
+        with jax.named_scope("veles.gpt.embed"):
+            h = (params["embed"][tokens]
+                 + params["pos"][jnp.clip(gpos, 0, self.seq_limit - 1)])
         idx = jnp.arange(slots)
         keep = active[:, None] & (offs[None, :] <= drafts[:, None])
         # masked rows park at position 0 and write the OLD value back
@@ -456,14 +490,16 @@ class TransformerGenModel(object):
         rows = jnp.broadcast_to(idx[:, None], (slots, kp1))
 
         def kv_hook(kc, vc, q, k, v):
-            kc = kc.at[rows, safe].set(
-                jnp.where(keep[..., None, None], k.astype(kc.dtype),
-                          kc[rows, safe]))
-            vc = vc.at[rows, safe].set(
-                jnp.where(keep[..., None, None], v.astype(vc.dtype),
-                          vc[rows, safe]))
-            att = verify_attention(q, kc, vc, positions + 1,
-                                   use_pallas=self.use_pallas)
+            with jax.named_scope("veles.gpt.kv_write"):
+                kc = kc.at[rows, safe].set(
+                    jnp.where(keep[..., None, None], k.astype(kc.dtype),
+                              kc[rows, safe]))
+                vc = vc.at[rows, safe].set(
+                    jnp.where(keep[..., None, None], v.astype(vc.dtype),
+                              vc[rows, safe]))
+            with jax.named_scope("veles.gpt.attn"):
+                att = verify_attention(q, kc, vc, positions + 1,
+                                       use_pallas=self.use_pallas)
             return kc, vc, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
@@ -481,8 +517,9 @@ class TransformerGenModel(object):
         bs = cache["k"].shape[2]               # [L, NB, BS, h, dh]
         offs = jnp.arange(kp1)
         gpos = positions[:, None] + offs[None, :]     # (slots, K+1)
-        h = (params["embed"][tokens]
-             + params["pos"][jnp.clip(gpos, 0, self.seq_limit - 1)])
+        with jax.named_scope("veles.gpt.embed"):
+            h = (params["embed"][tokens]
+                 + params["pos"][jnp.clip(gpos, 0, self.seq_limit - 1)])
         idx = jnp.arange(slots)
         keep = active[:, None] & (offs[None, :] <= drafts[:, None])
         safe = jnp.where(keep, gpos, 0)
@@ -490,11 +527,13 @@ class TransformerGenModel(object):
         blk_off = jnp.where(keep, safe % bs, 0)
 
         def kv_hook(kc, vc, q, k, v):
-            kc = kc.at[blk_idx, blk_off].set(k.astype(kc.dtype))
-            vc = vc.at[blk_idx, blk_off].set(v.astype(vc.dtype))
-            att = paged_verify_attention(q, kc, vc, tables,
-                                         positions + 1,
-                                         use_pallas=self.use_pallas)
+            with jax.named_scope("veles.gpt.kv_write"):
+                kc = kc.at[blk_idx, blk_off].set(k.astype(kc.dtype))
+                vc = vc.at[blk_idx, blk_off].set(v.astype(vc.dtype))
+            with jax.named_scope("veles.gpt.attn"):
+                att = paged_verify_attention(q, kc, vc, tables,
+                                             positions + 1,
+                                             use_pallas=self.use_pallas)
             return kc, vc, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
@@ -511,20 +550,23 @@ class TransformerGenModel(object):
         (cache', token) — the token is the greedy continuation and is
         meaningful on the final chunk only."""
         chunk = tokens.shape[1]
-        pos = jax.lax.dynamic_slice_in_dim(params["pos"], start, chunk)
-        h = params["embed"][tokens] + pos
+        with jax.named_scope("veles.gpt.embed"):
+            pos = jax.lax.dynamic_slice_in_dim(params["pos"], start, chunk)
+            h = params["embed"][tokens] + pos
 
         def kv_hook(kc, vc, q, k, v):
-            kc = jax.lax.dynamic_update_slice(
-                kc, k[0].astype(kc.dtype)[None], (slot, start, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v[0].astype(vc.dtype)[None], (slot, start, 0, 0))
-            kf = jax.lax.dynamic_slice(
-                kc, (slot, 0, 0, 0), (1,) + kc.shape[1:])
-            vf = jax.lax.dynamic_slice(
-                vc, (slot, 0, 0, 0), (1,) + vc.shape[1:])
-            att = chunk_attention(q, kf, vf, start,
-                                  use_pallas=self.use_pallas)
+            with jax.named_scope("veles.gpt.kv_write"):
+                kc = jax.lax.dynamic_update_slice(
+                    kc, k[0].astype(kc.dtype)[None], (slot, start, 0, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, v[0].astype(vc.dtype)[None], (slot, start, 0, 0))
+            with jax.named_scope("veles.gpt.attn"):
+                kf = jax.lax.dynamic_slice(
+                    kc, (slot, 0, 0, 0), (1,) + kc.shape[1:])
+                vf = jax.lax.dynamic_slice(
+                    vc, (slot, 0, 0, 0), (1,) + vc.shape[1:])
+                att = chunk_attention(q, kf, vf, start,
+                                      use_pallas=self.use_pallas)
             return kc, vc, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
@@ -541,24 +583,25 @@ class TransformerGenModel(object):
         n_blk = chunk_ids.shape[0]
         bs = cache["k"].shape[2]               # [L, NB, BS, h, dh]
         chunk = tokens.shape[1]
-        pos = jax.lax.dynamic_slice_in_dim(params["pos"], start, chunk)
-        h = params["embed"][tokens] + pos
+        with jax.named_scope("veles.gpt.embed"):
+            pos = jax.lax.dynamic_slice_in_dim(params["pos"], start, chunk)
+            h = params["embed"][tokens] + pos
 
         def kv_hook(kc, vc, q, k, v):
-            kc = kc.at[chunk_ids].set(
-                k[0].astype(kc.dtype).reshape(
-                    n_blk, bs, self.heads, self.head_dim))
-            vc = vc.at[chunk_ids].set(
-                v[0].astype(vc.dtype).reshape(
-                    n_blk, bs, self.heads, self.head_dim))
-
-            def gather(c):
-                g = c[table]               # (max_blocks, bs, h, dh)
-                return g.reshape(1, g.shape[0] * bs,
-                                 self.heads, self.head_dim)
-
-            att = chunk_attention(q, gather(kc), gather(vc), start,
-                                  use_pallas=self.use_pallas)
+            with jax.named_scope("veles.gpt.kv_write"):
+                kc = kc.at[chunk_ids].set(
+                    k[0].astype(kc.dtype).reshape(
+                        n_blk, bs, self.heads, self.head_dim))
+                vc = vc.at[chunk_ids].set(
+                    v[0].astype(vc.dtype).reshape(
+                        n_blk, bs, self.heads, self.head_dim))
+            with jax.named_scope("veles.gpt.attn"):
+                def gather(c):
+                    g = c[table]               # (max_blocks, bs, h, dh)
+                    return g.reshape(1, g.shape[0] * bs,
+                                     self.heads, self.head_dim)
+                att = chunk_attention(q, gather(kc), gather(vc), start,
+                                      use_pallas=self.use_pallas)
             return kc, vc, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)

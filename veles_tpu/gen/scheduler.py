@@ -48,7 +48,7 @@ class GenRequest(object):
                  "submitted", "first_token_at", "generated", "slot",
                  "finish_reason", "admit_seq", "preemptions", "ctx",
                  "queued_at", "admitted_at", "export_pages", "export",
-                 "rid")
+                 "rid", "req")
 
     def __init__(self, tokens, max_new_tokens, on_token=None,
                  ctx=None, export_pages=False, rid=None):
@@ -81,6 +81,9 @@ class GenRequest(object):
         #: preemption requeue) — the queue_wait phase span's begin
         self.queued_at = self.submitted
         self.admitted_at = None
+        #: small integer the scheduler gives at ``submit_request``:
+        #: the spans of one request share it in a profiler trace
+        self.req = -1
 
     def span_args(self, args=None):
         """``args`` tagged with this request's trace identity (the
@@ -158,6 +161,7 @@ class GenerativeScheduler(Logger):
         self.decode_steps = 0
         self.decode_slot_steps = 0   # active rows summed over steps
         self._admit_counter = 0
+        self._req_counter = 0        # GenRequest.req, under _cond
         #: submit → first streamed token (the prefill turnaround +
         #: queue wait): the latency generative SLOs are written against
         self.ttft = LatencyHistogram()
@@ -310,6 +314,8 @@ class GenerativeScheduler(Logger):
                 raise QueueFull(
                     "generation queue full (%d requests, limit %d)"
                     % (len(self._queue), self.max_queue))
+            self._req_counter += 1
+            request.req = self._req_counter
             self._queue.append(request)
             self._cond.notify()
         if trace.enabled():
@@ -552,6 +558,12 @@ class GenerativeScheduler(Logger):
         pool exhaustion, then one decode dispatch over the active set.
         Returns the amount of work done — tokens emitted plus chunks
         fed (0 = idle)."""
+        with trace.span("gen", "step", role="server") as span:
+            emitted = self._step()
+            span.set_metadata(emitted=emitted)
+        return emitted
+
+    def _step(self):
         emitted = 0
         decode_steps_before = self.decode_steps
         drain = None
@@ -620,7 +632,13 @@ class GenerativeScheduler(Logger):
                 # activate the request's trace context so the
                 # engine's own dispatch spans (prefill /
                 # prefill_chunk) carry its identity
-                with obs_context.activate(request.ctx):
+                with obs_context.activate(request.ctx), trace.span(
+                        "gen", "admit",
+                        {"req": request.req,
+                         "prompt": len(request.tokens),
+                         "queue_wait_us": int(1e6 * (
+                             time.perf_counter() - request.queued_at))},
+                        role="server"):
                     slot, token = self.engine.admit(request.prefix())
             except Exception as exc:  # noqa: BLE001 - per-request
                 # a failed admission must fail THIS request's future —
@@ -712,11 +730,16 @@ class GenerativeScheduler(Logger):
                 if result is not None:
                     out, active = result
                     self.decode_steps += 1
-                    self.decode_slot_steps += int(active.sum())
-                    for slot, request in list(self._active.items()):
-                        if active[slot]:
-                            self._emit(request, out[slot])
-                            emitted += 1
+                    n_active = int(active.sum())
+                    self.decode_slot_steps += n_active
+                    # token bookkeeping, on_token callbacks, finishes
+                    with trace.span("gen", "emit", {"n": n_active},
+                                    role="server"):
+                        for slot, request in list(
+                                self._active.items()):
+                            if active[slot]:
+                                self._emit(request, out[slot])
+                                emitted += 1
         from veles_tpu import watch
         if watch.enabled() \
                 and self.decode_steps != decode_steps_before \
@@ -763,7 +786,8 @@ class GenerativeScheduler(Logger):
                 if not self._queue and not self._active \
                         and not self._prefilling and not self._handoff \
                         and self._drain_future is None:
-                    self._cond.wait(0.05)
+                    with trace.span("gen", "idle", role="server"):
+                        self._cond.wait(0.05)
                     if self._stopped:
                         return
             try:

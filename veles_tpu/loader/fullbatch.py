@@ -247,6 +247,7 @@ class FullBatchLoader(Loader):
         from veles_tpu.stitch import StitchStage
         if not self.device_fast_path_active:
             return None
+        import jax
         import jax.numpy as jnp
 
         from veles_tpu.ops.gather import take_rows_norm
@@ -255,6 +256,7 @@ class FullBatchLoader(Loader):
         pads = {name: pad for name, _src, _out, pad in plan}
         norm = self.input_norm if self.native_device_dtype else None
 
+        @jax.named_scope("veles.loader.take_rows")
         def fn(t):
             offset = t["offset"].astype(jnp.int32)
             size = t["size"].astype(jnp.int32)

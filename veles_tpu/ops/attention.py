@@ -182,6 +182,7 @@ def _flash_fwd(q, k, v, causal=False, block_q=128, block_k=128,
         out, lse = pl.pallas_call(
             functools.partial(_attn_kernel, q_off=q_offset,
                               k_off=k_offset, **kw),
+            name="veles_flash_fwd",
             grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, scratch_shapes=scratch,
             compiler_params=params, interpret=interpret,
@@ -192,6 +193,7 @@ def _flash_fwd(q, k, v, causal=False, block_q=128, block_k=128,
         out, lse = pl.pallas_call(
             functools.partial(_attn_kernel_dyn, kernel=_attn_kernel,
                               **kw),
+            name="veles_flash_fwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid,
                 in_specs=[_dyn_spec(s) for s in in_specs],
@@ -378,6 +380,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
         dq3 = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, q_off=q_offset,
                               k_off=k_offset, **dq_kw),
+            name="veles_flash_bwd_dq",
             grid=(b * h, n_q, n_k), in_specs=dq_specs,
             out_specs=dq_out_spec, out_shape=dq_out_shape,
             scratch_shapes=dq_scratch, compiler_params=params,
@@ -386,6 +389,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
         dk3, dv3 = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, q_off=q_offset,
                               k_off=k_offset, **dkv_kw),
+            name="veles_flash_bwd_dkv",
             grid=(b * h, n_k, n_q), in_specs=dkv_specs,
             out_specs=dkv_out_specs, out_shape=dkv_out_shape,
             scratch_shapes=dkv_scratch, compiler_params=params,
@@ -398,6 +402,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
         dq3 = pl.pallas_call(
             functools.partial(_attn_kernel_dyn,
                               kernel=_bwd_dq_kernel, **dq_kw),
+            name="veles_flash_bwd_dq",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(b * h, n_q, n_k),
                 in_specs=[_dyn(s) for s in dq_specs],
@@ -409,6 +414,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
         dk3, dv3 = pl.pallas_call(
             functools.partial(_attn_kernel_dyn,
                               kernel=_bwd_dkv_kernel, **dkv_kw),
+            name="veles_flash_bwd_dkv",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(b * h, n_k, n_q),
                 in_specs=[_dyn(s) for s in dkv_specs],
@@ -532,6 +538,7 @@ def _decode_pallas(q, k, v, lengths, block_k=128, interpret=False,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, n_k=n_k, scale=scale,
                           block_k=bk, heads=h, row_step=row_step),
+        name="veles_attn_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_spec, scratch_shapes=scratch),
@@ -697,6 +704,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, tables, lengths,
         functools.partial(_paged_decode_kernel, n_b=n_b, scale=scale,
                           block_size=block_size, heads=h,
                           row_step=row_step),
+        name="veles_attn_paged_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
             out_specs=out_spec, scratch_shapes=scratch),

@@ -100,6 +100,7 @@ def _norm_row(v, f):
 
 
 @jax.jit
+@jax.named_scope("veles.loader.take_rows_norm")
 def _gather_norm_jnp(data, indices, scale, shift):
     taken = jnp.take(data, jnp.maximum(indices, 0), axis=0)
     flat = taken.reshape(taken.shape[0], -1).astype(jnp.float32)
@@ -148,6 +149,7 @@ def _gather_norm_pallas(data, indices, scale, shift, interpret=False):
     )
     out = pl.pallas_call(
         _gather_norm_kernel,
+        name="veles_gather_norm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, f), jnp.float32),
         interpret=interpret,
@@ -157,9 +159,11 @@ def _gather_norm_pallas(data, indices, scale, shift, interpret=False):
 
 
 @jax.jit
+@jax.named_scope("veles.loader.take_rows")
 def _gather_jnp(data, indices):
     # jitted: the eager form is 3 separate op dispatches per minibatch;
-    # one compiled program per (shape, dtype) serves every batch
+    # one compiled program per (shape, dtype) serves every batch.  The
+    # scope names the gather's device operations wherever it is fused
     taken = jnp.take(data, jnp.maximum(indices, 0), axis=0)
     mask = (indices >= 0).reshape((-1,) + (1,) * (data.ndim - 1))
     return jnp.where(mask, taken, 0)
@@ -201,6 +205,7 @@ def _gather_pallas(data, indices, interpret=False):
     )
     out = pl.pallas_call(
         _gather_kernel,
+        name="veles_gather",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, f), data.dtype),
         interpret=interpret,

@@ -104,6 +104,7 @@ def _matmul_pallas(a, b, bias, activation=None, tiles=None, out_dtype=None,
     out = pl.pallas_call(
         functools.partial(_matmul_kernel, n_k=n_k, activation=activation,
                           has_bias=has_bias),
+        name="veles_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -391,6 +392,7 @@ def gd_fused_pallas(x, y, err_output, w, b, vw, vb, lr, lr_bias, decay,
             functools.partial(_gd_dx_kernel, n_k=np_ // bn,
                               activation=activation,
                               transposed=transposed),
+            name="veles_gd_err_input",
             grid=(bp // bk, fp // bf, np_ // bn),
             in_specs=[
                 pl.BlockSpec((bk, bn), lambda i, j, kk: (i, kk)),
@@ -413,6 +415,7 @@ def gd_fused_pallas(x, y, err_output, w, b, vw, vb, lr, lr_bias, decay,
     w_new, vw_new = pl.pallas_call(
         functools.partial(_gd_dw_kernel, n_k=n_kb,
                           activation=activation, transposed=transposed),
+        name="veles_gd_update_w",
         grid=(fp // bf, np_ // bn, n_kb),
         in_specs=[
             pl.BlockSpec((bk, bf), lambda i, j, kk: (kk, i)),
@@ -441,6 +444,7 @@ def gd_fused_pallas(x, y, err_output, w, b, vw, vb, lr, lr_bias, decay,
         b_new, vb_new = pl.pallas_call(
             functools.partial(_gd_db_kernel, n_k=n_kb,
                               activation=activation),
+            name="veles_gd_update_b",
             grid=(np_ // bn, n_kb),
             in_specs=[
                 pl.BlockSpec((bk, bn), lambda i, kk: (kk, i)),

@@ -107,6 +107,7 @@ def _qmatmul_pallas(a, q, scale, bias, activation=None, tiles=None,
     out = pl.pallas_call(
         functools.partial(_qmatmul_kernel, n_k=n_k,
                           activation=activation, has_bias=has_bias),
+        name="veles_qmatmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -80,6 +80,7 @@ def _uniform_pallas_tpu(seed, shape, dtype=jnp.float32, low=0.0,
     rows = shape2[0] // bm
     out = pl.pallas_call(
         functools.partial(_uniform_kernel, low=low, high=high),
+        name="veles_uniform",
         grid=(rows,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((bm,) + shape2[1:],

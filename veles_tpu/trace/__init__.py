@@ -31,15 +31,19 @@ watch      training-health boundary fetches: ``health_check``
 =========  ==========================================================
 
 The knob: ``root.common.engine.trace = off | on | <path.json>`` —
-``off`` (default) costs a single attribute check per hook; ``on``
-records into the fixed-capacity ring (wraparound keeps the newest
-spans); a path additionally writes the Perfetto-loadable JSON at
-process exit.  :func:`device_trace` bridges to ``jax.profiler`` when a
-real accelerator is present.
+``off`` (default) records nothing; ``on`` records into the
+fixed-capacity ring (wraparound keeps the newest spans); a path
+additionally writes the Perfetto-loadable JSON at process exit.
+
+One API, two sinks: whatever the knob says, every :func:`span` is also
+a profiler annotation ``veles:<cat>/<name>`` (inert without a profiler
+session), so a ``jax.profiler`` trace — the benchmark's, or the one
+:func:`device_trace` takes for an operator — holds the program's own
+spans on the device's clock.
 """
 
 from veles_tpu.trace.core import (  # noqa: F401
-    DEFAULT_CAPACITY, NULL_SPAN, TraceRecorder, complete, configure,
+    ANNOTATION_PREFIX, DEFAULT_CAPACITY, TraceRecorder, complete, configure,
     counter, device_trace, enabled, instant, recorder, set_role, span)
 from veles_tpu.trace.export import (  # noqa: F401
     chrome_events, load, metrics_text, report_text, save, summary)

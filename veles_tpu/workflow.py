@@ -211,8 +211,8 @@ class Workflow(Unit):
         from veles_tpu.obs import blackbox
         from veles_tpu.units import MissingDemandedAttributes
         # honor the root.common.engine.trace knob per initialize (the
-        # natural "a run starts here" boundary — off stays a single
-        # attribute check in every hook); the flight-recorder knob
+        # natural "a run starts here" boundary — off records nothing
+        # in any hook); the flight-recorder knob
         # (root.common.obs.blackbox_dir) and the telemetry-bus knob
         # (root.common.watch.endpoint) arm at the same boundary
         trace.configure()

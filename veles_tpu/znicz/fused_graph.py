@@ -31,6 +31,16 @@ def _remat_stage(pure, config):
     return wrapped
 
 
+def _scoped_stage(pure, scope):
+    """``pure`` under ``jax.named_scope(scope)``: the stage's device
+    operations carry ``veles.layer.<nn>.<kind>`` in their ``op_name``
+    (and, under ``value_and_grad``, its backward ones
+    ``transpose(jvp(veles.layer.<nn>.<kind>))``), whichever of
+    ``loss_fn``, ``apply_fn`` and the eval path calls it.  Metadata
+    only: the program XLA builds is the same."""
+    return jax.named_scope(scope)(pure)
+
+
 def default_lr(solver):
     """The canonical learning rate when a spec omits it — adadelta's
     update is self-scaling, so its lr is a plain 1.0 gain.  The ONE
@@ -138,7 +148,7 @@ def lower_specs(layer_specs, sample_shape, loss="softmax",
     units = probe_units(layer_specs, sample_shape)
     stages = []      # (pure_fn, config_dict, hyper_dict, skip_at_eval)
     params = []
-    for spec, unit in zip(layer_specs, units):
+    for index, (spec, unit) in enumerate(zip(layer_specs, units)):
         layer_params = unit.pure_params(host=True)
         layer_params = {k: numpy.array(v) for k, v in
                         layer_params.items()}
@@ -190,6 +200,8 @@ def lower_specs(layer_specs, sample_shape, loss="softmax",
             # static config is bound BEFORE checkpointing so the
             # rematerialized callable is (params, x) -> out
             pure = _remat_stage(pure, unit.pure_config())
+        pure = _scoped_stage(pure, "veles.layer.%02d.%s"
+                             % (index, spec["type"]))
         stages.append((pure, unit.pure_config(), hyper,
                        bool(getattr(type(unit), "SKIP_AT_EVAL", False))))
         state = {k: v for k, v in layer_params.items()}
@@ -229,6 +241,7 @@ def lower_specs(layer_specs, sample_shape, loss="softmax",
                 prng.get("dropout").randint(0, 2 ** 30))
         params.append(state)
 
+    @jax.named_scope("veles.ingest")
     def _ingest(x):
         """Entry cast + optional fused affine normalization (see
         ``input_norm`` in the docstring)."""
@@ -274,6 +287,10 @@ def lower_specs(layer_specs, sample_shape, loss="softmax",
                 p = dict(wb)
             p.update(aux)
             h = pure(p, h, **config)
+        return loss_of(h, x, labels)
+
+    @jax.named_scope("veles.loss")
+    def loss_of(h, x, labels):
         out = jnp.asarray(h, jnp.float32)
         valid = labels >= 0 if loss == "softmax" \
             else jnp.ones(x.shape[0], bool)
@@ -360,6 +377,13 @@ def lower_specs(layer_specs, sample_shape, loss="softmax",
             n_err = (jax.lax.psum(n_err, grad_reduce_axis)
                      if loss == "softmax"
                      else jax.lax.pmean(n_err, grad_reduce_axis))
+        return update_fn(params_list, grads), {"loss": report,
+                                               "n_err": n_err}
+
+    @jax.named_scope("veles.update")
+    def update_fn(params_list, grads):
+        """The solver section of ``step_fn``, under a scope of its own
+        (XLA fuses most of it into the weight-gradient fusions)."""
         new_list = []
         for state, gwb, (_pure, _config, hyper, _skip) in zip(
                 params_list, grads, stages):
@@ -434,7 +458,7 @@ def lower_specs(layer_specs, sample_shape, loss="softmax",
             if "tick" in state:
                 new_state["tick"] = state["tick"] + jnp.int32(1)
             new_list.append(new_state)
-        return new_list, {"loss": report, "n_err": n_err}
+        return new_list
 
     def eval_fn(params_list, x, labels):
         out = apply_fn(params_list, x, train=False)

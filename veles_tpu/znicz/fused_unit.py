@@ -19,6 +19,7 @@ it.
 
 import numpy
 
+from veles_tpu import trace
 from veles_tpu.accelerated_units import AcceleratedUnit
 from veles_tpu.loader.base import TRAIN
 
@@ -351,22 +352,32 @@ class FusedTrainer(AcceleratedUnit):
                 if bool(self.loader.last_minibatch):
                     self.sync_weights()
                 return
-        x = self.loader.minibatch_data.devmem[:n]
-        labels = self._labels(n)
-        if self._batch_shard_ is not None:
-            import jax
-            shard = self._batch_shard_ if train else self._rep_shard_
-            x = jax.device_put(x, shard)
-            labels = jax.device_put(labels, shard)
+        # the step's phases as the host sees them (docs/observability
+        # .md): dispatch is the call's return, wait the host blocked
+        # on the device
+        phase = {"train": int(train)}
+        with trace.span("fused", "labels"):
+            x = self.loader.minibatch_data.devmem[:n]
+            labels = self._labels(n)
+            if self._batch_shard_ is not None:
+                import jax
+                shard = self._batch_shard_ if train \
+                    else self._rep_shard_
+                x = jax.device_put(x, shard)
+                labels = jax.device_put(labels, shard)
         if train:
-            self._params_, metrics = self._step_(self._params_, x,
-                                                 labels)
-            err = float(metrics["n_err"])
-            self.loss_value = float(metrics["loss"])
+            with trace.span("fused", "dispatch", phase):
+                self._params_, metrics = self._step_(self._params_, x,
+                                                     labels)
+            with trace.span("fused", "wait", phase):
+                err = float(metrics["n_err"])
+                self.loss_value = float(metrics["loss"])
         else:
-            ev = self._eval_(self._params_, x, labels)
-            err = float(ev["n_err"] if self.loss != "mse"
-                        else ev["rmse"])
+            with trace.span("fused", "dispatch", phase):
+                ev = self._eval_(self._params_, x, labels)
+            with trace.span("fused", "wait", phase):
+                err = float(ev["n_err"] if self.loss != "mse"
+                            else ev["rmse"])
         if self.loss == "mse":
             self.mse = err
         else:
@@ -472,17 +483,19 @@ class FusedTrainer(AcceleratedUnit):
         self._params_ = refreshed
 
     def sync_weights(self):
-        """Write the fused params back into the forward units."""
+        """Write the fused params back into the forward units (the
+        epoch boundary's D2H)."""
         if self._params_ is None:
             return
-        for fwd, state in zip(self.forwards, self._params_):
-            w = state.get("w")
-            if w is not None and fwd.weights:
-                fwd.weights.map_write()
-                fwd.weights.mem[...] = numpy.asarray(
-                    w, dtype=fwd.weights.mem.dtype)
-            b = state.get("b")
-            if b is not None and fwd.bias:
-                fwd.bias.map_write()
-                fwd.bias.mem[...] = numpy.asarray(
-                    b, dtype=fwd.bias.mem.dtype)
+        with trace.span("fused", "sync_weights"):
+            for fwd, state in zip(self.forwards, self._params_):
+                w = state.get("w")
+                if w is not None and fwd.weights:
+                    fwd.weights.map_write()
+                    fwd.weights.mem[...] = numpy.asarray(
+                        w, dtype=fwd.weights.mem.dtype)
+                b = state.get("b")
+                if b is not None and fwd.bias:
+                    fwd.bias.map_write()
+                    fwd.bias.mem[...] = numpy.asarray(
+                        b, dtype=fwd.bias.mem.dtype)
