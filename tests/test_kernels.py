@@ -442,11 +442,13 @@ def test_decode_lowers_with_gpt_scopes_and_none_on_the_scan():
     scopes, locs = _scope_names(lowered)
     assert scopes == {"veles.gpt." + part for part in (
         "embed", "qkv", "kv_write", "attn", "proj", "mlp", "readout")}
-    # the scan, and what it adds to move the cache through the layers
-    # (the slices of xs, the write-back of ys), stay outside every name
+    # the layer loop and its bookkeeping (the slices of the weights it
+    # scans over) stay outside every name; the cache is its carry, so
+    # the loop itself writes nothing back: no stacking of ys
     loops = [loc for loc in locs if "while" in loc or "scan" in loc]
     assert any(loc.endswith("/while") for loc in loops), loops
-    assert any(loc.endswith("/dynamic_update_slice") for loc in loops)
+    assert any(loc.endswith("/dynamic_slice") for loc in loops), loops
+    assert not any(loc.endswith("/dynamic_update_slice") for loc in loops)
     assert not any("veles." in loc for loc in loops), loops
 
 

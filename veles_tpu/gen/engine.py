@@ -660,20 +660,10 @@ class GenerativeEngine(Logger):
             proposer = self.proposer
             model = proposer.model
             window = proposer.window
-            cd = model.compute_dtype
 
             def draft_next(params, tokens, length):
                 h = params["embed"][tokens] + params["pos"][:window]
-                cache = {
-                    "k": jnp.zeros((model.layers, 1, 1, model.heads,
-                                    model.head_dim), cd),
-                    "v": jnp.zeros((model.layers, 1, 1, model.heads,
-                                    model.head_dim), cd)}
-
-                def kv_hook(kc, vc, q, k, v):
-                    return kc, vc, model._attend_prefill(q, k, v)
-
-                h, _ = model._run_layers(params, cache, h, kv_hook)
+                h = model._forward_cacheless(params, h)
                 return model._greedy_at(params, h, length - 1)
 
             self._draft_exe = self._compile_aux(

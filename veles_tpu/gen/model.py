@@ -225,23 +225,32 @@ class TransformerGenModel(object):
         return down
 
     def _run_layers(self, params, cache, h, kv_hook):
-        """Scan the block stack with the ONE shared layer body.
-        ``kv_hook(kc, vc, q, k, v) -> (kc', vc', att)`` is the only
-        thing the six entry points differ in — where this layer's K/V
-        land (slot slice, page scatter, chunk window) and what the
-        attention reads (the chunk itself, the masked cache, the
-        table-gathered pool).  One body means a layer-math change can
-        never desynchronize the paged==contiguous parity pair — and
-        the int8 deploy rides the same body: a quantized block weight
-        (``veles_tpu.quant`` pair, detected per leaf at trace time)
-        routes its matmul through :meth:`_qmm` while the float path
-        stays byte-identical, so EVERY entry point (prefill, decode,
-        paged, chunked) serves quantized without its own fork.
+        """Loop over the block stack with the ONE shared layer body,
+        the KV cache riding the loop as its CARRY: the same
+        ``[L, ...]`` buffers enter and leave, so with the engine's
+        donation the compiled program aliases cache-in to cache-out
+        and a layer writes only the rows it owns, in place.
+        ``kv_hook(i, cache, q, k, v) -> (cache', att)`` is the only
+        thing the eight entry points differ in: ``i`` is the layer's
+        (traced) index into the cache's leading axis, ``cache`` the
+        WHOLE arrays (or None for a forward that keeps none) — where
+        this layer's K/V land (slot slice, page scatter, chunk window)
+        and what the attention reads after the write (the chunk
+        itself, layer ``i``'s masked cache, its table-gathered pool).
+        A hook never writes a whole layer's slab back.  One body means
+        a layer-math change can never desynchronize the
+        paged==contiguous parity pair — and the int8 deploy rides the
+        same body: a quantized block weight (``veles_tpu.quant`` pair,
+        detected per leaf at trace time) routes its matmul through
+        :meth:`_qmm` while the float path stays byte-identical, so
+        EVERY entry point (prefill, decode, paged, chunked) serves
+        quantized without its own fork.
         Returns ``(h_final_normed, cache')``."""
         cd = self.compute_dtype
 
-        def layer(h, xs):
-            blk, kc, vc = xs
+        def layer(carry, xs):
+            h, cache = carry
+            blk, i = xs
             b_, s_ = h.shape[0], h.shape[1]
             with jax.named_scope("veles.gpt.qkv"):
                 x = _layernorm(h, blk["ln1_g"], blk["ln1_b"])
@@ -254,7 +263,7 @@ class TransformerGenModel(object):
                     qkv = jnp.einsum("bsd,dchx->bschx", x.astype(cd),
                                      blk["wqkv"].astype(cd))
                 q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            kc, vc, att = kv_hook(kc, vc, q, k, v)
+            cache, att = kv_hook(i, cache, q, k, v)
             with jax.named_scope("veles.gpt.proj"):
                 if isinstance(blk["wo"], dict):
                     proj = self._qmm(
@@ -266,23 +275,37 @@ class TransformerGenModel(object):
                 h = h + proj.astype(h.dtype)
             with jax.named_scope("veles.gpt.mlp"):
                 h = h + self._mlp(h, blk).astype(h.dtype)
-            return h, (kc, vc)
+            return (h, cache), None
 
-        # the scan itself gets NO scope: what it adds to move the cache
-        # through the layers stays outside every name, and is counted
-        # as what is left over (docs/observability.md)
-        h, (ks, vs) = jax.lax.scan(
-            layer, h, (params["blocks"], cache["k"], cache["v"]))
+        # the loop itself gets NO scope.  The cache's writes sit under
+        # veles.gpt.kv_write and its reads under veles.gpt.attn, so
+        # what no name covers is the loop's bookkeeping alone — and a
+        # whole-cache movement that comes back shows there first
+        # (engine.decode_unscoped_ms; docs/observability.md)
+        (h, cache), _ = jax.lax.scan(
+            layer, (h, cache),
+            (params["blocks"], jnp.arange(self.layers)))
         with jax.named_scope("veles.gpt.readout"):
             h = _layernorm(h, params["lnf_g"], params["lnf_b"])
-        return h, {"k": ks, "v": vs}
+        return h, cache
+
+    def _forward_cacheless(self, params, h):
+        """The block stack over ``h`` (1, S, d) with causal
+        self-attention and NO cache (the loop's carry holds ``h``
+        alone) — the calibration probe's and the draft proposer's
+        forward."""
+        def kv_hook(i, cache, q, k, v):
+            with jax.named_scope("veles.gpt.attn"):
+                return cache, self._attend_prefill(q, k, v)
+
+        return self._run_layers(params, None, h, kv_hook)[0]
 
     def calibration_logits(self, params, tokens):
         """Last-position logits of ONE prompt through the same shared
         ``_run_layers`` body the engine serves from — the float-vs-
         int8 calibration probe (:func:`veles_tpu.quant
-        .quantize_gen_params` gates relative drift on it).  Uses a
-        throwaway single-slot cache; nothing is retained."""
+        .quantize_gen_params` gates relative drift on it).  No cache
+        is kept."""
         tokens = jnp.asarray(tokens, jnp.int32).reshape(1, -1)
         s = tokens.shape[1]
         cd = self.compute_dtype
@@ -290,16 +313,7 @@ class TransformerGenModel(object):
         with jax.named_scope("veles.gpt.embed"):
             h = embed[tokens] + jnp.asarray(params["pos"])[:s]
 
-        def kv_hook(kc, vc, q, k, v):
-            with jax.named_scope("veles.gpt.attn"):
-                att = self._attend_prefill(q, k, v)
-            return kc, vc, att
-
-        cache = {"k": jnp.zeros((self.layers, 1, 1, self.heads,
-                                 self.head_dim), cd),
-                 "v": jnp.zeros((self.layers, 1, 1, self.heads,
-                                 self.head_dim), cd)}
-        h, _cache = self._run_layers(params, cache, h, kv_hook)
+        h = self._forward_cacheless(params, h)
         with jax.named_scope("veles.gpt.readout"):
             return jnp.einsum("d,vd->v", h[0, -1].astype(cd),
                               embed.astype(cd)).astype(jnp.float32)
@@ -349,15 +363,15 @@ class TransformerGenModel(object):
         with jax.named_scope("veles.gpt.embed"):
             h = params["embed"][tokens] + params["pos"][:bucket]
 
-        def kv_hook(kc, vc, q, k, v):
+        def kv_hook(i, cache, q, k, v):
             with jax.named_scope("veles.gpt.attn"):
                 att = self._attend_prefill(q, k, v)
             with jax.named_scope("veles.gpt.kv_write"):
-                kc = jax.lax.dynamic_update_slice(
-                    kc, k[0].astype(kc.dtype)[None], (slot, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    vc, v[0].astype(vc.dtype)[None], (slot, 0, 0, 0))
-            return kc, vc, att
+                def put(c, new):       # [i, slot, :bucket] <- the prompt
+                    return jax.lax.dynamic_update_slice(
+                        c, new.astype(c.dtype)[None], (i, slot, 0, 0, 0))
+                cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
+            return cache, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
         return cache, self._greedy_at(params, h, length - 1)
@@ -379,18 +393,18 @@ class TransformerGenModel(object):
         idx = jnp.arange(slots)
         keep = active[:, None, None]
 
-        def kv_hook(kc, vc, q, k, v):
+        def kv_hook(i, cache, q, k, v):
             with jax.named_scope("veles.gpt.kv_write"):
-                kc = kc.at[idx, positions].set(
-                    jnp.where(keep, k[:, 0].astype(kc.dtype),
-                              kc[idx, positions]))
-                vc = vc.at[idx, positions].set(
-                    jnp.where(keep, v[:, 0].astype(vc.dtype),
-                              vc[idx, positions]))
+                def put(c, new):       # one row a slot, inactive: as it was
+                    return c.at[i, idx, positions].set(
+                        jnp.where(keep, new[:, 0].astype(c.dtype),
+                                  c[i, idx, positions]))
+                cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
             with jax.named_scope("veles.gpt.attn"):
-                att = decode_attention(q, kc, vc, positions + 1,
+                att = decode_attention(q, cache["k"][i], cache["v"][i],
+                                       positions + 1,
                                        use_pallas=self.use_pallas)
-            return kc, vc, att
+            return cache, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
         return cache, self._greedy_rows(params, h)
@@ -410,17 +424,16 @@ class TransformerGenModel(object):
         with jax.named_scope("veles.gpt.embed"):
             h = params["embed"][tokens] + params["pos"][:bucket]
 
-        def kv_hook(kc, vc, q, k, v):
+        def kv_hook(i, cache, q, k, v):
             with jax.named_scope("veles.gpt.attn"):
                 att = self._attend_prefill(q, k, v)
             with jax.named_scope("veles.gpt.kv_write"):
-                kc = kc.at[block_ids].set(
-                    k[0].astype(kc.dtype).reshape(
-                        n_blk, bs, self.heads, self.head_dim))
-                vc = vc.at[block_ids].set(
-                    v[0].astype(vc.dtype).reshape(
-                        n_blk, bs, self.heads, self.head_dim))
-            return kc, vc, att
+                def put(c, new):       # the prompt's pages of layer i
+                    return c.at[i, block_ids].set(
+                        new[0].astype(c.dtype).reshape(
+                            n_blk, bs, self.heads, self.head_dim))
+                cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
+            return cache, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
         return cache, self._greedy_at(params, h, length - 1)
@@ -444,15 +457,18 @@ class TransformerGenModel(object):
         blk_idx = jnp.where(active, tables[idx, positions // bs], 0)
         blk_off = jnp.where(active, positions % bs, 0)
 
-        def kv_hook(kc, vc, q, k, v):
+        def kv_hook(i, cache, q, k, v):
             with jax.named_scope("veles.gpt.kv_write"):
-                kc = kc.at[blk_idx, blk_off].set(k[:, 0].astype(kc.dtype))
-                vc = vc.at[blk_idx, blk_off].set(v[:, 0].astype(vc.dtype))
+                def put(c, new):
+                    return c.at[i, blk_idx, blk_off].set(
+                        new[:, 0].astype(c.dtype))
+                cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
             with jax.named_scope("veles.gpt.attn"):
-                att = paged_decode_attention(q, kc, vc, tables,
+                att = paged_decode_attention(q, cache["k"][i],
+                                             cache["v"][i], tables,
                                              positions + 1,
                                              use_pallas=self.use_pallas)
-            return kc, vc, att
+            return cache, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
         return cache, self._greedy_rows(params, h)
@@ -489,18 +505,18 @@ class TransformerGenModel(object):
         safe = jnp.where(keep, gpos, 0)
         rows = jnp.broadcast_to(idx[:, None], (slots, kp1))
 
-        def kv_hook(kc, vc, q, k, v):
+        def kv_hook(i, cache, q, k, v):
             with jax.named_scope("veles.gpt.kv_write"):
-                kc = kc.at[rows, safe].set(
-                    jnp.where(keep[..., None, None], k.astype(kc.dtype),
-                              kc[rows, safe]))
-                vc = vc.at[rows, safe].set(
-                    jnp.where(keep[..., None, None], v.astype(vc.dtype),
-                              vc[rows, safe]))
+                def put(c, new):
+                    return c.at[i, rows, safe].set(
+                        jnp.where(keep[..., None, None],
+                                  new.astype(c.dtype), c[i, rows, safe]))
+                cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
             with jax.named_scope("veles.gpt.attn"):
-                att = verify_attention(q, kc, vc, positions + 1,
+                att = verify_attention(q, cache["k"][i], cache["v"][i],
+                                       positions + 1,
                                        use_pallas=self.use_pallas)
-            return kc, vc, att
+            return cache, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
         return cache, self._greedy_grid(params, h)
@@ -526,15 +542,18 @@ class TransformerGenModel(object):
         blk_idx = jnp.where(keep, tables[idx[:, None], safe // bs], 0)
         blk_off = jnp.where(keep, safe % bs, 0)
 
-        def kv_hook(kc, vc, q, k, v):
+        def kv_hook(i, cache, q, k, v):
             with jax.named_scope("veles.gpt.kv_write"):
-                kc = kc.at[blk_idx, blk_off].set(k.astype(kc.dtype))
-                vc = vc.at[blk_idx, blk_off].set(v.astype(vc.dtype))
+                def put(c, new):
+                    return c.at[i, blk_idx, blk_off].set(
+                        new.astype(c.dtype))
+                cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
             with jax.named_scope("veles.gpt.attn"):
-                att = paged_verify_attention(q, kc, vc, tables,
+                att = paged_verify_attention(q, cache["k"][i],
+                                             cache["v"][i], tables,
                                              positions + 1,
                                              use_pallas=self.use_pallas)
-            return kc, vc, att
+            return cache, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
         return cache, self._greedy_grid(params, h)
@@ -554,20 +573,20 @@ class TransformerGenModel(object):
             pos = jax.lax.dynamic_slice_in_dim(params["pos"], start, chunk)
             h = params["embed"][tokens] + pos
 
-        def kv_hook(kc, vc, q, k, v):
+        def kv_hook(i, cache, q, k, v):
             with jax.named_scope("veles.gpt.kv_write"):
-                kc = jax.lax.dynamic_update_slice(
-                    kc, k[0].astype(kc.dtype)[None], (slot, start, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    vc, v[0].astype(vc.dtype)[None], (slot, start, 0, 0))
+                def put(c, new):       # [i, slot, start:start+C] <- chunk
+                    return jax.lax.dynamic_update_slice(
+                        c, new.astype(c.dtype)[None],
+                        (i, slot, start, 0, 0))
+                cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
             with jax.named_scope("veles.gpt.attn"):
-                kf = jax.lax.dynamic_slice(
-                    kc, (slot, 0, 0, 0), (1,) + kc.shape[1:])
-                vf = jax.lax.dynamic_slice(
-                    vc, (slot, 0, 0, 0), (1,) + vc.shape[1:])
-                att = chunk_attention(q, kf, vf, start,
-                                      use_pallas=self.use_pallas)
-            return kc, vc, att
+                def row(c):            # the slot's whole row of layer i
+                    return jax.lax.dynamic_slice(
+                        c, (i, slot, 0, 0, 0), (1, 1) + c.shape[2:])[0]
+                att = chunk_attention(q, row(cache["k"]), row(cache["v"]),
+                                      start, use_pallas=self.use_pallas)
+            return cache, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
         return cache, self._greedy_at(params, h, chunk_len - 1)
@@ -587,22 +606,22 @@ class TransformerGenModel(object):
             pos = jax.lax.dynamic_slice_in_dim(params["pos"], start, chunk)
             h = params["embed"][tokens] + pos
 
-        def kv_hook(kc, vc, q, k, v):
+        def kv_hook(i, cache, q, k, v):
             with jax.named_scope("veles.gpt.kv_write"):
-                kc = kc.at[chunk_ids].set(
-                    k[0].astype(kc.dtype).reshape(
-                        n_blk, bs, self.heads, self.head_dim))
-                vc = vc.at[chunk_ids].set(
-                    v[0].astype(vc.dtype).reshape(
-                        n_blk, bs, self.heads, self.head_dim))
+                def put(c, new):       # the chunk's pages of layer i
+                    return c.at[i, chunk_ids].set(
+                        new[0].astype(c.dtype).reshape(
+                            n_blk, bs, self.heads, self.head_dim))
+                cache = {"k": put(cache["k"], k), "v": put(cache["v"], v)}
             with jax.named_scope("veles.gpt.attn"):
                 def gather(c):
-                    g = c[table]               # (max_blocks, bs, h, dh)
+                    g = c[i, table]            # (max_blocks, bs, h, dh)
                     return g.reshape(1, g.shape[0] * bs,
                                      self.heads, self.head_dim)
-                att = chunk_attention(q, gather(kc), gather(vc), start,
+                att = chunk_attention(q, gather(cache["k"]),
+                                      gather(cache["v"]), start,
                                       use_pallas=self.use_pallas)
-            return kc, vc, att
+            return cache, att
 
         h, cache = self._run_layers(params, cache, h, kv_hook)
         return cache, self._greedy_at(params, h, chunk_len - 1)
