@@ -243,3 +243,58 @@ def test_alexnet_fused_step_compiles_for_v5e_and_fits(chip, topo):
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < hbm, "AlexNet step needs %d bytes of %d" % (used,
                                                                   hbm)
+
+
+def _hybrid_program(which, chip):
+    """The benchmark's hybrid configuration as the GenerativeEngine
+    compiles it: 64 slots, 2048 positions, bf16, the cache donated."""
+    import json
+    import os
+
+    from benchmarks.drivers import serve_hybrid
+    from veles_tpu.gen import HybridGenModel
+    from veles_tpu.samples import hybrid_lm
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "nemotron3_super_120b_a12b.json")
+    with open(path) as handle:
+        config = json.load(handle)
+    pcfg = serve_hybrid.program_config(config)
+    model = HybridGenModel(pcfg, compute_dtype=bf16)
+    params = chip.tree(hybrid_lm.param_shapes(pcfg, bf16))
+    slots = config["engine"]["max_slots"]
+    cache = chip.tree(jax.eval_shape(functools.partial(
+        model.init_cache, slots, config["engine"]["max_seq"])))
+    if which == "decode":
+        args = (params, cache, chip((slots,), i32), chip((slots,), i32),
+                chip((slots,), jnp.bool_))
+        fn = model.decode
+    else:
+        args = (params, cache, chip((1, which), i32), chip((), i32),
+                chip((), i32))
+        fn = model.prefill
+    return model, jax.jit(fn, donate_argnums=(1,)).lower(*args)
+
+
+@pytest.mark.parametrize("which", ["decode", 1024])
+def test_hybrid_programs_compile_for_v5e_fit_and_write_in_place(
+        which, chip, topo):
+    """Mamba-2 + latent experts + grouped-query attention at published
+    widths, one chip's share: the program fits the v5e's HBM beside its
+    9.3 GB of weights, the whole 1.5 GB cache (recurrent state, the
+    convolution's tails, K and V) is aliased in to out, and the long
+    prefill takes the grouped expert product."""
+    from veles_tpu.backends import device_hbm_bytes
+    model, lowered = _hybrid_program(which, chip)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = model.cache_nbytes(64, 2048)
+    assert 1.4e9 < cache_bytes < 1.6e9
+    assert mem.alias_size_in_bytes == cache_bytes
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 10.5e9 < used < 15e9 < device_hbm_bytes(
+        topo.devices[0].device_kind)
+    assert mem.temp_size_in_bytes < 0.6e9
+    text = compiled.as_text()
+    assert ("ragged-dot" in text) == (which != "decode")

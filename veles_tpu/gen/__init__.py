@@ -9,6 +9,11 @@ KV cache with iteration-level scheduling.  Pieces:
   step over a slot-major KV cache) and
   :class:`~veles_tpu.gen.model.TransformerGenModel`, the adapter for
   the ``samples/transformer.py`` LM family.
+- :mod:`hybrid` — :class:`~veles_tpu.gen.hybrid.HybridGenModel`: a
+  second model behind the same protocol, with three kinds of layer in
+  one stack (Mamba-2 mixers, a chip's share of a latent mixture of
+  experts, grouped-query attention) and a cache of two kinds of state
+  (recurrent state beside keys and values); contiguous mode only.
 - :mod:`engine` — :class:`~veles_tpu.gen.engine.GenerativeEngine`:
   AOT-compiled prefill buckets + ONE fixed-shape decode program,
   KV cache in the HBM ledger's ``kv`` category, tensor-parallel
@@ -50,6 +55,7 @@ mixed-length closed-loop session with ZERO steady-state compiles.
 from veles_tpu.gen.engine import (  # noqa: F401
     DRAFT_MODELS, DraftModelProposer, GenerativeEngine, NGramProposer,
     register_draft_model)
+from veles_tpu.gen.hybrid import HybridGenModel  # noqa: F401
 from veles_tpu.gen.model import TransformerGenModel  # noqa: F401
 from veles_tpu.gen.paged import BlockPool, PoolExhausted  # noqa: F401
 from veles_tpu.gen.prefix import PrefixCache  # noqa: F401
@@ -58,7 +64,8 @@ from veles_tpu.gen.scheduler import (  # noqa: F401
 
 __all__ = [
     "BlockPool", "DRAFT_MODELS", "DraftModelProposer",
-    "GenerativeEngine", "GenerativeScheduler", "NGramProposer",
+    "GenerativeEngine", "GenerativeScheduler", "HybridGenModel",
+    "NGramProposer",
     "PoolExhausted", "PrefixCache", "TransformerGenModel",
     "register_draft_model", "static_generate",
 ]

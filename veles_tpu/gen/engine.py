@@ -215,10 +215,6 @@ class GenerativeEngine(Logger):
             raise ValueError(
                 "root.common.gen.prefix_cache must be 'on' or 'off', "
                 "got %r" % (pc,))
-        if self.prefix_cache and self.kv_mode != "paged":
-            raise ValueError(
-                "prefix_cache requires kv='paged' — the contiguous "
-                "engine has no shareable pages")
         spec = speculative if speculative is not None \
             else gen_cfg.get("speculative", "off")
         if spec in (False, None, "off"):
@@ -232,6 +228,22 @@ class GenerativeEngine(Logger):
             raise ValueError(
                 "draft_k must be 1..7 (the K+1 verify query rows ride "
                 "one 8-sublane tile), got %d" % self.draft_k)
+
+        #: a model that keeps recurrent state beside (or instead of)
+        #: keys and values: nothing that assumes K/V pages may hold it
+        self.recurrent = bool(getattr(model, "recurrent_state", False))
+        if self.recurrent:
+            for mode, on in (("kv='paged'", self.kv_mode == "paged"),
+                             ("prefix_cache", self.prefix_cache),
+                             ("prefill_chunk",
+                              self.prefill_chunk is not None),
+                             ("speculative", self.speculative is not None)):
+                if on:
+                    raise ValueError(self._no_recurrent(mode))
+        if self.prefix_cache and self.kv_mode != "paged":
+            raise ValueError(
+                "prefix_cache requires kv='paged' — the contiguous "
+                "engine has no shareable pages")
 
         self._pool = None
         self.block_size = None
@@ -332,6 +344,11 @@ class GenerativeEngine(Logger):
         else:
             self.kv_cache_bytes = model.cache_nbytes(self.max_slots,
                                                      self.max_seq)
+        #: the part of ``kv_cache_bytes`` (the WHOLE cache tree) that is
+        #: recurrent state and not keys and values: fixed a slot,
+        #: whatever the sequence's length
+        self.state_cache_bytes = model.recurrent_nbytes(self.max_slots) \
+            if self.recurrent else 0
         from veles_tpu.memory import Watcher
         Watcher.track(self.kv_cache_bytes, "kv", owner=self)
         self._kv_tracked = True
@@ -402,9 +419,32 @@ class GenerativeEngine(Logger):
         self.spec_drafted_total = 0
         self.spec_accepted_total = 0
         self.spec_tokens_total = 0
+        #: what the model's programs count behind their tokens
+        #: (``model.counters``, e.g. the expert layers' routed pairs):
+        #: program kind -> name -> running value; a name ending in
+        #: ``_max`` keeps the largest reading, the others add up
+        self._counter_names = tuple(getattr(model, "counters", ()))
+        self.counters = {kind: dict.fromkeys(self._counter_names, 0)
+                         for kind in ("prefill", "decode")}
         self._warmed = False
         self.prof_name = "gen%d" % next(_GEN_SEQ)
         self._prof_entries = {}
+
+    def _no_recurrent(self, mode):
+        return ("%s cannot hold the recurrent state of %s: a state is "
+                "not a run of K/V pages that can be shared, split or "
+                "replayed (serve it with kv='contiguous')"
+                % (mode, type(self.model).__name__))
+
+    def _count(self, kind, out, n):
+        """``out``: what a program of a counting model returned, on the
+        host: ``n`` tokens, then ``model.counters``.  Adds the counters
+        to ``self.counters[kind]`` and returns the tokens."""
+        totals = self.counters[kind]
+        for name, value in zip(self._counter_names, out[n:]):
+            totals[name] = max(totals[name], int(value)) \
+                if name.endswith("_max") else totals[name] + int(value)
+        return out[:n]
 
     # -- sharding ----------------------------------------------------------
     def _build_shardings(self):
@@ -903,6 +943,8 @@ class GenerativeEngine(Logger):
         without finishing the request — the scheduler requeues the
         sequence's tokens-so-far and greedy decode reproduces the
         stream, so preemption is lossless."""
+        if self.recurrent:
+            raise ValueError(self._no_recurrent("preempt's replay"))
         if not self.slot_active[slot] and slot not in self._chunking:
             raise ValueError("slot %d is not occupied" % slot)
         self.release_slot(slot)
@@ -982,6 +1024,8 @@ class GenerativeEngine(Logger):
                         jnp.asarray(padded[None]),
                         jnp.int32(slot), jnp.int32(n))
             with trace.span("gen", "prefill_fetch"):
+                if self._counter_names:
+                    tok = self._count("prefill", numpy.asarray(tok), 1)[0]
                 tok = int(tok)
             prof.ledger.record_dispatch(
                 entry, time.perf_counter_ns() - tic, items=n)
@@ -1152,6 +1196,8 @@ class GenerativeEngine(Logger):
                         jnp.asarray(positions), jnp.asarray(active))
             with trace.span("gen", "decode_fetch"):
                 out = numpy.asarray(out)
+                if self._counter_names:
+                    out = self._count("decode", out, self.max_slots)
             prof.ledger.record_dispatch(
                 entry, time.perf_counter_ns() - tic, items=n_active)
         self.slot_len[active] += 1
@@ -1292,6 +1338,8 @@ class GenerativeEngine(Logger):
         token stream stays bitwise-identical, because decode gathers
         K/V through the block table and masks past ``n``.  The slot
         itself is NOT released (the caller decides)."""
+        if self.recurrent:
+            raise ValueError(self._no_recurrent("export_slot"))
         if self._pool is None:
             raise ValueError("page export requires kv='paged'")
         if not self.slot_active[slot]:
@@ -1326,6 +1374,8 @@ class GenerativeEngine(Logger):
         Callers gate on :meth:`can_admit` with the payload's ``n`` —
         the pricing is identical to a fresh admission.  Returns
         ``(slot, first_token)`` like :meth:`prefill`."""
+        if self.recurrent:
+            raise ValueError(self._no_recurrent("adopt_sequence"))
         if self._pool is None:
             raise ValueError("page adoption requires kv='paged'")
         n = self._validate_prompt_len(int(payload["n"]))
@@ -1411,8 +1461,9 @@ class GenerativeEngine(Logger):
         """HBM actually held per in-flight sequence — the capacity
         metric the long-tail bench and /metrics report: the KV share
         (contiguous mode reserves a full ``max_seq`` slice per slot
-        at admission; paged mode pays only for the pages in use) PLUS
-        the shared params footprint amortized over the occupants —
+        at admission, and with it the slot's recurrent state where the
+        model keeps one; paged mode pays only for the pages in use)
+        PLUS the shared params footprint amortized over the occupants —
         so an int8 deploy's 4× params shrink is visible to the PR 12
         SLO samplers, not just to ``describe()``."""
         occupants = self.active_slots() + len(self._chunking)
@@ -1460,6 +1511,11 @@ class GenerativeEngine(Logger):
             "max_seq": self.max_seq,
             "prefill_buckets": list(self.prefill_buckets),
             "kv_cache_bytes": self.kv_cache_bytes,
+            "kv_bytes_per_slot": None if self._pool is not None else (
+                self.kv_cache_bytes - self.state_cache_bytes)
+            // self.max_slots,
+            "state_bytes_per_slot":
+                self.state_cache_bytes // self.max_slots,
             "kv": self.kv_mode,
             "quantize": self.quantized,
             "params_bytes": self.params_nbytes,
