@@ -145,6 +145,25 @@ def _gather_norm(chip):
                                      chip((100,), i32), row, row)
 
 
+def _resident(chip, rows, sample_shape, dtype):
+    """A resident set in its device form (``ops.gather``), described."""
+    from veles_tpu.ops.gather import ResidentRows, resident_shape
+    return ResidentRows(chip((rows,) + resident_shape(sample_shape), dtype),
+                        sample_shape)
+
+
+def _gather_resident(norm, chip):
+    from veles_tpu.ops import gather
+    data = _resident(chip, 25856, (227, 227, 3), jnp.uint8)
+    if not norm:
+        return gather._gather_pallas.lower(
+            data.form, chip((256,), i32), sample_shape=data.sample_shape)
+    row = chip((1, 227 * 227 * 3), f32)
+    return gather._gather_norm_pallas.lower(
+        data.form, chip((256,), i32), row, row,
+        sample_shape=data.sample_shape)
+
+
 def _prng_fill(chip):
     from veles_tpu.ops.random import _uniform_pallas_tpu
     return _uniform_pallas_tpu.lower(chip((), i32), shape=(4096, 4096))
@@ -215,6 +234,10 @@ CASES = {
     "gd_fused_mnist_784x100": functools.partial(_gd_fused, 100, 784, 100),
     "gather_dma_60000x784": _gather,
     "gather_norm_u8_60000x784": _gather_norm,
+    "gather_dma_resident_form_25856x1208x128": functools.partial(
+        _gather_resident, False),
+    "gather_norm_u8_resident_form_25856x1208x128": functools.partial(
+        _gather_resident, True),
     "prng_fill": _prng_fill,
     "lm_config_decode_step": functools.partial(_lm_decode, False),
     "lm_config_paged_decode_step": functools.partial(_lm_decode, True),
@@ -226,6 +249,69 @@ def test_pallas_kernel_compiles_for_v5e(case, chip):
     compiled = CASES[case](chip).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         "%s compiled for the v5e without a Pallas kernel in it" % case
+
+
+#: name -> (rows, sample shape, storage dtype, minibatch)
+RESIDENT_SETS = {
+    "alexnet_cell_u8_25856x227x227x3": (25856, (227, 227, 3), jnp.uint8,
+                                        256),
+    "cifar_f32_50000x32x32x3": (50000, (32, 32, 3), f32, 128),
+}
+
+
+def _set_sized(compiled, rows):
+    """The operations of a compiled program whose RESULT has the whole
+    set's rows (its parameters apart), and its temporaries in bytes."""
+    import re
+    ops = [line.strip() for line in compiled.as_text().splitlines()
+           if re.search(r"= \w+\[%d," % rows, line)
+           and " parameter(" not in line]
+    return ops, compiled.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("head", ["take_rows", "take_rows_norm"])
+@pytest.mark.parametrize("name", sorted(RESIDENT_SETS))
+def test_loader_gather_reads_the_rows_it_takes_not_the_set(name, head,
+                                                           chip):
+    """The minibatch gather as the loader compiles it, over the set in
+    its rows-major device form: no operation whose result has the set's
+    rows, temporaries under three minibatches.  And over the set in its
+    own shape, as it was held before: the chip lays an image-shaped
+    array out with the SAMPLES innermost, so the same gather first
+    copies the whole set, and the same reading says so."""
+    from veles_tpu.ops import gather
+    rows, sample_shape, dtype, batch = RESIDENT_SETS[name]
+    if head == "take_rows":
+        take, out_dtype = jax.jit(gather.take_rows), dtype
+    else:
+        take, out_dtype = jax.jit(lambda data, idx: gather.take_rows_norm(
+            data, idx, (1.0 / 255.0, 0.0))), f32
+    minibatch = batch * int(numpy.prod(sample_shape)) \
+        * jnp.dtype(out_dtype).itemsize
+    idx = chip((batch,), i32)
+    formed = take.lower(_resident(chip, rows, sample_shape, dtype),
+                        idx).compile()
+    assert "tpu_custom_call" not in formed.as_text()    # XLA's gather
+    ops, temporaries = _set_sized(formed, rows)
+    assert ops == [] and temporaries < 3 * minibatch, (ops, temporaries)
+    today = take.lower(chip((rows,) + sample_shape, dtype), idx).compile()
+    ops, temporaries = _set_sized(today, rows)
+    assert ops and temporaries > 3 * minibatch, (ops, temporaries)
+    assert any(" copy(" in op for op in ops)
+
+
+def test_resident_form_is_written_in_place_a_chunk_at_a_time(chip):
+    """The upload's one program: a chunk of the host's rows is padded,
+    split into lanes and written into the donated form, which is never
+    on the device twice."""
+    from veles_tpu.ops import gather
+    form = _resident(chip, 25856, (227, 227, 3), jnp.uint8).form
+    chunk = chip((1664, 227 * 227 * 3), jnp.uint8)
+    assert chunk.size <= gather.CHUNK_BYTES
+    compiled = gather._place.lower(form, chunk, chip((), i32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == form.size
+    assert mem.temp_size_in_bytes < 3 * chunk.size
 
 
 def test_alexnet_fused_step_compiles_for_v5e_and_fits(chip, topo):

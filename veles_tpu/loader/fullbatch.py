@@ -10,7 +10,18 @@ TPU re-design: the dataset Vectors live on HBM once (one upload), the
 minibatch fill is :func:`veles_tpu.ops.gather.take_rows` on the shuffled
 index slice — the jitted consumer (forward unit / fused train step) reads
 ``minibatch_data.devmem`` so the gather fuses into the step and nothing
-round-trips to host during training.  Normalization is applied to the
+round-trips to host during training.  The device copy of
+``original_data`` (and ``original_targets``) is NOT held in the samples'
+shape: the chip lays an image-shaped array out with the sample dimension
+innermost, so a gather of 256 rows first copied the whole set, every
+minibatch.  The Vectors are ``rows_major``: at upload each row is padded
+to whole tiles and split into lanes (``ops.gather.resident_shape``; rows
+that would grow by more than an eighth, and labels, keep their shape),
+which the chip lays out rows-major, and the three gathers (``fill_minibatch``,
+the stitched head, the epoch scan) take rows of that form and reshape
+only those.  The host side (``original_data.mem``, ``shape``,
+``analyze_dataset``, the normaliser) sees the samples' shape as before.
+Normalization is applied to the
 resident data once at initialize (the reference normalizes per-minibatch
 on host; one-shot is equivalent for stateless/TRAIN-fit normalizers and
 removes a per-step host pass).
@@ -52,7 +63,7 @@ class FullBatchLoader(Loader):
     hide_from_registry = True
 
     def __init__(self, workflow, **kwargs):
-        self.original_data = Vector(category="dataset")
+        self.original_data = Vector(category="dataset", rows_major=True)
         self.original_labels = []
         #: keep the dataset on device and gather there (default on)
         self.store_in_device_memory = kwargs.get(
@@ -233,7 +244,8 @@ class FullBatchLoader(Loader):
         """Head stage of the stitched eager chain: the host serving
         bookkeeping rides as the segment prelude
         (:meth:`veles_tpu.loader.base.Loader.stitch_prelude`) and the
-        fill becomes a masked ``jnp.take`` over the resident dataset —
+        fill becomes an in-program ``take_rows`` over the resident
+        dataset —
         the served span of the device-resident shuffled-index buffer is
         selected by the traced (offset, size) scalars, so one trace
         serves every batch of every class, short epoch tails included,
@@ -274,9 +286,14 @@ class FullBatchLoader(Loader):
                         t["src_" + name],
                         jnp.where(valid, idx, -1), norm)
                     continue
-                rows = jnp.take(t["src_" + name], idx, axis=0)
-                mask = valid.reshape((-1,) + (1,) * (rows.ndim - 1))
-                out[name] = jnp.where(mask, rows, pads[name])
+                # rows out of the resident set (its device form or,
+                # the labels, a plain array), the tail's rows zero
+                rows = take_rows(t["src_" + name],
+                                 jnp.where(valid, idx, -1))
+                if pads[name]:
+                    mask = valid.reshape((-1,) + (1,) * (rows.ndim - 1))
+                    rows = jnp.where(mask, rows, pads[name])
+                out[name] = rows
             return out
 
         params = {"indices": self.shuffled_indices}
@@ -375,7 +392,8 @@ class FullBatchLoaderMSE(FullBatchLoader):
     hide_from_registry = True
 
     def __init__(self, workflow, **kwargs):
-        self.original_targets = Vector(category="dataset")
+        self.original_targets = Vector(category="dataset",
+                                       rows_major=True)
         self.minibatch_targets = Vector(category="staging")
         super(FullBatchLoaderMSE, self).__init__(workflow, **kwargs)
 

@@ -151,13 +151,22 @@ class Vector(Pickleable):
     (``params`` / ``dataset`` / ``staging`` / ``kv``; ``None`` groups
     under ``other``) — set it at construction (weights, resident
     datasets and minibatch staging buffers already are), it rides
-    pickling and is read at device-upload time."""
+    pickling and is read at device-upload time.
 
-    def __init__(self, data=None, category=None):
+    ``rows_major`` marks a resident data set, ``[samples, ...]``: its
+    device copy is made by :func:`veles_tpu.ops.gather.upload_rows`, in
+    the form a row gather reads cheaply (a
+    :class:`~veles_tpu.ops.gather.ResidentRows` of this Vector's
+    ``shape``, or the plain array where the form does not help).  The
+    host side is untouched, and the copy is this Vector's like any
+    other: ``reset`` drops it, the Watcher counts it once."""
+
+    def __init__(self, data=None, category=None, rows_major=False):
         super(Vector, self).__init__()
         self._mem = None          # host numpy array (may be stale)
         self._device = None
         self.category = category
+        self.rows_major = rows_major
         if data is not None:
             self.reset(data)
 
@@ -176,6 +185,8 @@ class Vector(Pickleable):
         # lack the attribute entirely
         if not hasattr(self, "category"):
             self.category = None
+        if not hasattr(self, "rows_major"):
+            self.rows_major = False
 
     # -- basic properties ---------------------------------------------------
     def reset(self, data):
@@ -253,7 +264,11 @@ class Vector(Pickleable):
         if self._devmem_ is None or not self._dev_fresh_:
             if self._mem is None:
                 raise ValueError("empty Vector has no device memory")
-            if self._sharding_ is not None:
+            if self.rows_major:
+                from veles_tpu.ops.gather import upload_rows
+                self._set_devmem(upload_rows(
+                    self._mem, self._device.put, self._sharding_))
+            elif self._sharding_ is not None:
                 # pod placement: EVERY upload of this Vector (epoch
                 # reshuffles included) lands with its mesh sharding,
                 # so the AOT pod executables never see a drifted
@@ -369,7 +384,7 @@ class Vector(Pickleable):
         self._untrack_devmem()
         self._devmem_ = value
         self._tracked_bytes_ = (
-            int(numpy.prod(value.shape)) * value.dtype.itemsize
+            int(value.nbytes)
             if value is not None and value.shape else 0)
         if self._tracked_bytes_:
             self._tracked_category_ = getattr(self, "category", None)

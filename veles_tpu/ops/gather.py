@@ -5,11 +5,27 @@ Parity target: ``ocl/fullbatch_loader.cl:5-30`` /
 the device-resident full dataset by an index vector, zero-padding the tail
 of a short final batch.
 
-TPU re-design: the jnp path is ``jnp.take`` (XLA emits an efficient
-dynamic-gather); the Pallas path uses scalar-prefetched indices as the
-BlockSpec index map, so each sample row is DMA'd straight from the
-dataset in HBM into the output block — no materialized one-hot, no host
-round-trip for the epoch shuffle.
+TPU re-design.  A gather of rows is cheap only when a row is contiguous
+on the device, and the chip does NOT hold an image-shaped set that way:
+the compiler gives ``u8[25856, 227, 227, 3]`` the layout ``{0,2,3,1}``,
+the SAMPLE dimension minor-most (it is the only one that fills the 128
+lanes), so ``jnp.take`` along it first turns the whole 4 GB set around,
+on every minibatch (19.9 ms of a 41 ms AlexNet step; ledger, PR 28).
+Flattening the rows does not help (``u8[25856, 154587]`` gets ``{0,1}``),
+and a Pallas kernel that begins with ``data.reshape(n, -1)`` pays the
+same copy.  What helps is the FORM the set is held in
+(:func:`resident_shape`): every row padded to whole ``(8, 128)`` tiles
+and split into lanes, ``[n, tiles * 8, 128]``, which the chip lays out
+rows-major, ``{2,1,0}``.  :func:`upload_rows` builds that form once, at
+upload; :func:`take_rows` / :func:`take_rows_norm` index it and reshape
+only the rows they took.  ``tests/test_chip_compile.py`` compiles the
+gather for a described v5e both ways and holds the form to it.
+
+On the form the jnp path is one ``gather`` fusion over the rows taken;
+the Pallas path uses scalar-prefetched indices as the BlockSpec index
+map, so each sample row is DMA'd straight from the dataset in HBM into
+the output block — no materialized one-hot, no host round-trip for the
+epoch shuffle.
 """
 
 import functools
@@ -20,8 +36,152 @@ import numpy
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from veles_tpu import trace
 
-def _use_pallas(data, use_pallas):
+LANES = 128
+#: elements of the chip's (8 sublanes, 128 lanes) tile.  A ``[n, s, 128]``
+#: array is laid out rows-major exactly when ``s`` is a multiple of 8,
+#: for 8-, 16- and 32-bit elements alike; any other ``s`` puts the
+#: samples into the sublanes
+TILE = 8 * LANES
+#: rows are placed into the form a chunk at a time, so that the set is
+#: never on the device twice
+CHUNK_BYTES = 1 << 28
+
+
+def resident_shape(sample_shape):
+    """The shape a row takes in the rows-major resident form, or None
+    where the row keeps its own: THE rule, for upload and gather alike.
+
+    A row is padded to whole tiles and split into lanes.  Rows that would
+    grow by more than an eighth keep their shape (MNIST's 784 floats
+    would become 1024; as ``[60000, 28, 28]`` the chip gathers them
+    without a temporary already), and so do scalars (labels)."""
+    elems = int(numpy.prod(sample_shape)) if sample_shape else 0
+    padded = -(-elems // TILE) * TILE
+    if elems == 0 or (padded - elems) * 8 > elems:
+        return None
+    return (padded // LANES, LANES)
+
+
+@jax.tree_util.register_pytree_node_class
+class ResidentRows(object):
+    """A data set on the device in the rows-major form: ``form`` is
+    ``[n, *resident_shape(sample_shape)]`` and stands for
+    ``[n, *sample_shape]`` (what ``shape`` says); the padding is never
+    visible.  A pytree, so it passes through ``jit``, ``device_put`` and
+    a pod's shardings (the leading dimension is the samples') as the
+    array it stands for would; :func:`take_rows` takes rows of it and a
+    slice of it is a slice of its rows."""
+
+    def __init__(self, form, sample_shape):
+        self.form = form
+        self.sample_shape = tuple(sample_shape)
+
+    shape = property(lambda self: self.form.shape[:1] + self.sample_shape)
+    dtype = property(lambda self: self.form.dtype)
+    nbytes = property(lambda self: self.form.nbytes)
+
+    def __getitem__(self, rows):
+        if not isinstance(rows, slice):
+            raise TypeError("a resident set is sliced by rows; "
+                            "take_rows() gathers from it")
+        return ResidentRows(self.form[rows], self.sample_shape)
+
+    def __array__(self, dtype=None, copy=None):
+        host = numpy.asarray(_samples(self.form, self.sample_shape))
+        return host if dtype is None else host.astype(dtype)
+
+    def tree_flatten(self):
+        return (self.form,), self.sample_shape
+
+    @classmethod
+    def tree_unflatten(cls, sample_shape, children):
+        return cls(children[0], sample_shape)
+
+
+def _samples(rows, sample_shape):
+    """Rows of the form (or flattened rows) back in their own shape."""
+    if sample_shape is None or rows.shape[1:] == tuple(sample_shape):
+        return rows
+    elems = int(numpy.prod(sample_shape))
+    return rows.reshape(rows.shape[0], -1)[:, :elems].reshape(
+        rows.shape[:1] + tuple(sample_shape))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _place(form, chunk, start):
+    pad = form.shape[1] * form.shape[2] - chunk.shape[1]
+    rows = jnp.pad(chunk, ((0, 0), (0, pad))).reshape(
+        chunk.shape[:1] + form.shape[1:])
+    return jax.lax.dynamic_update_slice(form, rows, (start, 0, 0))
+
+
+def _upload_form(flat, row_shape, put):
+    """Rows ``flat`` ([n, elems] on the host) in the form on the ONE
+    device ``put`` uploads to: chunks of the host's own rows (views, no
+    second host copy) are uploaded and written in place into the form,
+    the last chunk moved back over rows already written so that one
+    program serves all."""
+    n, elems = flat.shape
+    per_chunk = CHUNK_BYTES // (elems * flat.dtype.itemsize)
+    chunk = min(n, max(LANES, per_chunk // LANES * LANES))
+    form = sent = None
+    for start in range(0, n, chunk):
+        start = min(start, n - chunk)
+        piece = put(flat[start:start + chunk])
+        if form is None:
+            form = jnp.zeros((n,) + row_shape, flat.dtype,
+                             device=piece.sharding)
+        form = _place(form, piece, start)
+        if sent is not None:
+            # two chunks in flight at most
+            sent.block_until_ready()
+        sent = piece
+    return form
+
+
+def upload_rows(host, put, sharding=None):
+    """The device copy of the data set ``host`` ([n, *sample_shape] on
+    the host): a :class:`ResidentRows` where :func:`resident_shape`
+    gives a form, the plain array where it does not.  ``put`` uploads
+    to the one device; under a pod's ``sharding`` (by rows, or
+    replicated) each device builds the form of its own rows."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    n, sample_shape = host.shape[0], host.shape[1:]
+    row_shape = resident_shape(sample_shape)
+    elems = int(numpy.prod(sample_shape))
+    padded = int(numpy.prod(row_shape)) if row_shape else elems
+    stats = {"rows": n, "row_elems": elems, "padded_elems": padded,
+             "bytes": n * padded * host.dtype.itemsize,
+             "form": str((n,) + (row_shape or sample_shape))}
+    with trace.span("loader", "upload", stats):
+        if row_shape is None:
+            return put(host) if sharding is None \
+                else jax.device_put(host, sharding)
+        flat = host.reshape(n, elems)
+        if sharding is None:
+            return ResidentRows(_upload_form(flat, row_shape, put),
+                                sample_shape)
+        if isinstance(sharding, NamedSharding):
+            if any(sharding.spec[1:]):
+                raise ValueError("a resident set is sharded by rows "
+                                 "alone, not %r" % (sharding.spec,))
+            sharding = NamedSharding(sharding.mesh,
+                                     PartitionSpec(*sharding.spec[:1]))
+        shape = (n,) + row_shape
+        parts = []
+        for device, index in \
+                sharding.addressable_devices_indices_map(shape).items():
+            lo, hi, _step = index[0].indices(n)
+            parts.append(_upload_form(
+                flat[lo:hi], row_shape,
+                functools.partial(jax.device_put, device=device)))
+        return ResidentRows(jax.make_array_from_single_device_arrays(
+            shape, sharding, parts), sample_shape)
+
+
+def _use_pallas(data, use_pallas, row_elems=None):
     """Resolve the gather backend.  Priority: explicit ``use_pallas``
     arg > ``root.common.engine.pallas_gather`` (True/False force; a
     config force also honors ``engine.interpret`` so CPU tests can pin
@@ -44,8 +204,9 @@ def _use_pallas(data, use_pallas):
     from veles_tpu.ops.benchmark import gather_choice
     # the verdict only transfers to the ROW SIZE it was measured at:
     # the kernel's win is not generic
-    measured = gather_choice(str(jnp.dtype(data.dtype)),
-                             row_elems=int(numpy.prod(data.shape[1:])))
+    measured = gather_choice(
+        str(jnp.dtype(data.dtype)),
+        row_elems=row_elems or int(numpy.prod(data.shape[1:])))
     return bool(measured) and on_tpu()
 
 
@@ -54,15 +215,24 @@ def _interpret():
     return bool(root.common.engine.get("interpret", False))
 
 
+def _rows_of(data):
+    """``(array to gather from, the samples' shape)`` of a resident set
+    in its form or of a plain array."""
+    if isinstance(data, ResidentRows):
+        return data.form, data.sample_shape
+    return data, tuple(data.shape[1:])
+
+
 def take_rows(data, indices, use_pallas=None):
-    """``data[indices]`` along axis 0.  Negative indices (the reference's
-    "empty slot" marker for short batches) produce zero rows.  Backend
-    dispatch: :func:`_use_pallas`."""
-    if _use_pallas(data, use_pallas):
-        out = _gather_pallas(data.reshape(data.shape[0], -1), indices,
-                             interpret=_interpret())
-        return out.reshape((indices.shape[0],) + data.shape[1:])
-    return _gather_jnp(data, indices)
+    """``data[indices]`` along axis 0, ``[batch, *sample_shape]`` in the
+    storage dtype, of a :class:`ResidentRows` or a plain array.
+    Negative indices (the reference's "empty slot" marker for short
+    batches) produce zero rows.  Backend dispatch: :func:`_use_pallas`."""
+    rows, shape = _rows_of(data)
+    if _use_pallas(rows, use_pallas, int(numpy.prod(shape))):
+        return _gather_pallas(rows, indices, interpret=_interpret(),
+                              sample_shape=shape)
+    return _gather_jnp(rows, indices, sample_shape=shape)
 
 
 def take_rows_norm(data, indices, norm, use_pallas=None):
@@ -81,16 +251,17 @@ def take_rows_norm(data, indices, norm, use_pallas=None):
     gather A/B verdict transfers: the epilogue adds two VPU ops to a
     DMA-bound kernel)."""
     scale, shift = norm
-    if _use_pallas(data, use_pallas):
-        flat = data.reshape(data.shape[0], -1)
-        f = flat.shape[1]
-        out = _gather_norm_pallas(
-            flat, indices, _norm_row(scale, f), _norm_row(shift, f),
-            interpret=_interpret())
-        return out.reshape((indices.shape[0],) + data.shape[1:])
-    return _gather_norm_jnp(data, indices,
+    rows, shape = _rows_of(data)
+    elems = int(numpy.prod(shape))
+    if _use_pallas(rows, use_pallas, elems):
+        return _gather_norm_pallas(
+            rows, indices, _norm_row(scale, elems),
+            _norm_row(shift, elems), interpret=_interpret(),
+            sample_shape=shape)
+    return _gather_norm_jnp(rows, indices,
                             jnp.asarray(scale, jnp.float32),
-                            jnp.asarray(shift, jnp.float32))
+                            jnp.asarray(shift, jnp.float32),
+                            sample_shape=shape)
 
 
 def _norm_row(v, f):
@@ -99,14 +270,15 @@ def _norm_row(v, f):
     return jnp.broadcast_to(v.reshape(1, -1), (1, f))
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("sample_shape",))
 @jax.named_scope("veles.loader.take_rows_norm")
-def _gather_norm_jnp(data, indices, scale, shift):
-    taken = jnp.take(data, jnp.maximum(indices, 0), axis=0)
+def _gather_norm_jnp(data, indices, scale, shift, sample_shape=None):
+    taken = _samples(jnp.take(data, jnp.maximum(indices, 0), axis=0),
+                     sample_shape)
     flat = taken.reshape(taken.shape[0], -1).astype(jnp.float32)
     normed = (flat * scale.reshape(1, -1)
               + shift.reshape(1, -1)).reshape(taken.shape)
-    mask = (indices >= 0).reshape((-1,) + (1,) * (data.ndim - 1))
+    mask = (indices >= 0).reshape((-1,) + (1,) * (taken.ndim - 1))
     return jnp.where(mask, normed, 0.0)
 
 
@@ -120,52 +292,70 @@ def _gather_norm_kernel(idx_ref, data_ref, scale_ref, shift_ref, o_ref):
         if jnp.issubdtype(x.dtype, jnp.integer):
             # Mosaic has no direct uint8 -> float32 cast; widen first
             x = x.astype(jnp.int32)
-        o_ref[:] = (x.astype(jnp.float32)
-                    * scale_ref[:].reshape(1, 1, -1)
-                    + shift_ref[:].reshape(1, 1, -1))
+        o_ref[:] = (x.astype(jnp.float32) * scale_ref[:][None]
+                    + shift_ref[:][None])
 
     @pl.when(jnp.logical_not(valid))
     def _zero():
         o_ref[:] = jnp.zeros_like(o_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _gather_norm_pallas(data, indices, scale, shift, interpret=False):
-    # same (n, 1, f) / (1, 1, f) block trick as _gather_pallas (block
-    # dims equal to array dims sidestep the sublane rule); scale/shift
-    # ride as whole-array (1, f) operands every grid point maps to
-    n, f = data.shape
+def _row_blocks(data):
+    """``data`` as ``[n, a, b]`` with one row a ``(1, a, b)`` block.  The
+    Mosaic lowering requires a block's last two dims to be divisible by
+    (8, 128) OR equal to the array's dims: a row of the resident form is
+    such a block as it is, and any other row rides flat as ``(n, 1, f)``
+    — a ``(1, f)`` block over ``(n, f)`` would fail the sublane rule for
+    any n > 1 — with no padding and no copy (the reshape is a view of
+    the same HBM bytes)."""
+    return data if data.ndim == 3 else data.reshape(data.shape[0], 1, -1)
+
+
+def _block_row(v, block):
+    """A ``(1, f)`` scale/shift row padded and split like a row block."""
+    padded = int(numpy.prod(block))
+    return jnp.pad(v, ((0, 0), (0, padded - v.shape[1]))).reshape(block)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "sample_shape"))
+def _gather_norm_pallas(data, indices, scale, shift, interpret=False,
+                        sample_shape=None):
+    # scale/shift, (1, f) rows, ride as whole-array (a, b) operands of
+    # one row block's shape that every grid point maps to
+    blocks = _row_blocks(data)
+    block = (1,) + blocks.shape[1:]
     b = indices.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, f), lambda i, idx_ref: (jnp.maximum(
+            pl.BlockSpec(block, lambda i, idx_ref: (jnp.maximum(
                 idx_ref[i], 0), 0, 0)),
-            pl.BlockSpec((1, f), lambda i, idx_ref: (0, 0)),
-            pl.BlockSpec((1, f), lambda i, idx_ref: (0, 0)),
+            pl.BlockSpec(block[1:], lambda i, idx_ref: (0, 0)),
+            pl.BlockSpec(block[1:], lambda i, idx_ref: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, f), lambda i, idx_ref: (i, 0, 0)),
+        out_specs=pl.BlockSpec(block, lambda i, idx_ref: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _gather_norm_kernel,
         name="veles_gather_norm",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, f), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b,) + block[1:], jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(indices, jnp.int32), data.reshape(n, 1, f),
-      scale, shift)
-    return out.reshape(b, f)
+    )(jnp.asarray(indices, jnp.int32), blocks,
+      _block_row(scale, block[1:]), _block_row(shift, block[1:]))
+    return _samples(out.reshape((b,) + data.shape[1:]), sample_shape)
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("sample_shape",))
 @jax.named_scope("veles.loader.take_rows")
-def _gather_jnp(data, indices):
+def _gather_jnp(data, indices, sample_shape=None):
     # jitted: the eager form is 3 separate op dispatches per minibatch;
     # one compiled program per (shape, dtype) serves every batch.  The
     # scope names the gather's device operations wherever it is fused
-    taken = jnp.take(data, jnp.maximum(indices, 0), axis=0)
-    mask = (indices >= 0).reshape((-1,) + (1,) * (data.ndim - 1))
+    taken = _samples(jnp.take(data, jnp.maximum(indices, 0), axis=0),
+                     sample_shape)
+    mask = (indices >= 0).reshape((-1,) + (1,) * (taken.ndim - 1))
     return jnp.where(mask, taken, 0)
 
 
@@ -182,15 +372,10 @@ def _gather_kernel(idx_ref, data_ref, o_ref):
         o_ref[:] = jnp.zeros_like(o_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _gather_pallas(data, indices, interpret=False):
-    # The Mosaic lowering requires a block's last two dims to be
-    # divisible by (8, 128) OR equal to the array's dims.  A (1, f)
-    # block over (n, f) fails the sublane rule for any n > 1, so the
-    # data rides as (n, 1, f) with (1, 1, f) blocks — both trailing
-    # block dims then EQUAL the array dims, with no padding and no
-    # copy (the reshape is a view of the same HBM bytes).
-    n, f = data.shape
+@functools.partial(jax.jit, static_argnames=("interpret", "sample_shape"))
+def _gather_pallas(data, indices, interpret=False, sample_shape=None):
+    blocks = _row_blocks(data)
+    block = (1,) + blocks.shape[1:]
     b = indices.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -198,16 +383,16 @@ def _gather_pallas(data, indices, interpret=False):
         in_specs=[
             # the index map reads the prefetched indices: block row i of
             # the output comes from dataset row indices[i]
-            pl.BlockSpec((1, 1, f), lambda i, idx_ref: (jnp.maximum(
+            pl.BlockSpec(block, lambda i, idx_ref: (jnp.maximum(
                 idx_ref[i], 0), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, f), lambda i, idx_ref: (i, 0, 0)),
+        out_specs=pl.BlockSpec(block, lambda i, idx_ref: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _gather_kernel,
         name="veles_gather",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, f), data.dtype),
+        out_shape=jax.ShapeDtypeStruct((b,) + block[1:], data.dtype),
         interpret=interpret,
-    )(jnp.asarray(indices, jnp.int32), data.reshape(n, 1, f))
-    return out.reshape(b, f)
+    )(jnp.asarray(indices, jnp.int32), blocks)
+    return _samples(out.reshape((b,) + data.shape[1:]), sample_shape)

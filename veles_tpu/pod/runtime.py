@@ -86,7 +86,9 @@ def spec_for_vector(vec, batch, shards, data_axis="data",
     if leading and (getattr(vec, "category", None) == "dataset"
                     or leading == batch):
         if leading % max(1, shards) == 0:
-            return P(data_axis, *([None] * (len(shape) - 1)))
+            # the leading dimension alone: the spec then fits a resident
+            # set's device form as it fits the array it stands for
+            return P(data_axis)
         return P()
     return P()
 

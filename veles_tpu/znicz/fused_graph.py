@@ -513,7 +513,7 @@ def epoch_runner(step_fn, n_samples, batch, shuffle=True):
             # valid so the two backends are value-identical)
             from veles_tpu.ops.gather import take_rows
             return step_fn(p, take_rows(data, batch_idx),
-                           labels[batch_idx])
+                           take_rows(labels, batch_idx))
 
         return jax.lax.scan(body, params, idx)
 
