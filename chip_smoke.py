@@ -184,10 +184,7 @@ def phase_cli_train(epochs=2, expect_platform="tpu"):
             "gemm": resolved_backend("gemm", "float32",
                                      (batch, n_in, hidden)),
             "gd": resolved_backend("gd", "float32",
-                                   (batch, n_in, hidden)),
-            "gather": resolved_backend(
-                "gather", loader.original_data.mem.dtype,
-                loader.original_data.shape)}}
+                                   (batch, n_in, hidden))}}
 
 
 # ---------------------------------------------------------------------------
@@ -286,9 +283,7 @@ def phase_alexnet_train(layers=None, input_shape=None, n_classes=1000,
             "gemm": resolved_backend(
                 "gemm", "bfloat16",
                 (batch, 4096, fc[0]["->"]["output_sample_shape"]))
-            if fc else None,
-            "gather": resolved_backend(
-                "gather", "uint8", (n_valid + n_train,) + shape)}}
+            if fc else None}}
 
 
 # ---------------------------------------------------------------------------

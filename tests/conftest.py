@@ -24,6 +24,7 @@ import jax  # noqa: E402
 # config too, before backend init
 jax.config.update("jax_platforms", "cpu")
 
+import numpy  # noqa: E402
 import pytest  # noqa: E402
 
 
@@ -74,3 +75,17 @@ def _pin_synthetic_data(request, tmp_path, monkeypatch):
     root.common.dirs.datasets = str(tmp_path / "no-datasets-here")
     yield
     root.common.dirs.datasets = saved
+
+
+@pytest.fixture
+def aligned():
+    """``aligned(shape, dtype)``: a zeroed numpy array on a 64-byte
+    boundary, the kind of host buffer jax's CPU backend takes WITHOUT a
+    copy (numpy's own allocator gives one in some runs and not in
+    others, which is how an aliasing defect comes to flip)."""
+    def make(shape, dtype):
+        nbytes = int(numpy.prod(shape)) * numpy.dtype(dtype).itemsize
+        raw = numpy.zeros(nbytes + 64, numpy.uint8)
+        start = -raw.ctypes.data % 64
+        return raw[start:start + nbytes].view(dtype).reshape(shape)
+    return make

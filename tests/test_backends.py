@@ -45,6 +45,17 @@ def test_cpu_put_get_sync():
     assert numpy.allclose(dev.get(dev_arr), arr)
 
 
+def test_cpu_put_hands_jax_a_private_copy(aligned):
+    """jax's CPU backend takes a 64-byte aligned numpy buffer without a
+    copy; CPUDevice.put must not let the "device copy" be the array
+    the host goes on writing."""
+    host = aligned((32,), numpy.int32)
+    dev = CPUDevice().put(host)
+    assert not numpy.shares_memory(numpy.asarray(dev), host)
+    host[:] = 5
+    assert (numpy.asarray(dev) == 0).all()
+
+
 def test_auto_device_is_the_cpu_when_the_process_asked_for_it():
     # conftest pins jax_platforms="cpu" (what JAX_PLATFORMS=cpu does):
     # the one case in which "auto" means the CPU

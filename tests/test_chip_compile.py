@@ -7,7 +7,7 @@ topology that is described and not attached.  A kernel that passes every
 interpret-mode parity test can still be refused here — a block that breaks
 the (8, 128) rule, a cast Mosaic does not implement, a scratch that
 overflows VMEM — and was, until PR 21 (flash forward/backward, chunk
-attention, the PRNG fill, the u8 gather+normalize).
+attention, the PRNG fill).
 
 Nothing runs: a compile that passes is not a chip run.
 
@@ -132,36 +132,11 @@ def _gd_fused(batch, fan_in, neurons, chip):
     return jax.jit(fn).lower(x, y, y, w, b, w, b)
 
 
-def _gather(chip):
-    from veles_tpu.ops.gather import _gather_pallas
-    return _gather_pallas.lower(chip((60000, 784), jnp.uint8),
-                                chip((100,), i32))
-
-
-def _gather_norm(chip):
-    from veles_tpu.ops.gather import _gather_norm_pallas
-    row = chip((1, 784), f32)
-    return _gather_norm_pallas.lower(chip((60000, 784), jnp.uint8),
-                                     chip((100,), i32), row, row)
-
-
 def _resident(chip, rows, sample_shape, dtype):
     """A resident set in its device form (``ops.gather``), described."""
     from veles_tpu.ops.gather import ResidentRows, resident_shape
     return ResidentRows(chip((rows,) + resident_shape(sample_shape), dtype),
                         sample_shape)
-
-
-def _gather_resident(norm, chip):
-    from veles_tpu.ops import gather
-    data = _resident(chip, 25856, (227, 227, 3), jnp.uint8)
-    if not norm:
-        return gather._gather_pallas.lower(
-            data.form, chip((256,), i32), sample_shape=data.sample_shape)
-    row = chip((1, 227 * 227 * 3), f32)
-    return gather._gather_norm_pallas.lower(
-        data.form, chip((256,), i32), row, row,
-        sample_shape=data.sample_shape)
 
 
 def _prng_fill(chip):
@@ -232,12 +207,6 @@ CASES = {
     "gd_fused_4096x4096_f32": functools.partial(_gd_fused, 256, 4096,
                                                 4096),
     "gd_fused_mnist_784x100": functools.partial(_gd_fused, 100, 784, 100),
-    "gather_dma_60000x784": _gather,
-    "gather_norm_u8_60000x784": _gather_norm,
-    "gather_dma_resident_form_25856x1208x128": functools.partial(
-        _gather_resident, False),
-    "gather_norm_u8_resident_form_25856x1208x128": functools.partial(
-        _gather_resident, True),
     "prng_fill": _prng_fill,
     "lm_config_decode_step": functools.partial(_lm_decode, False),
     "lm_config_paged_decode_step": functools.partial(_lm_decode, True),

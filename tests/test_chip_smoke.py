@@ -103,7 +103,7 @@ def test_cli_train_phase_runs_main_on_the_default_auto_device():
     assert out["train_minibatches"] == 60
     assert numpy.isfinite(out["last_loss"])
     assert min(out["max_abs_weight_change"]) > 0
-    assert set(out["kernel_backends"]) == {"gemm", "gd", "gather"}
+    assert set(out["kernel_backends"]) == {"gemm", "gd"}
 
 
 def test_cli_train_phase_fails_on_the_wrong_device():

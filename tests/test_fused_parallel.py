@@ -808,62 +808,6 @@ def test_epoch_runner_matches_host_loop():
                for v in metrics.values())
 
 
-def test_epoch_runner_pallas_gather_inside_scan_matches_xla():
-    """The one-program epoch with the Pallas DMA gather forced
-    (interpret mode on CPU) must equal the XLA-gather epoch bit for
-    bit — pins the exact composition the TPU path runs when the
-    device DB's gather verdict says pallas."""
-    import jax
-    import numpy
-    from veles_tpu.config import root
-    from veles_tpu.znicz.fused_graph import epoch_runner, lower_specs
-
-    rng = numpy.random.default_rng(1)
-    n, batch = 32, 8
-    data = rng.integers(0, 256, (n, 12)).astype(numpy.uint8)
-    labels = rng.integers(0, 4, n).astype(numpy.int32)
-    specs = [
-        {"type": "all2all_tanh", "->": {"output_sample_shape": 6},
-         "<-": {"learning_rate": 0.05, "gradient_moment": 0.9}},
-        {"type": "softmax", "->": {"output_sample_shape": 4},
-         "<-": {"learning_rate": 0.05, "gradient_moment": 0.9}},
-    ]
-    params, step_fn, _e, _a = lower_specs(
-        specs, (12,),
-        input_norm=(numpy.float32(1 / 255.0), numpy.float32(0.0)))
-    key = jax.random.key(3)
-    p_xla, _ = jax.jit(epoch_runner(step_fn, n, batch))(
-        params, data, labels, key)
-    from veles_tpu.ops import gather as G
-    real_pallas = G._gather_pallas
-    hits = []
-
-    def counting(*a, **k):
-        hits.append(1)
-        return real_pallas(*a, **k)
-
-    _ABSENT = object()
-    saved = {k: root.common.engine.__dict__.get(k, _ABSENT)
-             for k in ("pallas_gather", "interpret")}
-    try:
-        root.common.engine.pallas_gather = True
-        root.common.engine.interpret = True
-        G._gather_pallas = counting
-        p_pl, _ = jax.jit(epoch_runner(step_fn, n, batch))(
-            params, data, labels, key)
-    finally:
-        G._gather_pallas = real_pallas
-        for k, v in saved.items():      # restore, don't just delete
-            if v is _ABSENT:
-                root.common.engine.__dict__.pop(k, None)
-            else:
-                root.common.engine.__dict__[k] = v
-    assert hits, "the Pallas kernel was never dispatched"
-    for a, b in zip(jax.tree.leaves(p_xla), jax.tree.leaves(p_pl)):
-        numpy.testing.assert_array_equal(numpy.asarray(a),
-                                         numpy.asarray(b))
-
-
 def test_epoch_runner_rejects_tiny_dataset():
     import pytest as _pytest
     from veles_tpu.znicz.fused_graph import epoch_runner

@@ -3,8 +3,7 @@
 
 1. interpret-mode PARITY ORACLES — the fused backward-GD Pallas kernel
    (dW + optimizer epilogue / db / dX, every activation × both weight
-   storage layouts) against the dense ``znicz.gd._gd_math`` reference,
-   and the gather+normalize loader head against its jnp twin;
+   storage layouts) against the dense ``znicz.gd._gd_math`` reference;
 2. END-TO-END parity — ``kernels=pallas`` must train to the same
    weights as ``kernels=xla`` (documented interpret-mode tolerance)
    with ZERO steady-state recompiles on every training path: the
@@ -66,31 +65,6 @@ def test_gd_fused_matches_dense_math(activation, transposed):
             numpy.asarray(g), numpy.asarray(r), atol=5e-5, rtol=0,
             err_msg="%s (activation=%s, transposed=%s)"
                     % (name, activation, transposed))
-
-
-def test_gather_norm_interpret_matches_jnp():
-    """The loader head: u8 row gather + normalize, negative indices
-    zero-filled, both scalar and per-feature norms."""
-    from veles_tpu.ops.gather import (_gather_norm_jnp,
-                                      _gather_norm_pallas, _norm_row)
-    rng = numpy.random.default_rng(11)
-    data = jnp.asarray(rng.integers(0, 256, (37, 5, 3)), jnp.uint8)
-    idx = jnp.asarray([3, 36, -1, 0, 17, -1, 9, 2], jnp.int32)
-    feat = int(numpy.prod(data.shape[1:]))
-    for scale, shift in (
-            (1.0 / 255.0, 0.0),
-            (rng.standard_normal(feat).astype(numpy.float32),
-             rng.standard_normal(feat).astype(numpy.float32))):
-        ref = _gather_norm_jnp(data, idx,
-                               jnp.asarray(scale, jnp.float32),
-                               jnp.asarray(shift, jnp.float32))
-        got = _gather_norm_pallas(
-            data.reshape(data.shape[0], -1), idx,
-            _norm_row(scale, feat), _norm_row(shift, feat),
-            interpret=True).reshape(ref.shape)
-        numpy.testing.assert_allclose(numpy.asarray(got),
-                                      numpy.asarray(ref), atol=1e-6)
-        assert float(jnp.max(jnp.abs(got[jnp.asarray([2, 5])]))) == 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +273,7 @@ def _pallas_names(fn, *args):
 
 
 def _kernel_cases():
-    from veles_tpu.ops import attention, gather, gemm, qgemm
+    from veles_tpu.ops import attention, gemm, qgemm
     from veles_tpu.ops import random as ops_random
     f32 = jnp.float32
     q = jnp.zeros((1, 16, 2, 8), f32)           # (b, s, h, d)
@@ -311,8 +285,6 @@ def _kernel_cases():
     lengths = jnp.ones(2, jnp.int32)
     a = jnp.zeros((8, 16), f32)
     w = jnp.zeros((16, 8), f32)
-    rows = jnp.zeros((6, 128), f32)
-    idx = jnp.arange(4, dtype=jnp.int32)
     gd = (a, jnp.zeros((8, 8), f32), jnp.zeros((8, 8), f32), w,
           jnp.zeros(8, f32), jnp.zeros_like(w), jnp.zeros(8, f32))
 
@@ -343,11 +315,6 @@ def _kernel_cases():
         "veles_gd_err_input": (gd_fused, gd),
         "veles_gd_update_w": (gd_fused, gd),
         "veles_gd_update_b": (gd_fused, gd),
-        "veles_gather": (lambda d, i: gather._gather_pallas(
-            d, i, interpret=True), (rows, idx)),
-        "veles_gather_norm": (lambda d, i: gather._gather_norm_pallas(
-            d, i, jnp.ones((1, 128), f32), jnp.zeros((1, 128), f32),
-            interpret=True), (rows, idx)),
         "veles_uniform": (lambda s: ops_random._uniform_pallas_tpu(
             s, (8, 128)), (jnp.int32(1),)),
     }
@@ -357,8 +324,7 @@ def _kernel_cases():
     "veles_flash_fwd", "veles_flash_bwd_dq", "veles_flash_bwd_dkv",
     "veles_attn_decode", "veles_attn_paged_decode", "veles_matmul",
     "veles_qmatmul", "veles_gd_err_input", "veles_gd_update_w",
-    "veles_gd_update_b", "veles_gather", "veles_gather_norm",
-    "veles_uniform"])
+    "veles_gd_update_b", "veles_uniform"])
 def test_every_pallas_call_carries_its_kernels_name(name):
     """The name a device trace shows for a Pallas kernel is the one
     its ``pallas_call`` was given: one name a kernel."""
@@ -385,7 +351,7 @@ def test_no_pallas_call_site_without_a_name():
             head = text[match.end():match.end() + 400]
             assert re.search(r'\bname="veles_[a-z_]+"', head), \
                 (fname, text[:match.start()].count("\n") + 1)
-    assert sites == 16
+    assert sites == 14
 
 
 def _scope_names(lowered):

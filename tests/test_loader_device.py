@@ -17,7 +17,7 @@ from veles_tpu.backends import CPUDevice, NumpyDevice
 from veles_tpu.config import root
 from veles_tpu.dummy import DummyLauncher
 from veles_tpu.loader.base import TRAIN
-from veles_tpu.loader.fullbatch import FullBatchLoader
+from veles_tpu.loader.fullbatch import FullBatchLoader, FullBatchLoaderMSE
 from veles_tpu.znicz.standard_workflow import StandardWorkflow
 
 
@@ -85,6 +85,34 @@ def _build(device=None, minibatch_size=48, max_epochs=3, seed=5,
                          "fail_iterations": 10 ** 6})
     wf.launcher = DummyLauncher()
     wf.initialize(device=device or CPUDevice())
+    return wf
+
+
+class SynthMSE(FullBatchLoaderMSE):
+    def load_data(self):
+        rng = numpy.random.default_rng(3)
+        data = rng.standard_normal((120, 12)).astype(numpy.float32)
+        self.original_data.mem = data
+        self.original_targets.mem = (
+            data[:, :4] * 0.5).astype(numpy.float32)
+        self.class_lengths[:] = [0, 40, 80]
+
+
+def _build_mse():
+    prng.seed_all(7)
+    wf = StandardWorkflow(
+        None,
+        loader_factory=lambda w: SynthMSE(w, minibatch_size=32),
+        layers=[{"type": "all2all_tanh",
+                 "->": {"output_sample_shape": 8},
+                 "<-": {"learning_rate": 0.05}},
+                {"type": "all2all",
+                 "->": {"output_sample_shape": 4},
+                 "<-": {"learning_rate": 0.05}}],
+        loss_function="mse",
+        decision_config={"max_epochs": 3, "fail_iterations": 10 ** 6})
+    wf.launcher = DummyLauncher()
+    wf.initialize(device=CPUDevice())
     return wf
 
 
@@ -198,47 +226,52 @@ def test_mse_targets_ride_the_device_stage(loader_mode):
     """FullBatchLoaderMSE extends the in-program gather with targets —
     an MSE workflow trains through the loader-headed segment and
     matches the host path."""
-    from veles_tpu.loader.fullbatch import FullBatchLoaderMSE
-
-    class SynthMSE(FullBatchLoaderMSE):
-        def load_data(self):
-            rng = numpy.random.default_rng(3)
-            n = 120
-            data = rng.standard_normal((n, 12)).astype(numpy.float32)
-            self.original_data.mem = data
-            self.original_targets.mem = (
-                data[:, :4] * 0.5).astype(numpy.float32)
-            self.class_lengths[:] = [0, 40, 80]
-
-    def build(mode):
-        root.common.engine.loader = mode
-        prng.seed_all(7)
-        wf = StandardWorkflow(
-            None,
-            loader_factory=lambda w: SynthMSE(w, minibatch_size=32),
-            layers=[{"type": "all2all_tanh",
-                     "->": {"output_sample_shape": 8},
-                     "<-": {"learning_rate": 0.05}},
-                    {"type": "all2all",
-                     "->": {"output_sample_shape": 4},
-                     "<-": {"learning_rate": 0.05}}],
-            loss_function="mse",
-            decision_config={"max_epochs": 3,
-                             "fail_iterations": 10 ** 6})
-        wf.launcher = DummyLauncher()
-        wf.initialize(device=CPUDevice())
-        return wf
-
     loader_mode("device")
-    wf_dev = build("device")
+    wf_dev = _build_mse()
     assert wf_dev.stitch_report()["loader_headed"][0]
     assert "minibatch_targets" in [
         name for name, *_rest in wf_dev.loader._device_stage_plan()]
     wf_dev.run()
-    wf_host = build("host")
+    loader_mode("host")
+    wf_host = _build_mse()
     wf_host.run()
     assert wf_dev.decision.best_mse == pytest.approx(
         wf_host.decision.best_mse, rel=1e-3)
+
+
+def _trained(wf):
+    out = []
+    for fwd in wf.forwards:
+        for vec in (fwd.weights, fwd.bias):
+            vec.map_read()
+            out.append(numpy.array(vec.mem))
+    return out
+
+
+@pytest.mark.parametrize("loss", ["softmax", "mse"])
+def test_host_path_trains_on_the_rows_it_served(loss, loader_mode,
+                                                aligned):
+    """The host loader's gather takes its indices from a Vector whose
+    host array ``fill_indices`` rewrites for the NEXT minibatch; jax's
+    CPU backend takes an aligned argument without a copy and runs the
+    gather later.  With that array aligned, the host path must still
+    end, every time, where the device path (in-program gather, no such
+    argument) ends."""
+    def build(mode):
+        loader_mode(mode)
+        return _build() if loss == "softmax" else _build_mse()
+
+    wf_dev = build("device")
+    wf_dev.run()
+    for _ in range(5):
+        wf_host = build("host")
+        indices = wf_host.loader.minibatch_indices
+        indices.reset(aligned(indices.shape, indices.dtype))
+        wf_host.run()
+        for dev, host in zip(_trained(wf_dev), _trained(wf_host)):
+            numpy.testing.assert_array_equal(host, dev)
+        if loss == "mse":
+            assert wf_host.decision.best_mse == wf_dev.decision.best_mse
 
 
 # -- transfer elimination ---------------------------------------------------

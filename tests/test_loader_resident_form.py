@@ -12,7 +12,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from veles_tpu import prng, trace
 from veles_tpu.backends import CPUDevice, NumpyDevice
-from veles_tpu.config import root
 from veles_tpu.dummy import DummyLauncher, DummyWorkflow
 from veles_tpu.loader.fullbatch import FullBatchLoader, FullBatchLoaderMSE
 from veles_tpu.memory import Vector, Watcher
@@ -110,18 +109,13 @@ def test_take_rows_norm_over_the_form_equals_numpy(elems, formed, dtype):
 
 @pytest.mark.parametrize("sample_shape, dtype", [
     ((32, 32, 3), numpy.float32), ((227, 227, 3), numpy.uint8)])
-def test_pallas_twins_read_the_form(sample_shape, dtype):
+def test_both_heads_read_the_form_at_a_sample_shape(sample_shape, dtype):
     rng = numpy.random.default_rng(4)
     host = rng.integers(0, 255, (6,) + sample_shape).astype(dtype)
     dev = upload_rows(host, _put)
     idx = numpy.array([4, -1, 0, 5], numpy.int32)
-    try:
-        root.common.engine.interpret = True
-        out = numpy.asarray(gather.take_rows(dev, idx, use_pallas=True))
-        normed = numpy.asarray(gather.take_rows_norm(
-            dev, idx, (0.5, 1.0), use_pallas=True))
-    finally:
-        root.common.engine.__dict__.pop("interpret", None)
+    out = numpy.asarray(gather.take_rows(dev, idx))
+    normed = numpy.asarray(gather.take_rows_norm(dev, idx, (0.5, 1.0)))
     ref = _numpy_rows(host, idx)
     assert out.dtype == dtype and (out == ref).all()
     mask = (idx >= 0).reshape(-1, 1, 1, 1)

@@ -4,6 +4,7 @@ map/unmap semantics, pickling device data transparently)."""
 import pickle
 
 import numpy
+import pytest
 
 from veles_tpu.backends import CPUDevice, NumpyDevice
 from veles_tpu.memory import Vector, Watcher
@@ -43,6 +44,37 @@ def test_host_edit_republish():
     v.mem[...] = 7.0
     v.unmap()
     assert float(numpy.asarray(v.devmem)[0, 0]) == 7.0
+
+
+def _map_write_edit(v, host):
+    v.map_write()
+    v.mem[...] = 7.0
+
+
+def _publish_host(v, host):
+    v.publish(host_array=numpy.full(v.shape, 7.0, numpy.float32))
+
+
+def _write_installed_array(v, host):
+    host[...] = 7.0       # the array `v.mem = host` installed, no copy
+
+
+@pytest.mark.parametrize("write", [
+    _map_write_edit, _publish_host, _write_installed_array])
+def test_device_copy_is_not_the_host_array(write, aligned):
+    """The rule of memory.py: what ``devmem`` returned keeps its values
+    whatever the host side does afterwards.  On the CPU backend jax
+    would alias an aligned host buffer, and the write would show in a
+    "device copy" that a queued step still reads."""
+    host = aligned((8, 16), numpy.float32)
+    host[...] = 1.0
+    v = Vector()
+    v.mem = host
+    v.initialize(CPUDevice())
+    before = v.devmem
+    write(v, host)
+    assert (v.mem == 7.0).all()
+    assert (numpy.asarray(before) == 1.0).all()
 
 
 def test_interpret_device_passthrough():

@@ -10,7 +10,7 @@ import pytest
 
 from veles_tpu.ops import gemm, normalize, reduce as reduce_ops
 from veles_tpu.ops.join import join as join_op
-from veles_tpu.ops.gather import _gather_jnp, _gather_pallas, take_rows
+from veles_tpu.ops.gather import take_rows
 from veles_tpu.ops.random import dropout_mask, normal, uniform
 
 
@@ -31,7 +31,7 @@ class TestMatmul:
 
     def test_jnp_path(self):
         a, b, bv, ref = self._golden(17, 33, 9, bias=True)
-        out = gemm.matmul(a, b, bv, use_pallas=False)
+        out = gemm.matmul(a, b, bv)
         assert numpy.allclose(out, ref, atol=1e-4)
 
     def test_pallas_interpret_matches(self):
@@ -56,7 +56,7 @@ class TestMatmul:
 
     def test_activation_fused(self):
         a, b, bv, ref = self._golden(8, 16, 4, activation="tanh", bias=True)
-        out = gemm.matmul(a, b, bv, "tanh", use_pallas=False)
+        out = gemm.matmul(a, b, bv, "tanh")
         assert numpy.allclose(out, ref, atol=1e-4)
 
     def test_grad_through_matmul(self):
@@ -85,7 +85,7 @@ class TestMatmul:
         b = numpy.random.default_rng(4).standard_normal(
             (7, 2)).astype(numpy.float32)
         ga = jax.grad(lambda a_: jnp.sum(gemm.matmul(
-            a_, b, None, "strict_relu", use_pallas=False)))(a)
+            a_, b, None, "strict_relu")))(a)
         ra = jax.grad(lambda a_: jnp.sum(
             jnp.maximum(a_ @ b, 0)))(a)
         assert numpy.allclose(ga, ra, atol=1e-4)
@@ -114,31 +114,20 @@ class TestGather:
     def test_basic(self):
         data = numpy.arange(40, dtype=numpy.float32).reshape(10, 4)
         idx = numpy.array([3, 1, 7], dtype=numpy.int32)
-        out = take_rows(data, idx, use_pallas=False)
+        out = take_rows(data, idx)
         assert (numpy.asarray(out) == data[idx]).all()
 
     def test_negative_index_zero_fill(self):
         data = numpy.ones((5, 3), dtype=numpy.float32)
         idx = numpy.array([0, -1, 2], dtype=numpy.int32)
-        out = numpy.asarray(take_rows(data, idx, use_pallas=False))
+        out = numpy.asarray(take_rows(data, idx))
         assert (out[1] == 0).all() and (out[0] == 1).all()
-
-    def test_pallas_interpret_matches_jnp(self):
-        data = numpy.random.default_rng(2).standard_normal(
-            (32, 128)).astype(numpy.float32)
-        idx = numpy.array([5, 0, 31, -1, 7], dtype=numpy.int32)
-        ref = numpy.asarray(_gather_jnp(jnp.asarray(data),
-                                        jnp.asarray(idx)))
-        out = numpy.asarray(_gather_pallas(jnp.asarray(data),
-                                           jnp.asarray(idx),
-                                           interpret=True))
-        assert numpy.allclose(out, ref)
 
     def test_3d_data(self):
         data = numpy.random.default_rng(3).standard_normal(
             (6, 4, 5)).astype(numpy.float32)
         idx = numpy.array([2, 4], dtype=numpy.int32)
-        out = numpy.asarray(take_rows(data, idx, use_pallas=False))
+        out = numpy.asarray(take_rows(data, idx))
         assert out.shape == (2, 4, 5)
         assert numpy.allclose(out, data[idx])
 
@@ -393,70 +382,6 @@ def test_two_point_marginal_survives_short_point_stall():
     m2 = _two_point_marginal(noisy, 4, 32, target_signal=0.05,
                              max_k=10000)
     assert m2 == pytest.approx(true_per_unit, rel=0.25)
-
-
-def test_autotune_gather_writes_db_and_take_rows_dispatches(
-        tmp_path, monkeypatch):
-    """autotune_gather persists the A/B winner (Pallas failures are a
-    recorded verdict, not a crash — on CPU the non-interpret Pallas
-    call fails, so XLA must win); take_rows dispatch order is config
-    force → DB verdict → XLA default."""
-    import jax.numpy as jnp
-
-    from veles_tpu.config import root
-    from veles_tpu.ops import benchmark as B
-    from veles_tpu.ops import gather as G
-
-    db_path = str(tmp_path / "dev.json")
-    info = B.autotune_gather(n=64, row=(9, 9, 3), batch=8,
-                             db_path=db_path)
-    entry = info.ratings["gather"]["uint8"]
-    assert entry["backend"] == "xla"       # CPU: pallas can't run
-    assert entry["xla_ms"] > 0
-    assert entry["pallas_ms"] is None and entry["pallas_error"]
-    assert B.gather_choice(db_path=db_path) is False
-    assert B.gather_choice(
-        db_path=str(tmp_path / "absent.json")) is None
-
-    # a Pallas verdict transfers ONLY to the row size it was measured
-    # at (unmeasured shapes could fail at Mosaic compile time, beyond
-    # any fallback) — mismatched rows get XLA
-    import json as _json
-
-    import jax
-    pallas_db = str(tmp_path / "pallas.json")
-    model = jax.devices()[0].device_kind
-    with open(pallas_db, "w") as fout:
-        _json.dump({model: {"gather": {"uint8": {
-            "backend": "pallas", "xla_ms": 1.0, "pallas_ms": 0.5,
-            "shape": [64, 9, 9, 3], "batch": 8}}}}, fout)
-    assert B.gather_choice(db_path=pallas_db,
-                           row_elems=9 * 9 * 3) is True
-    assert B.gather_choice(db_path=pallas_db, row_elems=784) is False
-    assert B.gather_choice(db_path=pallas_db) is True  # no row info
-
-    # dispatch: DB verdict consulted only when config doesn't force
-    calls = []
-
-    def fake_choice(dtype_name="uint8", db_path=None, row_elems=None):
-        calls.append((dtype_name, row_elems))
-        return False
-
-    monkeypatch.setattr("veles_tpu.ops.benchmark.gather_choice",
-                        fake_choice)
-    data = jnp.zeros((4, 6), jnp.float32)
-    idx = jnp.asarray([1, -1], jnp.int32)
-    out = numpy.asarray(G.take_rows(data, idx))
-    assert out.shape == (2, 6) and calls   # DB was consulted
-    calls.clear()
-    try:
-        root.common.engine.pallas_gather = False
-        numpy.asarray(G.take_rows(data, idx))
-        assert not calls                   # config force skips the DB
-    finally:
-        # remove the key outright: leaving any value (even a
-        # pseudo-absent sentinel) would leak order-dependent state
-        root.common.engine.__dict__.pop("pallas_gather", None)
 
 
 def test_timing_pins_operands_on_device():

@@ -265,6 +265,21 @@ def test_stitched_matches_unstitched_weights_and_metrics(stitch_config):
     assert numpy.abs(cm_on - cm_off).sum() <= 0.02 * cm_on.sum()
 
 
+def test_unstitched_chain_is_given_no_host_array(stitch_config):
+    """A Vector with no device answers ``devmem`` with its host array
+    itself; the eager chain's jitted evaluator then read the loader's
+    labels while the next fill rewrote them (memory.py's rule).  What a
+    loader serves has the workflow's device, stitched or not."""
+    stitch_config.stitch = "off"
+    wf = build(CPUDevice())
+    assert wf.stitch_report()["dispatches"] == 0
+    wf.loader.run()
+    for vec in (wf.loader.minibatch_data, wf.loader.minibatch_labels,
+                wf.evaluator.labels, wf.forwards[0].input):
+        assert vec.device is wf.device
+        assert not isinstance(vec.devmem, numpy.ndarray)
+
+
 def test_deferred_metrics_are_device_scalars_until_flush(stitch_config):
     wf = build(CPUDevice(), max_epochs=2)
     wf.run()

@@ -68,12 +68,10 @@ KNOB_REGISTRY = {
         "snapshot cadence/policy for the snapshotter",
     "root.common.engine.kernels":
         "training-kernel backend (auto | xla | pallas): the fused "
-        "backward-GD / flash-attention / gather family, resolved at "
+        "backward-GD / flash-attention family, resolved at "
         "stage-build time (auto consults the autotune DB)",
     "root.common.engine.pallas_gemm":
         "use the Pallas GEMM kernel where shapes allow (on | off)",
-    "root.common.engine.pallas_gather":
-        "use the Pallas gather kernel for embedding lookups",
     "root.common.engine.s2d_conv":
         "space-to-depth conv input transform (on | off)",
     "root.common.engine.seed":

@@ -418,6 +418,32 @@ class CPUDevice(_JaxDevice):
     BACKEND = "cpu"
     PLATFORM = "cpu"
 
+    def put(self, array):
+        """Upload a PRIVATE copy of a numpy ``array`` (:func:`upload`).
+        This device's memory IS host memory: ``jax.device_put`` takes a
+        64-byte aligned numpy buffer without a copy and returns at once,
+        so the "device copy" of a Vector would be the very array the
+        host goes on writing (``mem[...] =``, ``publish``), under a step
+        that is still queued.  A chip's upload is a copy by nature, so
+        :class:`TPUDevice` keeps the plain put."""
+        return upload(array, self._jax_devices[0])
+
+
+def upload(array, placement):
+    """``jax.device_put(array, placement)`` (a jax device or a sharding)
+    under the one rule: a host buffer that jax has been handed is never
+    written again.  Numpy arrays (``array`` may be a pytree of them)
+    bound for CPU devices, where jax would alias their buffers
+    (:meth:`CPUDevice.put`), are copied first; anything else goes as it
+    is."""
+    import jax
+    devices = getattr(placement, "device_set", (placement,))
+    if all(d.platform == "cpu" for d in devices):
+        array = jax.tree.map(
+            lambda a: a.copy() if isinstance(a, numpy.ndarray) else a,
+            array)
+    return jax.device_put(array, placement)
+
 
 class NumpyDevice(Device):
     """Pure-numpy interpret backend (ref ``backends.py:918``): the debug /

@@ -274,6 +274,14 @@ class Loader(Unit):
             raise LoaderError(
                 "minibatch_data MUST be allocated in "
                 "create_minibatch_data()")
+        # a Vector with no device gives a jitted consumer its host
+        # array itself, which the next fill rewrites under a step that
+        # is still queued: what a loader serves (data, labels, a
+        # subclass's targets) uploads like any unit's Vectors (a Loader
+        # is not an AcceleratedUnit)
+        for name, vec in vars(self).items():
+            if name.startswith("minibatch_") and isinstance(vec, Vector):
+                vec.initialize(self.device)
         self.analyze_dataset()
         self.shuffle()
 

@@ -159,7 +159,6 @@ class FullBatchLoader(Loader):
                 and self.store_in_device_memory:
             self.original_data.initialize(self.device)
             self.original_data.devmem  # upload once
-            self.minibatch_data.initialize(self.device)
             if self.resident_labels:
                 self.resident_labels.initialize(self.device)
 
@@ -179,7 +178,9 @@ class FullBatchLoader(Loader):
             self.labels_mapping = {raw: i for i, raw in enumerate(uniques)}
 
     def fill_minibatch(self):
-        """Gather the minibatch rows (device-side when resident)."""
+        """Gather the minibatch rows (device-side when resident);
+        returns the indices it took them by, for a subclass with further
+        rows to take."""
         count = self.minibatch_size
         if count < self.max_minibatch_size:
             # short batch: -1 the tail for DIRECT fill_minibatch
@@ -188,7 +189,10 @@ class FullBatchLoader(Loader):
             # the write entirely (the fast-skip satellite)
             self.minibatch_indices.map_write()
             self.minibatch_indices.mem[count:] = -1
-        indices = self.minibatch_indices.mem[:self.max_minibatch_size]
+        # a COPY: fill_indices rewrites the Vector's host array for the
+        # next minibatch while this one's gather may still be queued
+        indices = self.minibatch_indices.mem[
+            :self.max_minibatch_size].copy()
         if self.device is not None and not self.device.is_interpret \
                 and self.store_in_device_memory:
             self.minibatch_data.devmem = take_rows(
@@ -215,6 +219,7 @@ class FullBatchLoader(Loader):
                     self.raw_minibatch_labels[i] = \
                         self.original_labels[index] if index >= 0 \
                         else None
+        return indices
 
     def pad_minibatch(self, minibatch_size):
         """No-op: fill_minibatch gathers with -1 markers which zero/-1
@@ -419,14 +424,9 @@ class FullBatchLoaderMSE(FullBatchLoader):
                 and self.store_in_device_memory:
             self.original_targets.initialize(self.device)
             self.original_targets.devmem
-            self.minibatch_targets.initialize(self.device)
 
     def fill_minibatch(self):
-        super(FullBatchLoaderMSE, self).fill_minibatch()
-        count = self.minibatch_size
-        self.minibatch_indices.map_read()
-        indices = self.minibatch_indices.mem[:self.max_minibatch_size].copy()
-        indices[count:] = -1
+        indices = super(FullBatchLoaderMSE, self).fill_minibatch()
         if self.device is not None and not self.device.is_interpret \
                 and self.store_in_device_memory:
             self.minibatch_targets.devmem = take_rows(

@@ -13,6 +13,15 @@ the Vector knows whether host or device holds the freshest data and
 converts lazily.  ``map_write → unmap`` round-trips still work (host edit
 then re-upload), but the idiomatic fast path for jitted units is
 ``v.devmem`` in / reassign ``v.devmem`` out — no copies, donation-friendly.
+The protocol takes host copy and device copy for two memories, and on the
+CPU backend jax would make them one (an aligned numpy buffer is taken
+without a copy, by ``device_put`` and by a jitted call alike, and the
+call returns before it has run).  So the rule: **a host buffer that jax
+has been handed is never written again** — uploads to CPU devices hand
+over a private copy (:meth:`veles_tpu.backends.CPUDevice.put`,
+:func:`~veles_tpu.backends.upload`), a Vector that a jitted unit
+reads has a device (the owner attaches it), and no jitted call is given
+``mem`` or a view of it.
 Pickling syncs device→host exactly like the reference, so whole-workflow
 snapshots capture weights regardless of where they live.
 """
@@ -22,6 +31,7 @@ import threading
 import numpy
 
 from veles_tpu import trace
+from veles_tpu.backends import upload
 from veles_tpu.distributable import Pickleable
 
 
@@ -273,9 +283,7 @@ class Vector(Pickleable):
                 # reshuffles included) lands with its mesh sharding,
                 # so the AOT pod executables never see a drifted
                 # single-device array
-                import jax
-                self._set_devmem(jax.device_put(self._mem,
-                                                self._sharding_))
+                self._set_devmem(upload(self._mem, self._sharding_))
             else:
                 self._set_devmem(self._device.put(self._mem))
             Watcher.track_h2d(self._mem.nbytes)

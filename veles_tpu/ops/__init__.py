@@ -9,16 +9,17 @@ reference template      this package
 matrix_multiplication   :mod:`veles_tpu.ops.gemm` (Pallas tiled)
 matrix_reduce           :mod:`veles_tpu.ops.reduce`
 random (xorshift1024*)  :mod:`veles_tpu.ops.random` (TPU PRNG)
-fullbatch_loader        :mod:`veles_tpu.ops.gather`
+fullbatch_loader        :mod:`veles_tpu.ops.gather` (XLA's gather
+                        over a rows-major device form; no kernel)
 mean_disp_normalizer    :mod:`veles_tpu.ops.normalize`
 join.jcl (Jinja2)       :mod:`veles_tpu.ops.join`
 benchmark               :mod:`veles_tpu.ops.benchmark`
 =====================  ==========================================
 
-Every op has (a) a Pallas TPU kernel for the hot path and (b) a pure jnp
-fallback that XLA fuses — used on CPU, under interpret mode, and as the
-golden reference in tests.  Dispatch is by the current JAX default
-platform unless forced via ``use_pallas=``.
+An op with a Pallas TPU kernel for the hot path also has a pure jnp
+twin that XLA fuses — used on CPU, under interpret mode, and as the
+golden reference in tests; dispatch between the two is by the current
+JAX default platform unless forced via ``use_pallas=``.
 """
 
 from veles_tpu.ops.gemm import matmul  # noqa: F401
@@ -42,13 +43,12 @@ def resolved_backend(family, dtype, shape):
     implies a kernel ran when the DB routed the family to XLA.
 
     ``family`` / ``shape``: ``gemm`` and ``gemm_int8`` (m, k, n);
-    ``gd`` (batch, fan_in, neurons); ``gather`` (rows, \\*row_shape);
-    ``flash_attention``, ``flash_attention_bwd`` and ``chunk_attention``
-    (b, s, h, d); ``decode_attention`` (decode, verify and their paged
+    ``gd`` (batch, fan_in, neurons); ``flash_attention``,
+    ``flash_attention_bwd`` and ``chunk_attention`` (b, s, h, d);
+    ``decode_attention`` (decode, verify and their paged
     twins: Pallas on the TPU, no DB entry consulted)."""
-    import jax
     import jax.numpy as jnp
-    from veles_tpu.ops import attention, gather, gemm, qgemm
+    from veles_tpu.ops import attention, gemm, qgemm
     dtype = jnp.dtype(dtype)
     if family == "gemm":
         pallas = gemm._dispatch(None, None, dtype, tuple(shape))[0]
@@ -56,9 +56,6 @@ def resolved_backend(family, dtype, shape):
         pallas = qgemm._dispatch(None, None, dtype, tuple(shape))[0]
     elif family == "gd":
         pallas = gemm.gd_kernel_choice(dtype, tuple(shape))[0] == "pallas"
-    elif family == "gather":
-        pallas = gather._use_pallas(
-            jax.ShapeDtypeStruct(tuple(shape), dtype), None)
     elif family in ("flash_attention", "chunk_attention"):
         pallas = attention._resolve_backend(None, dtype, tuple(shape))
     elif family == "flash_attention_bwd":

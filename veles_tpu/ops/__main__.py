@@ -7,8 +7,7 @@ families end to end on any host, TPU or not:
    reference for every family shipped by ``veles_tpu.ops``: the fused
    backward-GD kernels (dW+optimizer epilogue / db / dX,
    ``ops.gemm.gd_fused_pallas`` vs ``znicz.gd._gd_math``, every
-   activation × both storage layouts), the gather+normalize loader
-   head (``ops.gather``), and flash-attention fwd+bwd (the
+   activation × both storage layouts) and flash-attention fwd+bwd (the
    ``jax.custom_vjp`` pair vs dense attention under ``jax.grad``);
 2. **autotune table round-trip** — a real (toy-shape) ``autotune_gd``
    sweep into a temp DB, read back through ``gemm_choice`` and
@@ -70,36 +69,6 @@ def _check_gd_parity():
                         "transposed=%s): max |Δ| = %.3e"
                         % (name, activation, transposed, err))
     return worst, None
-
-
-def _check_gather_parity():
-    import jax.numpy as jnp
-
-    from veles_tpu.ops.gather import (
-        _gather_norm_jnp, _gather_norm_pallas, _norm_row)
-
-    rng = numpy.random.default_rng(11)
-    data = jnp.asarray(rng.integers(0, 256, (37, 5, 3)), jnp.uint8)
-    idx = jnp.asarray([3, 36, -1, 0, 17, -1, 9, 2], jnp.int32)
-    feat = int(numpy.prod(data.shape[1:]))
-    for norm in ((1.0 / 255.0, 0.0),
-                 (rng.standard_normal(feat).astype(numpy.float32),
-                  rng.standard_normal(feat).astype(numpy.float32))):
-        ref = _gather_norm_jnp(data, idx,
-                               jnp.asarray(norm[0], jnp.float32),
-                               jnp.asarray(norm[1], jnp.float32))
-        got = _gather_norm_pallas(data.reshape(data.shape[0], -1),
-                                  idx, _norm_row(norm[0], feat),
-                                  _norm_row(norm[1], feat),
-                                  interpret=True)
-        got = got.reshape(ref.shape)
-        err = float(jnp.max(jnp.abs(ref - got)))
-        if err > 1e-6:
-            return None, ("gather+normalize mismatch: max |Δ| = %.3e"
-                          % err)
-        if float(jnp.max(jnp.abs(got[jnp.asarray([2, 5])]))) != 0.0:
-            return None, "gather+normalize: pad rows are not zero"
-    return 0.0, None
 
 
 def _check_attention_parity():
@@ -246,9 +215,6 @@ def run_smoke():
     gd_err, msg = _check_gd_parity()
     if msg:
         return _fail(msg)
-    _, msg = _check_gather_parity()
-    if msg:
-        return _fail(msg)
     attn_err, msg = _check_attention_parity()
     if msg:
         return _fail(msg)
@@ -259,9 +225,8 @@ def run_smoke():
     if msg:
         return _fail(msg)
     print("ops smoke: OK — GD parity max |Δ| = %.3e, attention "
-          "fwd+bwd max |Δ| = %.3e, gather+normalize exact, gd "
-          "autotune table round-trips, 0 recompiles under "
-          "kernels=pallas" % (gd_err, attn_err))
+          "fwd+bwd max |Δ| = %.3e, gd autotune table round-trips, "
+          "0 recompiles under kernels=pallas" % (gd_err, attn_err))
     return 0
 
 

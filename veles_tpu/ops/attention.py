@@ -312,8 +312,8 @@ def _flash_bwd(q, k, v, o, lse, do, causal=False, block_q=128,
     forward (VMEM accumulators, per-tensor padding, causal block
     skipping), so the backward's matmuls tile the MXU at the swept
     block sizes instead of the XLA scan fallback's fixed-128 serial
-    chain (PROFILE_LM.md: backward 75% of the LM step at 34.6
-    TFLOP/s — the round-5 target).  ``lse`` is (b, h, sq)."""
+    chain (the backward's share of an LM step: not measured on the
+    chip).  ``lse`` is (b, h, sq)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     scale = 1.0 / (d ** 0.5)

@@ -448,21 +448,6 @@ def measure_s2d_ab(batch=256, spatial=227, dtype_name="bfloat16",
     return {"base_sec": secs[False], "s2d_sec": secs[True]}
 
 
-def _persist_ab_entry(rating_key, dtype_name, entry, save, db_path):
-    """Shared write path of the boolean-A/B autotunes (s2d, gather):
-    load the DB, set ``ratings[rating_key][dtype_name]``, save, and
-    invalidate the verdict cache."""
-    db_path = db_path or DEVICE_INFOS_JSON
-    model = jax.devices()[0].device_kind
-    db = DeviceInfo.load_db(db_path)
-    info = db.setdefault(model, DeviceInfo(model))
-    info.ratings.setdefault(rating_key, {})[dtype_name] = entry
-    if save:
-        DeviceInfo.save_db(db, db_path)
-    _verdict_cached.cache_clear()
-    return info
-
-
 def autotune_s2d(batch=256, spatial=227, dtype_name="bfloat16",
                  save=True, db_path=None):
     """Measure the space-to-depth conv rewrite A/B on the attached
@@ -472,114 +457,38 @@ def autotune_s2d(batch=256, spatial=227, dtype_name="bfloat16",
     the heuristic said s2d, the chip said 0.51x)."""
     secs = measure_s2d_ab(batch=batch, spatial=spatial,
                           dtype_name=dtype_name)
-    return _persist_ab_entry("s2d_conv", dtype_name, {
+    db_path = db_path or DEVICE_INFOS_JSON
+    model = jax.devices()[0].device_kind
+    db = DeviceInfo.load_db(db_path)
+    info = db.setdefault(model, DeviceInfo(model))
+    info.ratings.setdefault("s2d_conv", {})[dtype_name] = {
         "enabled": secs["s2d_sec"] < secs["base_sec"],
         "base_ms": round(secs["base_sec"] * 1e3, 4),
         "s2d_ms": round(secs["s2d_sec"] * 1e3, 4),
-        "shape": [batch, spatial, spatial, 3]}, save, db_path)
-
-
-def measure_gather_ab(n=4096, row=(227, 227, 3), dtype_name="uint8",
-                      batch=256, k1=4, k2=64):
-    """A/B of the resident-dataset minibatch row gather: XLA's native
-    gather vs the Pallas scalar-prefetch DMA kernel, ImageNet-conv
-    shaped by default (the ~12 ms/step e2e-vs-synthetic gap of r4's
-    banked AlexNet ladder).  Returns ``{"xla_sec": ..., "pallas_sec":
-    sec | None, "pallas_error": str | None}`` — the Pallas kernel may
-    be unsupported for a shape/generation, which is a recorded verdict,
-    not a crash."""
-    from veles_tpu.ops.gather import _gather_jnp, _gather_pallas
-
-    dtype = jnp.dtype(dtype_name)
-    f = int(numpy.prod(row))
-    rng = numpy.random.default_rng(0)
-    # generate the FLAT (n, f) array directly in its storage dtype
-    # (an (n,)+row int64 intermediate would be ~5 GB host for the
-    # default ImageNet shape)
-    if dtype.kind in "ui":
-        flat = jnp.asarray(rng.integers(0, 256, (n, f),
-                                        dtype=numpy.uint8).astype(dtype))
-    else:
-        flat = jnp.asarray(
-            rng.random((n, f), dtype=numpy.float32).astype(dtype))
-    idx0 = jnp.asarray(rng.integers(0, n, batch), jnp.int32)
-
-    def run(fn):
-        def unit(carry):
-            # the dataset rides the CARRY — closing over it would bake
-            # 633 MB into the program as a CONSTANT.  The
-            # serialized idx leads the tuple: the stopwatch's probe is
-            # derived from the FIRST carry leaf, and a probe on the
-            # pass-through dataset would let XLA DCE the whole loop.
-            idx, s, data_ = carry
-            # serialize iterations: the next gather's indices depend
-            # on the previous result's bytes
-            idx = (idx + (s * 0).astype(jnp.int32)) % n
-            out = fn(data_, idx)
-            # reduce the WHOLE output: a sliced probe would let XLA
-            # commute the slice into the gather and time a narrowed
-            # per-row fetch while the opaque Pallas arm moves full
-            # rows (the gemm sweep's round-2 guard, same hazard)
-            return (idx, jnp.sum(jnp.abs(out.astype(jnp.float32))),
-                    data_)
-
-        return inprogram_marginal(
-            unit, (idx0, jnp.float32(0.0), flat), k1=k1, k2=k2)
-
-    # both arms gather the same flat array and reduce the same full
-    # output, so the A/B isolates the gather backend itself
-    res = {"xla_sec": run(_gather_jnp), "pallas_sec": None,
-           "pallas_error": None}
-    try:
-        res["pallas_sec"] = run(lambda d, i: _gather_pallas(d, i))
-    except Exception as exc:   # unsupported shape/generation = verdict
-        res["pallas_error"] = "%s: %s" % (type(exc).__name__, exc)
-    return res
-
-
-def autotune_gather(n=4096, row=(227, 227, 3), dtype_name="uint8",
-                    batch=256, save=True, db_path=None):
-    """Measure the minibatch-gather A/B on the attached chip and
-    persist the winner under ``ratings["gather"]`` so
-    :func:`veles_tpu.ops.gather.take_rows` dispatches the resident-
-    dataset gather from a measurement."""
-    res = measure_gather_ab(n=n, row=row, dtype_name=dtype_name,
-                            batch=batch)
-    pallas_wins = (res["pallas_sec"] is not None
-                   and res["pallas_sec"] < res["xla_sec"])
-    entry = {
-        "backend": "pallas" if pallas_wins else "xla",
-        "xla_ms": round(res["xla_sec"] * 1e3, 4),
-        "pallas_ms": (None if res["pallas_sec"] is None
-                      else round(res["pallas_sec"] * 1e3, 4)),
-        "shape": [n] + list(row), "batch": batch}
-    if res["pallas_error"]:
-        entry["pallas_error"] = res["pallas_error"][:200]
-    return _persist_ab_entry("gather", dtype_name, entry, save,
-                             db_path)
+        "shape": [batch, spatial, spatial, 3]}
+    if save:
+        DeviceInfo.save_db(db, db_path)
+    _s2d_cached.cache_clear()
+    return info
 
 
 @functools.lru_cache(maxsize=64)
-def _verdict_cached(rating_key, model, dtype_name, db_path, _mtime):
+def _s2d_cached(model, dtype_name, db_path, _mtime):
     db = DeviceInfo.load_db(db_path)
     info = db.get(model)
     if info is None:
         return None
-    entry = info.ratings.get(rating_key, {}).get(dtype_name)
+    entry = info.ratings.get("s2d_conv", {}).get(dtype_name)
     if entry is None:
         return None            # unmeasured dtype: caller falls back
-    if rating_key == "s2d_conv":
-        return bool(entry.get("enabled"))
-    # gather: the verdict plus the row size it was measured at
-    shape = entry.get("shape") or []
-    row_elems = int(numpy.prod(shape[1:])) if len(shape) > 1 else None
-    return (entry.get("backend") == "pallas", row_elems)
+    return bool(entry.get("enabled"))
 
 
-def _device_db_verdict(rating_key, dtype_name, db_path):
-    """Shared mtime-cached boolean-verdict reader for per-device A/B
-    entries (``s2d_conv``, ``gather``): True/False from the DB, or
-    None when this (device generation, dtype) was never measured."""
+def s2d_choice(dtype_name="bfloat16", db_path=None):
+    """Measured space-to-depth verdict for the current device
+    generation: True/False from the DB's ``s2d_conv`` A/B entry
+    (mtime-cached), or None when this (device generation, dtype) was
+    never measured (callers fall back to the heuristic)."""
     db_path = db_path or DEVICE_INFOS_JSON
     try:
         model = jax.devices()[0].device_kind
@@ -589,47 +498,10 @@ def _device_db_verdict(rating_key, dtype_name, db_path):
         mtime = os.path.getmtime(db_path)
     except OSError:
         return None
-    return _verdict_cached(rating_key, model, dtype_name, db_path,
-                           mtime)
+    return _s2d_cached(model, dtype_name, db_path, mtime)
 
 
-def gather_choice(dtype_name="uint8", db_path=None, row_elems=None):
-    """Measured gather-backend verdict for the current device
-    generation: True (Pallas DMA) / False (XLA) from the DB's
-    ``gather`` A/B entry, or None when unmeasured (callers fall back
-    to the XLA path).
-
-    ``row_elems``: the caller's flattened row size.  A Pallas verdict
-    only transfers to the row size it was measured at — the kernel's
-    shape support (and its win) is not generic, and an unmeasured
-    shape that Mosaic rejects would fail at compile time of the
-    enclosing program, beyond any fallback — so a mismatch returns
-    False (XLA), never the measured True."""
-    verdict = _device_db_verdict("gather", dtype_name, db_path)
-    if verdict is None:
-        return None
-    is_pallas, measured_elems = verdict
-    if is_pallas and row_elems is not None \
-            and measured_elems != row_elems:
-        # a missing measured shape (legacy/hand-edited DB entry) is
-        # NON-transferable too: trusting it re-exposes the Mosaic
-        # compile-time failure this gate exists to prevent (ADVICE r4)
-        return False
-    return is_pallas
-
-
-gather_choice.cache_clear = _verdict_cached.cache_clear
-
-
-def s2d_choice(dtype_name="bfloat16", db_path=None):
-    """Measured space-to-depth verdict for the current device
-    generation: True/False from the DB's ``s2d_conv`` A/B entry, or
-    None when this device was never measured (callers fall back to
-    the heuristic)."""
-    return _device_db_verdict("s2d_conv", dtype_name, db_path)
-
-
-s2d_choice.cache_clear = _verdict_cached.cache_clear
+s2d_choice.cache_clear = _s2d_cached.cache_clear
 
 
 @functools.lru_cache(maxsize=256)

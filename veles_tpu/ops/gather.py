@@ -21,11 +21,10 @@ upload; :func:`take_rows` / :func:`take_rows_norm` index it and reshape
 only the rows they took.  ``tests/test_chip_compile.py`` compiles the
 gather for a described v5e both ways and holds the form to it.
 
-On the form the jnp path is one ``gather`` fusion over the rows taken;
-the Pallas path uses scalar-prefetched indices as the BlockSpec index
-map, so each sample row is DMA'd straight from the dataset in HBM into
-the output block — no materialized one-hot, no host round-trip for the
-epoch shuffle.
+On the form the gather is one XLA ``gather`` fusion over the rows taken.
+It is the only path: a Pallas kernel that DMAs each row by a
+scalar-prefetched index bought 0.08 ms a minibatch on the chip, 0.3% of
+the AlexNet cell's step and under its spread (PERF.md, PR 30).
 """
 
 import functools
@@ -33,10 +32,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from veles_tpu import trace
+from veles_tpu.backends import upload
 
 LANES = 128
 #: elements of the chip's (8 sublanes, 128 lanes) tile.  A ``[n, s, 128]``
@@ -158,7 +156,7 @@ def upload_rows(host, put, sharding=None):
     with trace.span("loader", "upload", stats):
         if row_shape is None:
             return put(host) if sharding is None \
-                else jax.device_put(host, sharding)
+                else upload(host, sharding)
         flat = host.reshape(n, elems)
         if sharding is None:
             return ResidentRows(_upload_form(flat, row_shape, put),
@@ -176,43 +174,9 @@ def upload_rows(host, put, sharding=None):
             lo, hi, _step = index[0].indices(n)
             parts.append(_upload_form(
                 flat[lo:hi], row_shape,
-                functools.partial(jax.device_put, device=device)))
+                functools.partial(upload, placement=device)))
         return ResidentRows(jax.make_array_from_single_device_arrays(
             shape, sharding, parts), sample_shape)
-
-
-def _use_pallas(data, use_pallas, row_elems=None):
-    """Resolve the gather backend.  Priority: explicit ``use_pallas``
-    arg > ``root.common.engine.pallas_gather`` (True/False force; a
-    config force also honors ``engine.interpret`` so CPU tests can pin
-    the in-scan composition through the Pallas interpreter) > the
-    device DB's measured A/B (``autotune_gather``) on the TPU > XLA.
-
-    Whatever this resolves to RUNS: a kernel the chip's compiler
-    refuses raises at the compile of the enclosing program instead of
-    being swapped for the XLA gather behind the caller's back."""
-    if data.ndim < 2:
-        return False
-    if use_pallas is not None:
-        return bool(use_pallas)
-    from veles_tpu.config import root   # deferred: import cycle
-    from veles_tpu.ops import on_tpu
-    forced = root.common.engine.get("pallas_gather", None)
-    if isinstance(forced, bool):
-        interp = bool(root.common.engine.get("interpret", False))
-        return forced and (on_tpu() or interp)
-    from veles_tpu.ops.benchmark import gather_choice
-    # the verdict only transfers to the ROW SIZE it was measured at:
-    # the kernel's win is not generic
-    measured = gather_choice(
-        str(jnp.dtype(data.dtype)),
-        row_elems=row_elems or int(numpy.prod(data.shape[1:])))
-    return bool(measured) and on_tpu()
-
-
-def _interpret():
-    from veles_tpu.config import root   # deferred: import cycle
-    return bool(root.common.engine.get("interpret", False))
 
 
 def _rows_of(data):
@@ -223,19 +187,16 @@ def _rows_of(data):
     return data, tuple(data.shape[1:])
 
 
-def take_rows(data, indices, use_pallas=None):
+def take_rows(data, indices):
     """``data[indices]`` along axis 0, ``[batch, *sample_shape]`` in the
     storage dtype, of a :class:`ResidentRows` or a plain array.
     Negative indices (the reference's "empty slot" marker for short
-    batches) produce zero rows.  Backend dispatch: :func:`_use_pallas`."""
+    batches) produce zero rows."""
     rows, shape = _rows_of(data)
-    if _use_pallas(rows, use_pallas, int(numpy.prod(shape))):
-        return _gather_pallas(rows, indices, interpret=_interpret(),
-                              sample_shape=shape)
     return _gather_jnp(rows, indices, sample_shape=shape)
 
 
-def take_rows_norm(data, indices, norm, use_pallas=None):
+def take_rows_norm(data, indices, norm):
     """Fused gather + affine normalize: float32
     ``data[indices]*scale + shift`` with negative indices producing
     ZERO rows (masking applies AFTER the normalize, so a short batch's
@@ -243,31 +204,16 @@ def take_rows_norm(data, indices, norm, use_pallas=None):
 
     This is the fullbatch loader's native-dtype head: the dataset stays
     resident in its storage dtype (e.g. uint8 pixels) and the first
-    forward program receives normalized float32 — the gather's DMA and
-    the normalizer's multiply-add are one kernel, so the raw bytes are
-    read exactly once.  ``norm`` is the loader's affine
-    ``(scale, shift)`` pair (``NormalizerBase.as_affine``): scalars or
-    flat per-feature arrays.  Dispatch mirrors :func:`take_rows` (the
-    gather A/B verdict transfers: the epilogue adds two VPU ops to a
-    DMA-bound kernel)."""
+    forward program receives normalized float32, the raw bytes read
+    once.  ``norm`` is the loader's affine ``(scale, shift)`` pair
+    (``NormalizerBase.as_affine``): scalars or flat per-feature
+    arrays."""
     scale, shift = norm
     rows, shape = _rows_of(data)
-    elems = int(numpy.prod(shape))
-    if _use_pallas(rows, use_pallas, elems):
-        return _gather_norm_pallas(
-            rows, indices, _norm_row(scale, elems),
-            _norm_row(shift, elems), interpret=_interpret(),
-            sample_shape=shape)
     return _gather_norm_jnp(rows, indices,
                             jnp.asarray(scale, jnp.float32),
                             jnp.asarray(shift, jnp.float32),
                             sample_shape=shape)
-
-
-def _norm_row(v, f):
-    """scale/shift as a (1, f) float32 row the kernel broadcasts."""
-    v = jnp.asarray(v, jnp.float32)
-    return jnp.broadcast_to(v.reshape(1, -1), (1, f))
 
 
 @functools.partial(jax.jit, static_argnames=("sample_shape",))
@@ -282,71 +228,6 @@ def _gather_norm_jnp(data, indices, scale, shift, sample_shape=None):
     return jnp.where(mask, normed, 0.0)
 
 
-def _gather_norm_kernel(idx_ref, data_ref, scale_ref, shift_ref, o_ref):
-    i = pl.program_id(0)
-    valid = idx_ref[i] >= 0
-
-    @pl.when(valid)
-    def _copy():
-        x = data_ref[:]
-        if jnp.issubdtype(x.dtype, jnp.integer):
-            # Mosaic has no direct uint8 -> float32 cast; widen first
-            x = x.astype(jnp.int32)
-        o_ref[:] = (x.astype(jnp.float32) * scale_ref[:][None]
-                    + shift_ref[:][None])
-
-    @pl.when(jnp.logical_not(valid))
-    def _zero():
-        o_ref[:] = jnp.zeros_like(o_ref)
-
-
-def _row_blocks(data):
-    """``data`` as ``[n, a, b]`` with one row a ``(1, a, b)`` block.  The
-    Mosaic lowering requires a block's last two dims to be divisible by
-    (8, 128) OR equal to the array's dims: a row of the resident form is
-    such a block as it is, and any other row rides flat as ``(n, 1, f)``
-    — a ``(1, f)`` block over ``(n, f)`` would fail the sublane rule for
-    any n > 1 — with no padding and no copy (the reshape is a view of
-    the same HBM bytes)."""
-    return data if data.ndim == 3 else data.reshape(data.shape[0], 1, -1)
-
-
-def _block_row(v, block):
-    """A ``(1, f)`` scale/shift row padded and split like a row block."""
-    padded = int(numpy.prod(block))
-    return jnp.pad(v, ((0, 0), (0, padded - v.shape[1]))).reshape(block)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "sample_shape"))
-def _gather_norm_pallas(data, indices, scale, shift, interpret=False,
-                        sample_shape=None):
-    # scale/shift, (1, f) rows, ride as whole-array (a, b) operands of
-    # one row block's shape that every grid point maps to
-    blocks = _row_blocks(data)
-    block = (1,) + blocks.shape[1:]
-    b = indices.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec(block, lambda i, idx_ref: (jnp.maximum(
-                idx_ref[i], 0), 0, 0)),
-            pl.BlockSpec(block[1:], lambda i, idx_ref: (0, 0)),
-            pl.BlockSpec(block[1:], lambda i, idx_ref: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec(block, lambda i, idx_ref: (i, 0, 0)),
-    )
-    out = pl.pallas_call(
-        _gather_norm_kernel,
-        name="veles_gather_norm",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b,) + block[1:], jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(indices, jnp.int32), blocks,
-      _block_row(scale, block[1:]), _block_row(shift, block[1:]))
-    return _samples(out.reshape((b,) + data.shape[1:]), sample_shape)
-
-
 @functools.partial(jax.jit, static_argnames=("sample_shape",))
 @jax.named_scope("veles.loader.take_rows")
 def _gather_jnp(data, indices, sample_shape=None):
@@ -357,42 +238,3 @@ def _gather_jnp(data, indices, sample_shape=None):
                      sample_shape)
     mask = (indices >= 0).reshape((-1,) + (1,) * (taken.ndim - 1))
     return jnp.where(mask, taken, 0)
-
-
-def _gather_kernel(idx_ref, data_ref, o_ref):
-    i = pl.program_id(0)
-    valid = idx_ref[i] >= 0
-
-    @pl.when(valid)
-    def _copy():
-        o_ref[:] = data_ref[:]
-
-    @pl.when(jnp.logical_not(valid))
-    def _zero():
-        o_ref[:] = jnp.zeros_like(o_ref)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "sample_shape"))
-def _gather_pallas(data, indices, interpret=False, sample_shape=None):
-    blocks = _row_blocks(data)
-    block = (1,) + blocks.shape[1:]
-    b = indices.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[
-            # the index map reads the prefetched indices: block row i of
-            # the output comes from dataset row indices[i]
-            pl.BlockSpec(block, lambda i, idx_ref: (jnp.maximum(
-                idx_ref[i], 0), 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(block, lambda i, idx_ref: (i, 0, 0)),
-    )
-    out = pl.pallas_call(
-        _gather_kernel,
-        name="veles_gather",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b,) + block[1:], data.dtype),
-        interpret=interpret,
-    )(jnp.asarray(indices, jnp.int32), blocks)
-    return _samples(out.reshape((b,) + data.shape[1:]), sample_shape)

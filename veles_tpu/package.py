@@ -209,7 +209,7 @@ def build_forward_fn(forwards):
             vec = getattr(unit, attr, None)
             if vec:
                 vec.map_read()
-                params[key] = jnp.asarray(vec.mem)
+                params[key] = jnp.array(vec.mem)   # a copy: memory.py's rule
         if not getattr(unit, "include_bias", True):
             params.pop("b", None)
         mapping = type(unit).MAPPING

@@ -67,6 +67,8 @@ from veles_tpu.obs import context as obs_context
 
 HEARTBEAT_INTERVAL = 2.0
 SLAVE_TIMEOUT = 10.0
+#: how long a master given its port waits for the port's last owner
+BIND_RETRY_SECONDS = 10.0
 #: how many applied-update seqs the dedup set remembers (a replay can
 #: only arrive within a few round-trips of the original; this is ~3
 #: orders of magnitude above that)
@@ -201,7 +203,20 @@ class JobServer(Logger):
         # (welcome or reject) can never be answered
         self._socket.setsockopt(zmq.ROUTER_HANDOVER, 1)
         if port:
-            self._socket.bind("tcp://%s:%d" % (host, port))
+            # a GIVEN port is a master coming back on the endpoint its
+            # slaves know (resume_from_checkpoint): the old owner, a
+            # dying process or a zmq socket closing in the background,
+            # may not have let go of it yet
+            deadline = time.time() + BIND_RETRY_SECONDS
+            while True:
+                try:
+                    self._socket.bind("tcp://%s:%d" % (host, port))
+                    break
+                except zmq.ZMQError as exc:
+                    if exc.errno != zmq.EADDRINUSE \
+                            or time.time() > deadline:
+                        raise
+                    time.sleep(0.1)
             self.port = port
         else:
             self.port = self._socket.bind_to_random_port("tcp://%s" % host)

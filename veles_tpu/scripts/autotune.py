@@ -42,7 +42,6 @@ def main(argv=None):
     parser.add_argument("--skip-attention", action="store_true")
     parser.add_argument("--skip-gd", action="store_true")
     parser.add_argument("--skip-s2d", action="store_true")
-    parser.add_argument("--skip-gather", action="store_true")
     args = parser.parse_args(argv)
 
     import jax
@@ -97,7 +96,7 @@ def main(argv=None):
         # ATTN_SHAPE_CLASSES (round-3's DB held a single shape)
         # quick measures a toy shape, so it must NOT overwrite the
         # production winners (the quick-pass-poisons-rating hazard,
-        # same guard as s2d/gather below): measure + print only
+        # same guard as s2d below): measure + print only
         shape = (2, 512, 4, 64) if args.quick else None
         info = benchmark.autotune_flash_attention(
             shape=shape, runs=1 if args.quick else 2, db_path=db_path,
@@ -147,28 +146,6 @@ def main(argv=None):
         print("s2d_conv%s: %s" % (
             " (quick, NOT saved)" if args.quick else "",
             json.dumps(info.ratings.get("s2d_conv", {}))),
-            file=sys.stderr)
-
-    if not args.skip_gather:
-        # resident-dataset minibatch gather A/B (XLA vs the Pallas
-        # DMA kernel): ~12 ms/step of the AlexNet e2e-vs-synthetic gap
-        # in r4's banked ladder is this gather.  Quick mode: measure +
-        # print only, never overwrite the production verdict.
-        dts = ("uint8",) if args.quick else ("uint8", "float32")
-        for dt in dts:   # u8 = the resident-native path; f32 = the
-            # classic loader path.  n only needs to defeat caching —
-            # gather cost scales with ROW bytes — and the dataset
-            # is uploaded once per sweep, so the f32 leg uses fewer
-            # rows (633 MB vs 2.5 GB)
-            n = 256 if args.quick else (4096 if dt == "uint8"
-                                        else 1024)
-            info = benchmark.autotune_gather(
-                n=n, row=(19, 19, 3) if args.quick else (227, 227, 3),
-                batch=32 if args.quick else 256, dtype_name=dt,
-                db_path=db_path, save=not args.quick)
-        print("gather%s: %s" % (
-            " (quick, NOT saved)" if args.quick else "",
-            json.dumps(info.ratings.get("gather", {}))),
             file=sys.stderr)
 
     if not args.skip_power:

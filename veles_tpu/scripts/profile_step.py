@@ -11,7 +11,7 @@ and prints a markdown table with per-phase seconds, derived phase
 costs, images/sec and MFU.  Run on the real chip:
 
     python -m veles_tpu.scripts.profile_step [--sample alexnet]
-        [--batch 256] [--out PROFILE.md]
+        [--batch 256] [--out <report.md>]
 
 (ref: the per-unit timer table ``workflow.py:767-826`` and the
 ``--sync-run`` kernel-accuracy note ``accelerated_units.py:294-297`` —
@@ -40,8 +40,8 @@ def build(sample, batch):
         # the GPT LM (bench stage config).  Keep --batch <= 32: the
         # chunked-CE live memory is O(batch * 128 * vocab) floats.
         # Honors the SAME BENCH_LM_REMAT / BENCH_LM_CE_CHUNK knobs as
-        # bench.py's transformer stage, so PROFILE_LM.md describes the
-        # exact program the banked LM line measured.
+        # bench.py's transformer stage, so the report describes the
+        # exact program that stage measures.
         import os
         from veles_tpu.samples import transformer as T
         cfg = {"vocab": 32000, "dim": 512, "heads": 8, "layers": 8,

@@ -507,10 +507,8 @@ def epoch_runner(step_fn, n_samples, batch, shuffle=True):
         idx = perm[: steps * batch].reshape(steps, batch)
 
         def body(p, batch_idx):
-            # take_rows: the minibatch gather rides the same
-            # measured XLA-vs-Pallas dispatch as the host-driven
-            # loader path (ops/gather.py; indices here are always
-            # valid so the two backends are value-identical)
+            # take_rows: the same gather as the host-driven loader
+            # path (ops/gather.py)
             from veles_tpu.ops.gather import take_rows
             return step_fn(p, take_rows(data, batch_idx),
                            take_rows(labels, batch_idx))

@@ -474,8 +474,9 @@ class FusedTrainer(AcceleratedUnit):
                 if old is None or not vec:
                     continue
                 vec.map_read()
-                host = numpy.ascontiguousarray(vec.mem).astype(
-                    old.dtype, copy=False)
+                # astype copies: the leaf must not alias the Vector's
+                # host array, which sync_weights and the next job rewrite
+                host = numpy.ascontiguousarray(vec.mem).astype(old.dtype)
                 # the leaf's own sharding: committed single-device
                 # placement and mesh NamedShardings both round-trip
                 state[key] = jax.device_put(host, old.sharding)
