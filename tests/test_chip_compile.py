@@ -139,6 +139,18 @@ def _resident(chip, rows, sample_shape, dtype):
                         sample_shape)
 
 
+def _grouped(k, n, relu2, chip):
+    """The hybrid cell's expert products: 1024 tokens x 22 pairs sorted
+    into 128 held experts' groups."""
+    from veles_tpu.ops.grouped import grouped_matmul
+    return jax.jit(lambda rows, weights, sizes: grouped_matmul(
+        rows, weights, sizes, relu2=relu2,
+        out_dtype=bf16 if relu2 else f32, use_pallas=True,
+        interpret=False)).lower(
+            chip((22528, k), bf16), chip((128, k, n), bf16),
+            chip((128,), i32))
+
+
 def _prng_fill(chip):
     from veles_tpu.ops.random import _uniform_pallas_tpu
     return _uniform_pallas_tpu.lower(chip((), i32), shape=(4096, 4096))
@@ -208,6 +220,10 @@ CASES = {
                                                 4096),
     "gd_fused_mnist_784x100": functools.partial(_gd_fused, 100, 784, 100),
     "prng_fill": _prng_fill,
+    "grouped_matmul_1024x2688_relu2": functools.partial(
+        _grouped, 1024, 2688, True),
+    "grouped_matmul_2688x1024": functools.partial(
+        _grouped, 2688, 1024, False),
     "lm_config_decode_step": functools.partial(_lm_decode, False),
     "lm_config_paged_decode_step": functools.partial(_lm_decode, True),
 }
@@ -315,7 +331,8 @@ def _hybrid_program(which, chip):
     with open(path) as handle:
         config = json.load(handle)
     pcfg = serve_hybrid.program_config(config)
-    model = HybridGenModel(pcfg, compute_dtype=bf16)
+    # the host here is a CPU: ask for the TPU's grouped product
+    model = HybridGenModel(pcfg, compute_dtype=bf16, use_pallas=True)
     params = chip.tree(hybrid_lm.param_shapes(pcfg, bf16))
     slots = config["engine"]["max_slots"]
     cache = chip.tree(jax.eval_shape(functools.partial(
@@ -331,14 +348,15 @@ def _hybrid_program(which, chip):
     return model, jax.jit(fn, donate_argnums=(1,)).lower(*args)
 
 
-@pytest.mark.parametrize("which", ["decode", 1024])
+@pytest.mark.parametrize("which", ["decode", 256, 1024])
 def test_hybrid_programs_compile_for_v5e_fit_and_write_in_place(
         which, chip, topo):
     """Mamba-2 + latent experts + grouped-query attention at published
     widths, one chip's share: the program fits the v5e's HBM beside its
     9.3 GB of weights, the whole 1.5 GB cache (recurrent state, the
     convolution's tails, K and V) is aliased in to out, and the long
-    prefill takes the grouped expert product."""
+    prefill alone takes the grouped expert product: the kernel, two
+    calls a layer, whose float32 hidden array is never written."""
     from veles_tpu.backends import device_hbm_bytes
     model, lowered = _hybrid_program(which, chip)
     compiled = lowered.compile()
@@ -352,4 +370,9 @@ def test_hybrid_programs_compile_for_v5e_fit_and_write_in_place(
         topo.devices[0].device_kind)
     assert mem.temp_size_in_bytes < 0.6e9
     text = compiled.as_text()
-    assert ("ragged-dot" in text) == (which != "decode")
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and "veles_grouped_matmul" in line]
+    assert len(kernels) == (2 * model.pattern.count("E")
+                            if which == 1024 else 0)
+    assert "ragged-dot" not in text
+    assert "f32[22528,2688]" not in text

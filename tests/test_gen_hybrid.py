@@ -23,6 +23,7 @@ from benchmarks import harness  # noqa: E402
 from benchmarks.drivers import serve_hybrid  # noqa: E402
 from benchmarks.reference import nemotron_h as reference  # noqa: E402
 from veles_tpu.gen import GenerativeEngine, HybridGenModel  # noqa: E402
+from veles_tpu.ops import grouped  # noqa: E402
 from veles_tpu.samples import hybrid_lm  # noqa: E402
 
 CONFIG_FILE = os.path.join(REPO_ROOT, "benchmarks", "configs",
@@ -44,6 +45,16 @@ def params(config):
 @pytest.fixture(scope="module")
 def model(config):
     return HybridGenModel(serve_hybrid.program_config(config))
+
+
+@pytest.fixture
+def interpret():
+    """``use_pallas=True`` on this CPU: the kernels in interpret mode."""
+    from veles_tpu.config import root
+    prior = root.common.engine.get("interpret", False)
+    root.common.engine.interpret = True
+    yield
+    root.common.engine.interpret = prior
 
 
 def _tokens(seed, n, vocab=64):
@@ -126,14 +137,17 @@ def test_prefill_then_decode_through_the_cache_is_the_whole_forward_pass(
         numpy.testing.assert_allclose(got, want[n + i], atol=2e-4)
 
 
-@pytest.mark.parametrize("dense_tokens", [0, 256])
+@pytest.mark.parametrize("dense_tokens,use_pallas", [
+    (0, False), (256, None), (0, True)])
 def test_both_forms_of_the_expert_product_are_the_references_stack(
-        config, params, dense_tokens):
+        config, params, dense_tokens, use_pallas, interpret):
     """The whole stack's logits at every position of one sequence: with
-    every prompt taking the grouped product (``dense_tokens`` 0), and
-    with the dense pass the rehearsal's buckets take by default."""
+    every prompt taking the grouped product (``dense_tokens`` 0) as
+    ``ragged_dot`` and as the TPU's kernel, and with the dense pass the
+    rehearsal's buckets take by default."""
     model = HybridGenModel(serve_hybrid.program_config(config),
-                           dense_tokens=dense_tokens)
+                           dense_tokens=dense_tokens,
+                           use_pallas=use_pallas)
     sequence = _tokens(21, 27)
     numpy.testing.assert_allclose(
         model.logits(params, sequence),
@@ -197,8 +211,14 @@ def test_a_slot_admitted_again_gives_the_logits_of_a_fresh_engine(
         numpy.testing.assert_array_equal(again, clean)
 
 
+@pytest.mark.parametrize("dense_tokens", [256, 8])
 def test_the_engine_serves_the_references_greedy_tokens_and_counts(
-        config, params, model):
+        config, params, dense_tokens, interpret):
+    """``dense_tokens`` 8: the 32 bucket is a prompt above it and takes
+    the grouped product (the kernel), a decode step of 4 slots does
+    not."""
+    model = HybridGenModel(serve_hybrid.program_config(config),
+                           dense_tokens=dense_tokens, use_pallas=True)
     engine = GenerativeEngine(model, params=params, max_slots=SLOTS,
                               max_seq=MAX_SEQ, prefill_buckets=(8, 32))
     try:
@@ -227,6 +247,19 @@ def test_the_engine_serves_the_references_greedy_tokens_and_counts(
             assert 0 < kind["moe_local_pairs"] <= kind["moe_pairs_total"]
             assert 0 < kind["moe_experts_touched"]
             assert 0 < kind["moe_expert_load_max"] <= 13
+        assert counted["decode"]["moe_grouped_rows"] == \
+            counted["decode"]["moe_grouped_blocks"] == 0
+        through = counted["prefill"]["moe_grouped_rows"]
+        blocks = counted["prefill"]["moe_grouped_blocks"]
+        if dense_tokens == 8:       # both prompts took the 32 bucket
+            assert 0 < through == counted["prefill"]["moe_local_pairs"]
+            # a block holds a pair, and at most a block's rows
+            assert through <= blocks * grouped.BLOCK_ROWS
+            assert 0 < blocks <= min(through, 2 * layers * (
+                -(-32 * model.top_k // grouped.BLOCK_ROWS)
+                + model.held - 1))
+        else:
+            assert through == blocks == 0
         info = engine.describe()
         assert info["state_bytes_per_slot"] == \
             model.recurrent_nbytes(SLOTS) // SLOTS > 0
@@ -310,9 +343,10 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(config):
 
 
 # (e) ----------------------------------------------------------------------
-@pytest.mark.parametrize("dense_tokens", [0, 64])
+@pytest.mark.parametrize("dense_tokens,use_pallas", [
+    (0, False), (64, None), (0, True)])
 def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
-        config, dense_tokens):
+        config, dense_tokens, use_pallas, interpret):
     one = dict(config, hybrid_override_pattern="E", num_hidden_layers=1,
                init_gain=8.0)
     dims = reference.dims(one)
@@ -325,16 +359,24 @@ def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
         (T, dims["d"])).astype(numpy.float32)
     u = reference._rmsnorm(jnp.asarray(x), layer["norm"], dims["eps"])
     model = HybridGenModel(serve_hybrid.program_config(one),
-                           dense_tokens=dense_tokens)
+                           dense_tokens=dense_tokens,
+                           use_pallas=use_pallas)
     valid = jnp.arange(T) < 37
     out, counts = model._moe(layer, jnp.asarray(x), valid)
     numpy.testing.assert_allclose(
         out - x, reference.moe_layer(layer, u, dims), atol=2e-5)
-    local, total, touched, largest = (int(c) for c in
-                                      (counts[0], counts[2], counts[1],
-                                       counts[3]))
-    assert largest == 37 and total == 37 * model.top_k
-    assert 37 <= local <= total and 1 <= touched <= model.held
+    counts = dict(zip(model.counters, (int(c) for c in counts)))
+    assert counts["moe_expert_load_max"] == 37
+    assert counts["moe_pairs_total"] == 37 * model.top_k
+    assert 37 <= counts["moe_local_pairs"] <= counts["moe_pairs_total"]
+    assert 1 <= counts["moe_experts_touched"] <= model.held
+    if dense_tokens:
+        assert counts["moe_grouped_rows"] == \
+            counts["moe_grouped_blocks"] == 0
+    else:
+        assert counts["moe_grouped_rows"] == counts["moe_local_pairs"]
+        assert counts["moe_experts_touched"] <= \
+            counts["moe_grouped_blocks"] <= counts["moe_local_pairs"]
 
 
 # (f) ----------------------------------------------------------------------

@@ -273,7 +273,7 @@ def _pallas_names(fn, *args):
 
 
 def _kernel_cases():
-    from veles_tpu.ops import attention, gemm, qgemm
+    from veles_tpu.ops import attention, gemm, grouped, qgemm
     from veles_tpu.ops import random as ops_random
     f32 = jnp.float32
     q = jnp.zeros((1, 16, 2, 8), f32)           # (b, s, h, d)
@@ -317,6 +317,9 @@ def _kernel_cases():
         "veles_gd_update_b": (gd_fused, gd),
         "veles_uniform": (lambda s: ops_random._uniform_pallas_tpu(
             s, (8, 128)), (jnp.int32(1),)),
+        "veles_grouped_matmul": (lambda a, w, sizes: grouped.grouped_matmul(
+            a, w[None], sizes, tm=8, use_pallas=True, interpret=True),
+            (a, w, jnp.full(1, 8, jnp.int32))),
     }
 
 
@@ -324,7 +327,7 @@ def _kernel_cases():
     "veles_flash_fwd", "veles_flash_bwd_dq", "veles_flash_bwd_dkv",
     "veles_attn_decode", "veles_attn_paged_decode", "veles_matmul",
     "veles_qmatmul", "veles_gd_err_input", "veles_gd_update_w",
-    "veles_gd_update_b", "veles_uniform"])
+    "veles_gd_update_b", "veles_uniform", "veles_grouped_matmul"])
 def test_every_pallas_call_carries_its_kernels_name(name):
     """The name a device trace shows for a Pallas kernel is the one
     its ``pallas_call`` was given: one name a kernel."""
@@ -351,7 +354,7 @@ def test_no_pallas_call_site_without_a_name():
             head = text[match.end():match.end() + 400]
             assert re.search(r'\bname="veles_[a-z_]+"', head), \
                 (fname, text[:match.start()].count("\n") + 1)
-    assert sites == 14
+    assert sites == 15
 
 
 def _scope_names(lowered):

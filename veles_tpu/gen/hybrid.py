@@ -41,13 +41,16 @@ prompt of up to 256) every held expert runs over every token with a
 zero weight where it was not chosen: at that size the pass is bound by
 reading the experts' weights, which it reads once either way.  Longer
 prompts sort their token-expert pairs by expert and take the grouped
-product (:func:`jax.lax.ragged_dot`, whose TPU lowering spends a
-512-row tile on every group however few rows it has: cheaper than the
-dense pass only from about a thousand tokens on).
+product (:func:`veles_tpu.ops.grouped.grouped_matmul`: on a TPU the
+kernel ``veles_grouped_matmul`` over row blocks of one expert each,
+which reads each touched expert's matrices once and no row past the
+last pair held here; elsewhere :func:`jax.lax.ragged_dot`).
 
 Both programs return, behind the tokens, the counters ``COUNTERS``
-summed over the expert layers (a few int32 in the array the engine
-fetches anyway).
+over the expert layers (a few int32 in the array the engine fetches
+anyway): sums, and the largest of a name that ends in ``_max``.
+``moe_grouped_rows`` / (``moe_grouped_blocks`` x
+``grouped.BLOCK_ROWS``) is the fill of the grouped product's blocks.
 """
 
 import math
@@ -56,13 +59,14 @@ import jax
 import jax.numpy as jnp
 import numpy
 
+from veles_tpu.ops import grouped
 from veles_tpu.samples import hybrid_lm
 
 F32 = jnp.float32
 
 #: what both programs count over their ``E`` layers, behind the tokens
 COUNTERS = ("moe_local_pairs", "moe_experts_touched", "moe_pairs_total",
-            "moe_expert_load_max")
+            "moe_expert_load_max", "moe_grouped_rows", "moe_grouped_blocks")
 
 
 def _rmsnorm(x, g, eps, out):
@@ -129,7 +133,8 @@ class HybridGenModel(object):
     recurrent_state = True
     counters = COUNTERS
 
-    def __init__(self, cfg, compute_dtype=None, dense_tokens=256):
+    def __init__(self, cfg, compute_dtype=None, dense_tokens=256,
+                 use_pallas=None):
         self.cfg = dict(cfg)
         self.vocab = int(cfg["vocab"])
         self.dim = int(cfg["dim"])
@@ -165,6 +170,8 @@ class HybridGenModel(object):
         self.compute_dtype = compute_dtype or jnp.float32
         #: up to this many tokens the held experts run dense
         self.dense_tokens = int(dense_tokens)
+        #: the grouped product's kernel: None lets the platform decide
+        self.use_pallas = use_pallas
 
     # -- params / cache ----------------------------------------------------
     def init_params(self, seed=0):
@@ -419,28 +426,40 @@ class HybridGenModel(object):
         return jnp.einsum("etf,efl->tl", hidden, p["w2"].astype(cd),
                           preferred_element_type=F32)
 
-    def _experts_grouped(self, p, latent, local, g):
+    def _experts_grouped(self, p, latent, local, g, valid):
         """The token-expert pairs sorted by expert, one grouped product
         a projection; a pair whose expert lives elsewhere sorts last
-        and belongs to no group."""
+        and belongs to no group.  Returns the mixture and how many of
+        the product's blocks held a pair of a ``valid`` token."""
         cd = self.compute_dtype
         T = latent.shape[0]
         keys = local.reshape(-1)
-        order = jnp.argsort(keys, stable=True)
-        sizes = jnp.bincount(keys, length=self.held + 1)[:self.held] \
-            .astype(jnp.int32)
+        # ONE sort carries everything that has to follow the pairs: a
+        # gather of 22,528 scalars costs the chip more than the sort
+        ranked, order, marked = jax.lax.sort(
+            (keys, jnp.arange(keys.shape[0], dtype=jnp.int32),
+             jnp.repeat(valid, self.top_k)), num_keys=1)
+        # how many pairs sort before each held expert's, and before the
+        # pairs held elsewhere
+        starts = (ranked[None, :] < jnp.arange(self.held + 1)[:, None]) \
+            .sum(1).astype(jnp.int32)
+        sizes = starts[1:] - starts[:-1]
+        blocks = grouped.block_map(sizes, keys.shape[0])
         rows = latent[order // self.top_k]
-        hidden = _relu2(jax.lax.ragged_dot(
-            rows, p["w1"].astype(cd), sizes,
-            preferred_element_type=F32)).astype(cd)
-        out = jax.lax.ragged_dot(hidden, p["w2"].astype(cd), sizes,
-                                 preferred_element_type=F32)
-        weight = jnp.where(keys < self.held, g.reshape(-1), 0.0)[order]
+        hidden = grouped.grouped_matmul(
+            rows, p["w1"].astype(cd), sizes, relu2=True, out_dtype=cd,
+            blocks=blocks, use_pallas=self.use_pallas)
+        out = grouped.grouped_matmul(
+            hidden, p["w2"].astype(cd), sizes, blocks=blocks,
+            use_pallas=self.use_pallas)
+        # back in the tokens' order (the inverse of a permutation is
+        # its argsort), where the weights are
+        back = jnp.argsort(order)
+        out = out[back].reshape(T, self.top_k, -1)
+        weight = jnp.where(local < self.held, g, 0.0)[..., None]
         # rows past the last group are whatever the product left there
-        out = jnp.where(weight[:, None] != 0, out * weight[:, None], 0.0)
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0]))
-        return out[back].reshape(T, self.top_k, -1).sum(1)
+        return jnp.where(weight != 0, out * weight, 0.0).sum(1), \
+            grouped.blocks_holding(blocks, marked)
 
     def _moe(self, p, x, valid):
         """``x [T, d]`` -> ``(x', counters)``; ``valid [T]`` says which
@@ -453,16 +472,19 @@ class HybridGenModel(object):
             load = jnp.bincount(
                 jnp.where(pairs, local, self.held).reshape(-1),
                 length=self.held + 1)[:self.held]
-            counts = jnp.stack([
-                pairs.sum(), (load > 0).sum(),
-                valid.sum() * self.top_k, load.max()]).astype(jnp.int32)
+            counts = [pairs.sum(), (load > 0).sum(),
+                      valid.sum() * self.top_k, load.max()]
         with jax.named_scope("veles.hybrid.moe.latent"):
             latent = self._dot(u, p["w_down"]).astype(cd)
         with jax.named_scope("veles.hybrid.moe.experts"):
             if x.shape[0] <= self.dense_tokens:
                 mixed = self._experts_dense(p, latent, local, g)
+                counts += [0, 0]
             else:
-                mixed = self._experts_grouped(p, latent, local, g)
+                mixed, blocks = self._experts_grouped(p, latent, local, g,
+                                                      valid)
+                counts += [counts[0], blocks]
+            counts = jnp.stack(counts).astype(jnp.int32)
         with jax.named_scope("veles.hybrid.moe.latent"):
             x = x + self._dot(mixed, p["w_up"]).astype(x.dtype)
         with jax.named_scope("veles.hybrid.moe.shared"):
@@ -472,9 +494,11 @@ class HybridGenModel(object):
 
     @staticmethod
     def _merge(total, counts):
-        """Sums, and the largest single load."""
-        return jnp.concatenate([total[:3] + counts[:3],
-                                jnp.maximum(total[3:], counts[3:])])
+        """By name, as ``GenerativeEngine._count`` does: the largest of
+        a name that ends in ``_max``, else the sum."""
+        largest = numpy.array([name.endswith("_max") for name in COUNTERS])
+        return jnp.where(largest, jnp.maximum(total, counts),
+                         total + counts)
 
     def head_logits(self, params, x):
         """``x [rows, d]`` -> float32 logits over the head's rows of

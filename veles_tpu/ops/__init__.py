@@ -14,6 +14,8 @@ fullbatch_loader        :mod:`veles_tpu.ops.gather` (XLA's gather
 mean_disp_normalizer    :mod:`veles_tpu.ops.normalize`
 join.jcl (Jinja2)       :mod:`veles_tpu.ops.join`
 benchmark               :mod:`veles_tpu.ops.benchmark`
+(none: expert layers)   :mod:`veles_tpu.ops.grouped` (rows sorted
+                        by group x one matrix a group)
 =====================  ==========================================
 
 An op with a Pallas TPU kernel for the hot path also has a pure jnp
