@@ -331,7 +331,7 @@ def _hybrid_program(which, chip):
     with open(path) as handle:
         config = json.load(handle)
     pcfg = serve_hybrid.program_config(config)
-    # the host here is a CPU: ask for the TPU's grouped product
+    # the host here is a CPU: ask for the TPU's kernels
     model = HybridGenModel(pcfg, compute_dtype=bf16, use_pallas=True)
     params = chip.tree(hybrid_lm.param_shapes(pcfg, bf16))
     slots = config["engine"]["max_slots"]
@@ -376,3 +376,75 @@ def test_hybrid_programs_compile_for_v5e_fit_and_write_in_place(
                             if which == 1024 else 0)
     assert "ragged-dot" not in text
     assert "f32[22528,2688]" not in text
+
+
+def _window_moe_program(which, chip):
+    """The benchmark's window / full configuration as the
+    GenerativeEngine compiles it: 24 slots, 32,768 positions, bf16, a
+    chunk of 1024, the cache tree donated."""
+    import json
+    import os
+
+    from benchmarks.drivers import serve_window_moe
+    from veles_tpu.gen import WindowMoEGenModel
+    from veles_tpu.samples import window_moe_lm
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "command_a_plus_05_2026.json")
+    with open(path) as handle:
+        config = json.load(handle)
+    pcfg = serve_window_moe.program_config(config)
+    engine = config["engine"]
+    # the host here is a CPU: ask for the TPU's kernels
+    model = WindowMoEGenModel(pcfg, compute_dtype=bf16, use_pallas=True)
+    params = chip.tree(window_moe_lm.param_shapes(pcfg, bf16))
+    slots = engine["max_slots"]
+    cache = chip.tree(jax.eval_shape(functools.partial(
+        model.init_cache, slots, engine["max_seq"])))
+    if which == "decode":
+        args = (params, cache, chip((slots,), i32), chip((slots,), i32),
+                chip((slots,), jnp.bool_))
+        fn = model.decode
+    else:
+        args = (params, cache, chip((1, engine["prefill_chunk"]), i32),
+                chip((), i32), chip((), i32), chip((), i32))
+        fn = model.prefill_chunk
+    return model, engine, jax.jit(fn, donate_argnums=(1,)).lower(*args)
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+def test_window_moe_programs_compile_for_v5e_fit_and_write_in_place(
+        which, chip, topo):
+    """Window and full attention layers, a parallel block and gated
+    experts at published widths, one chip's share: both programs fit
+    the v5e's HBM at 24 slots beside 9.5 GB of weights, the whole 4.4
+    GB cache tree (three rings of 4096 rows and one layer of 32,768 a
+    slot) is aliased in to out, and the chunk alone takes the grouped
+    expert product (the kernel, three calls a layer: gate, up, down) and
+    the chunk attention that reads the rings and the full layer in
+    place (the kernel, one call a layer)."""
+    from veles_tpu.backends import device_hbm_bytes
+    model, engine, lowered = _window_moe_program(which, chip)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    slots, max_seq = engine["max_slots"], engine["max_seq"]
+    assert (slots, max_seq, engine["prefill_chunk"]) == (24, 32768, 1024)
+    cache_bytes = model.cache_nbytes(slots, max_seq)
+    assert cache_bytes == slots * (3 * 4096 + 32768) * 2 * 1024 * 2
+    assert mem.alias_size_in_bytes == cache_bytes
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 13.8e9 < used < 15.6e9 < device_hbm_bytes(
+        topo.devices[0].device_kind)
+    assert mem.temp_size_in_bytes < (0.1e9 if which == "decode"
+                                     else 0.6e9)
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and "veles_grouped_matmul" in line]
+    assert len(kernels) == (3 * len(model.pattern)
+                            if which == "chunk" else 0)
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line
+               and "veles_attn_ring_chunk" in line]
+    assert len(kernels) == (len(model.pattern) if which == "chunk" else 0)
+    assert "ragged-dot" not in text

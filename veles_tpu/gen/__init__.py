@@ -14,6 +14,13 @@ KV cache with iteration-level scheduling.  Pieces:
   one stack (Mamba-2 mixers, a chip's share of a latent mixture of
   experts, grouped-query attention) and a cache of two kinds of state
   (recurrent state beside keys and values); contiguous mode only.
+- :mod:`window_moe` — :class:`~veles_tpu.gen.window_moe
+  .WindowMoEGenModel`: a third model behind the same protocol, sliding
+  window layers with rotary positions among full layers without, a
+  parallel block, a chip's share of gated experts; its cache is a tree
+  of rings and full-length rows, filled by chunks; contiguous mode only.
+- :mod:`experts` — the chip's share of a mixture of experts that both
+  of those classes run, for two forms of expert.
 - :mod:`engine` — :class:`~veles_tpu.gen.engine.GenerativeEngine`:
   AOT-compiled prefill buckets + ONE fixed-shape decode program,
   KV cache in the HBM ledger's ``kv`` category, tensor-parallel
@@ -61,11 +68,12 @@ from veles_tpu.gen.paged import BlockPool, PoolExhausted  # noqa: F401
 from veles_tpu.gen.prefix import PrefixCache  # noqa: F401
 from veles_tpu.gen.scheduler import (  # noqa: F401
     GenerativeScheduler, static_generate)
+from veles_tpu.gen.window_moe import WindowMoEGenModel  # noqa: F401
 
 __all__ = [
     "BlockPool", "DRAFT_MODELS", "DraftModelProposer",
     "GenerativeEngine", "GenerativeScheduler", "HybridGenModel",
     "NGramProposer",
     "PoolExhausted", "PrefixCache", "TransformerGenModel",
-    "register_draft_model", "static_generate",
+    "WindowMoEGenModel", "register_draft_model", "static_generate",
 ]

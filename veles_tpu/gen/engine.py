@@ -240,6 +240,23 @@ class GenerativeEngine(Logger):
                              ("speculative", self.speculative is not None)):
                 if on:
                     raise ValueError(self._no_recurrent(mode))
+        #: a model some of whose layers keep only their last
+        #: ``window`` positions (rings in the contiguous tree): nothing
+        #: that assumes each layer keeps each position may hold it
+        self.window = int(getattr(model, "window_rows", 0) or 0)
+        if self.window:
+            for mode, on in (("kv='paged'", self.kv_mode == "paged"),
+                             ("prefix_cache", self.prefix_cache),
+                             ("speculative", self.speculative is not None)):
+                if on:
+                    raise ValueError(self._no_window(mode))
+            rows = min(self.window, self.max_seq)
+            if self.prefill_chunk is None or rows % self.prefill_chunk:
+                raise ValueError(
+                    "%s takes its prompts by chunks only, and a chunk's "
+                    "rows are one run of a window layer's %d: "
+                    "prefill_chunk %r must divide it"
+                    % (type(model).__name__, rows, self.prefill_chunk))
         if self.prefix_cache and self.kv_mode != "paged":
             raise ValueError(
                 "prefix_cache requires kv='paged' — the contiguous "
@@ -426,6 +443,16 @@ class GenerativeEngine(Logger):
         self._counter_names = tuple(getattr(model, "counters", ()))
         self.counters = {kind: dict.fromkeys(self._counter_names, 0)
                          for kind in ("prefill", "decode")}
+        if self.window:
+            #: counted on the host a decode step, from the live slots'
+            #: lengths: the cache rows the step's queries see in the
+            #: window layers (a window at most, each) and in the others
+            self.counters["host"] = {"kv_rows_window": 0,
+                                     "kv_rows_full": 0}
+            kinds = [rows < self.max_seq
+                     for rows in model.layer_rows(self.max_seq)]
+            self._window_layers = sum(kinds)
+            self._full_layers = len(kinds) - sum(kinds)
         self._warmed = False
         self.prof_name = "gen%d" % next(_GEN_SEQ)
         self._prof_entries = {}
@@ -435,6 +462,13 @@ class GenerativeEngine(Logger):
                 "not a run of K/V pages that can be shared, split or "
                 "replayed (serve it with kv='contiguous')"
                 % (mode, type(self.model).__name__))
+
+    def _no_window(self, mode):
+        return ("%s cannot hold the window layers of %s: a layer that "
+                "keeps only its last %d positions has no page for every "
+                "position, to share, verify, replay or ship (serve it "
+                "with kv='contiguous' and prefill_chunk)"
+                % (mode, type(self.model).__name__, self.window))
 
     def _count(self, kind, out, n):
         """``out``: what a program of a counting model returned, on the
@@ -945,6 +979,8 @@ class GenerativeEngine(Logger):
         stream, so preemption is lossless."""
         if self.recurrent:
             raise ValueError(self._no_recurrent("preempt's replay"))
+        if self.window:
+            raise ValueError(self._no_window("preempt's replay"))
         if not self.slot_active[slot] and slot not in self._chunking:
             raise ValueError("slot %d is not occupied" % slot)
         self.release_slot(slot)
@@ -1121,6 +1157,16 @@ class GenerativeEngine(Logger):
                     self._params, self._cache,
                     jnp.asarray(tokens[None]), jnp.int32(slot),
                     jnp.int32(start), jnp.int32(chunk_len))
+            if self._counter_names:
+                # the counters ride behind every chunk's token, and the
+                # fetch is a wait for the chunk: a prompt's LAST chunk
+                # has to wait (its token feeds the next decode step), so
+                # every chunk does, and a live slot's token waits the
+                # same time behind any of them (left on the device until
+                # the last chunk, the others' steps came out 2 ms
+                # shorter: two levels in the tail of the gaps)
+                with trace.span("gen", "prefill_fetch"):
+                    tok = self._count("prefill", numpy.asarray(tok), 1)[0]
             prof.ledger.record_dispatch(
                 entry, time.perf_counter_ns() - tic, items=chunk_len)
         state["done"] = start + chunk_len
@@ -1202,6 +1248,12 @@ class GenerativeEngine(Logger):
                 entry, time.perf_counter_ns() - tic, items=n_active)
         self.slot_len[active] += 1
         self.slot_token[active] = out[active]
+        if self.window:
+            seen = self.slot_len[active].astype(numpy.int64)
+            host = self.counters["host"]
+            host["kv_rows_window"] += self._window_layers * int(
+                numpy.minimum(seen, self.window).sum())
+            host["kv_rows_full"] += self._full_layers * int(seen.sum())
         return out, active
 
     # -- speculative decode (draft K, verify in one dispatch) --------------
@@ -1340,6 +1392,8 @@ class GenerativeEngine(Logger):
         itself is NOT released (the caller decides)."""
         if self.recurrent:
             raise ValueError(self._no_recurrent("export_slot"))
+        if self.window:
+            raise ValueError(self._no_window("export_slot"))
         if self._pool is None:
             raise ValueError("page export requires kv='paged'")
         if not self.slot_active[slot]:
@@ -1376,6 +1430,8 @@ class GenerativeEngine(Logger):
         ``(slot, first_token)`` like :meth:`prefill`."""
         if self.recurrent:
             raise ValueError(self._no_recurrent("adopt_sequence"))
+        if self.window:
+            raise ValueError(self._no_window("adopt_sequence"))
         if self._pool is None:
             raise ValueError("page adoption requires kv='paged'")
         n = self._validate_prompt_len(int(payload["n"]))

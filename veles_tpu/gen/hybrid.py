@@ -31,26 +31,13 @@ Recurrent state cannot be paged, shared by prefix, chunked, verified
 k tokens at once or shipped as pages: the model declares
 ``recurrent_state`` and the engine refuses those modes by name.
 
-The expert layer is one chip's share of expert parallelism: it routes
-over ALL ``router_width`` experts (sigmoid scores, ``top_k``, weights
-normalised over all chosen) and computes the part of the result that
-the ``experts_held`` experts it holds give, for the tokens routed to
-them, dropping none and with no capacity buffers.  On one chip it runs
-without its exchange.  Up to ``dense_tokens`` tokens (a decode step, a
-prompt of up to 256) every held expert runs over every token with a
-zero weight where it was not chosen: at that size the pass is bound by
-reading the experts' weights, which it reads once either way.  Longer
-prompts sort their token-expert pairs by expert and take the grouped
-product (:func:`veles_tpu.ops.grouped.grouped_matmul`: on a TPU the
-kernel ``veles_grouped_matmul`` over row blocks of one expert each,
-which reads each touched expert's matrices once and no row past the
-last pair held here; elsewhere :func:`jax.lax.ragged_dot`).
-
-Both programs return, behind the tokens, the counters ``COUNTERS``
-over the expert layers (a few int32 in the array the engine fetches
-anyway): sums, and the largest of a name that ends in ``_max``.
-``moe_grouped_rows`` / (``moe_grouped_blocks`` x
-``grouped.BLOCK_ROWS``) is the fill of the grouped product's blocks.
+The expert layer is one chip's share of expert parallelism
+(:mod:`veles_tpu.gen.experts`, shared with
+:mod:`veles_tpu.gen.window_moe`): it routes over ALL ``router_width``
+experts and computes the part of the result that the ``experts_held``
+experts it holds give; an expert here is ungated, ``relu(l W1)^2 W2``
+in the latent.  Both programs return, behind the tokens, that module's
+``COUNTERS`` over the expert layers.
 """
 
 import math
@@ -59,24 +46,17 @@ import jax
 import jax.numpy as jnp
 import numpy
 
-from veles_tpu.ops import grouped
+from veles_tpu.gen import experts
+from veles_tpu.gen.experts import COUNTERS
 from veles_tpu.samples import hybrid_lm
 
 F32 = jnp.float32
-
-#: what both programs count over their ``E`` layers, behind the tokens
-COUNTERS = ("moe_local_pairs", "moe_experts_touched", "moe_pairs_total",
-            "moe_expert_load_max", "moe_grouped_rows", "moe_grouped_blocks")
 
 
 def _rmsnorm(x, g, eps, out):
     x = x.astype(F32)
     x = x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps)
     return (x * g.astype(F32)).astype(out)
-
-
-def _relu2(x):
-    return jnp.square(jax.nn.relu(x))
 
 
 def ssd_chunked(xs, dt, A, B, C, chunk):
@@ -396,109 +376,29 @@ class HybridGenModel(object):
             x = self._attn_out(p, x, att)
         return state, x
 
-    def _route(self, p, u):
-        """``(local [T, top_k] index among the held experts, or
-        ``held`` where the chosen expert lives elsewhere; g [T, top_k]
-        float32)``.  float32 and ``highest``: a near-tie must fall the
-        way the reference's falls."""
-        scores = jax.nn.sigmoid(jnp.dot(
-            u.astype(F32), p["router"],
-            precision=jax.lax.Precision.HIGHEST))
-        _best, chosen = jax.lax.top_k(scores + p["e_bias"], self.top_k)
-        picked = jnp.take_along_axis(scores, chosen, axis=1)
-        g = picked / (picked.sum(-1, keepdims=True) + 1e-20) \
-            * self.routed_scale
-        local = chosen - self.held_from
-        here = (local >= 0) & (local < self.held)
-        return jnp.where(here, local, self.held), g
-
-    def _experts_dense(self, p, latent, local, g):
-        """Every held expert over every token, weight 0 where it was
-        not chosen."""
-        cd = self.compute_dtype
-        rows = jnp.arange(latent.shape[0])[:, None]
-        weights = jnp.zeros((latent.shape[0], self.held + 1), F32) \
-            .at[rows, local].set(g)[:, :self.held]
-        hidden = _relu2(jnp.einsum("tl,elf->etf", latent,
-                                   p["w1"].astype(cd),
-                                   preferred_element_type=F32))
-        hidden = (hidden * weights.T[:, :, None]).astype(cd)
-        return jnp.einsum("etf,efl->tl", hidden, p["w2"].astype(cd),
-                          preferred_element_type=F32)
-
-    def _experts_grouped(self, p, latent, local, g, valid):
-        """The token-expert pairs sorted by expert, one grouped product
-        a projection; a pair whose expert lives elsewhere sorts last
-        and belongs to no group.  Returns the mixture and how many of
-        the product's blocks held a pair of a ``valid`` token."""
-        cd = self.compute_dtype
-        T = latent.shape[0]
-        keys = local.reshape(-1)
-        # ONE sort carries everything that has to follow the pairs: a
-        # gather of 22,528 scalars costs the chip more than the sort
-        ranked, order, marked = jax.lax.sort(
-            (keys, jnp.arange(keys.shape[0], dtype=jnp.int32),
-             jnp.repeat(valid, self.top_k)), num_keys=1)
-        # how many pairs sort before each held expert's, and before the
-        # pairs held elsewhere
-        starts = (ranked[None, :] < jnp.arange(self.held + 1)[:, None]) \
-            .sum(1).astype(jnp.int32)
-        sizes = starts[1:] - starts[:-1]
-        blocks = grouped.block_map(sizes, keys.shape[0])
-        rows = latent[order // self.top_k]
-        hidden = grouped.grouped_matmul(
-            rows, p["w1"].astype(cd), sizes, relu2=True, out_dtype=cd,
-            blocks=blocks, use_pallas=self.use_pallas)
-        out = grouped.grouped_matmul(
-            hidden, p["w2"].astype(cd), sizes, blocks=blocks,
-            use_pallas=self.use_pallas)
-        # back in the tokens' order (the inverse of a permutation is
-        # its argsort), where the weights are
-        back = jnp.argsort(order)
-        out = out[back].reshape(T, self.top_k, -1)
-        weight = jnp.where(local < self.held, g, 0.0)[..., None]
-        # rows past the last group are whatever the product left there
-        return jnp.where(weight != 0, out * weight, 0.0).sum(1), \
-            grouped.blocks_holding(blocks, marked)
-
     def _moe(self, p, x, valid):
         """``x [T, d]`` -> ``(x', counters)``; ``valid [T]`` says which
         rows are real tokens (the counters leave the others out)."""
         cd = self.compute_dtype
         with jax.named_scope("veles.hybrid.moe.router"):
             u = _rmsnorm(x, p["norm"], self.eps, cd)
-            local, g = self._route(p, u)
-            pairs = (local < self.held) & valid[:, None]
-            load = jnp.bincount(
-                jnp.where(pairs, local, self.held).reshape(-1),
-                length=self.held + 1)[:self.held]
-            counts = [pairs.sum(), (load > 0).sum(),
-                      valid.sum() * self.top_k, load.max()]
+            local, g = experts.route(
+                u, p["router"], self.top_k, self.held_from, self.held,
+                e_bias=p["e_bias"], scale=self.routed_scale)
+            counts = experts.load_counts(local, valid, self.held,
+                                         self.top_k)
         with jax.named_scope("veles.hybrid.moe.latent"):
             latent = self._dot(u, p["w_down"]).astype(cd)
         with jax.named_scope("veles.hybrid.moe.experts"):
-            if x.shape[0] <= self.dense_tokens:
-                mixed = self._experts_dense(p, latent, local, g)
-                counts += [0, 0]
-            else:
-                mixed, blocks = self._experts_grouped(p, latent, local, g,
-                                                      valid)
-                counts += [counts[0], blocks]
-            counts = jnp.stack(counts).astype(jnp.int32)
+            mixed, counts = experts.mix(
+                "relu2", p, latent, local, g, valid, counts, self.held,
+                self.top_k, self.dense_tokens, cd, self.use_pallas)
         with jax.named_scope("veles.hybrid.moe.latent"):
             x = x + self._dot(mixed, p["w_up"]).astype(x.dtype)
         with jax.named_scope("veles.hybrid.moe.shared"):
-            hidden = _relu2(self._dot(u, p["s1"])).astype(cd)
+            hidden = experts.relu2(self._dot(u, p["s1"])).astype(cd)
             x = x + self._dot(hidden, p["s2"]).astype(x.dtype)
         return x, counts
-
-    @staticmethod
-    def _merge(total, counts):
-        """By name, as ``GenerativeEngine._count`` does: the largest of
-        a name that ends in ``_max``, else the sum."""
-        largest = numpy.array([name.endswith("_max") for name in COUNTERS])
-        return jnp.where(largest, jnp.maximum(total, counts),
-                         total + counts)
 
     def head_logits(self, params, x):
         """``x [rows, d]`` -> float32 logits over the head's rows of
@@ -534,7 +434,7 @@ class HybridGenModel(object):
                 state, x = attn(p, state, x)
             else:
                 x, counts = self._moe(p, x, valid)
-                total = self._merge(total, counts)
+                total = experts.merge(total, counts)
             states.append(state)
         return {"layers": states}, x, total
 

@@ -776,6 +776,147 @@ def chunk_attention(q, k, v, start, use_pallas=None, interpret=None):
     return o
 
 
+def _ring_chunk_kernel(meta_ref, q_ref, t_ref, ko_ref, vo_ref, kc_ref,
+                       vc_ref, o_ref, acc_ref, m_ref, l_ref, *, n_own,
+                       n_k, block_q, block_k, group, rows, window, scale):
+    """Grid (kv_heads, q_blocks, own blocks then cache blocks); the key
+    blocks are the sequential dimension.  A q block is ``block_q //
+    group`` tokens, ``group`` query heads each, against ONE KV head.
+    ``meta_ref`` (scalar-prefetched): slot, start, live cache blocks.
+    The chunk's own keys come FIRST, so that every row has seen a key
+    (block 0 holds one at or before every query) before a block in
+    which it sees none: a masked score is then ``exp(-1e30 - m) = 0``
+    with no second select."""
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    start, live = meta_ref[1], meta_ref[2]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def visit(k_ref, v_ref, first):
+        """A block of keys at positions ``first ..`` (negative: rows
+        never written since the slot was admitted)."""
+        t = start + t_ref[...]                         # (bq, 1)
+        lo = jnp.maximum(t - (window - 1), 0) if window else 0
+        scores = jax.lax.dot_general(
+            q_ref[0], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (bq, bk)
+        p = first + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        scores = jnp.where((p <= t) & (p >= lo), scores, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1,
+                                            keepdims=True))
+        w = jnp.exp(scores - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + w.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            w.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    # own blocks wholly after the q block's last token are skipped: a
+    # function of the grid alone, the same in every chunk
+    @pl.when((j < n_own)
+             & (j * block_k <= ((qi + 1) * block_q - 1) // group))
+    def _own():
+        visit(ko_ref, vo_ref, start + j * block_k)
+
+    @pl.when((j >= n_own) & (j - n_own < live))
+    def _cache():
+        # rows before the write pointer hold the newest lap, rows from
+        # it on the lap before; a block lies on one side (the pointer
+        # is a multiple of the chunk, the chunk of the block)
+        row = (j - n_own) * block_k
+        pointer = jax.lax.rem(start, rows)
+        visit(kc_ref, vc_ref, start - pointer + row
+              - jnp.where(row >= pointer, rows, 0))
+
+    @pl.when(j == n_k - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def ring_chunk_attention(q, k, v, k_cache, v_cache, slot, start,
+                         window=None, floor_rows=0, block_q=2048,
+                         block_k=1024, interpret=False):
+    """Grouped-query attention of ONE prefill chunk against its slot's
+    cache rows AS THEY ARE and then its own keys, before they are
+    written: the kernel ``veles_attn_ring_chunk``.
+
+    ``q``: (C, g, r, d), the queries at positions ``start + i``
+    (``start`` a traced multiple of C), ``r`` query heads a KV head;
+    ``k``/``v``: (C, g * d), the chunk's own; ``k_cache``/``v_cache``:
+    (slots, rows, g * d) in which position ``p`` lives in row ``p mod
+    rows`` (a ring where ``rows`` is the window, every position where
+    it is the sequence limit), read IN PLACE a block at a time.  A
+    query sees ``p <= t``, and ``t - p < window`` where one is given.
+    Which position a row holds follows from ``start`` alone, so rows
+    that an earlier, longer request left are never seen.
+
+    The first ``max(start, floor_rows)`` rows are visited (all of a
+    ring with ``floor_rows = rows``), dead or live: up to that depth a
+    chunk costs the same wherever it lies in its prompt, which is what
+    lets a token that waits behind ONE chunk wait one length of time.
+    Returns (C, g, r, d) in ``q``'s type."""
+    C, G, R, d = q.shape
+    rows = k_cache.shape[1]
+    bk = min(block_k, C)
+    bq = min(block_q, C * R)
+    if C % bk or rows % C or (C * R) % bq or bq % R:
+        raise ValueError(
+            "a chunk of %d x %d query rows, %d cache rows: blocks of %d "
+            "queries and %d keys do not tile them" % (C, R, rows, bq, bk))
+    n_own, n_cache = C // bk, rows // bk
+    n_k = n_own + n_cache
+    start = jnp.asarray(start, jnp.int32)
+    live = jnp.minimum(jnp.maximum(start, floor_rows), rows) // bk
+    meta = jnp.stack([jnp.asarray(slot, jnp.int32), start, live])
+    q3 = jnp.moveaxis(q, 1, 0).reshape(G, C * R, d)
+    t_rel = (jnp.arange(C * R, dtype=jnp.int32) // R)[:, None]
+
+    def own(g, qi, j, meta):
+        needed = ((qi + 1) * bq - 1) // R // bk
+        return jnp.minimum(j, jnp.minimum(needed, n_own - 1)), g
+
+    def cached(g, qi, j, meta):
+        return (meta[0],
+                jnp.clip(j - n_own, 0, jnp.maximum(meta[2] - 1, 0)), g)
+
+    in_specs = [
+        pl.BlockSpec((1, bq, d), lambda g, qi, j, meta: (g, qi, 0)),
+        pl.BlockSpec((bq, 1), lambda g, qi, j, meta: (qi, 0)),
+        pl.BlockSpec((bk, d), own), pl.BlockSpec((bk, d), own),
+        pl.BlockSpec((None, bk, d), cached),
+        pl.BlockSpec((None, bk, d), cached),
+    ]
+    out = pl.pallas_call(
+        functools.partial(
+            _ring_chunk_kernel, n_own=n_own, n_k=n_k, block_q=bq,
+            block_k=bk, group=R, rows=rows, window=window,
+            scale=1.0 / (d ** 0.5)),
+        name="veles_attn_ring_chunk",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(G, C * R // bq, n_k),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, bq, d),
+                                   lambda g, qi, j, meta: (g, qi, 0)),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((G, C * R, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+    )(meta, q3, t_rel, k, v, k_cache, v_cache)
+    return jnp.moveaxis(out.reshape(G, C, R, d), 0, 1)
+
+
 def _verify_jnp(q, k, v, lengths):
     """Dense masked reference for the K-token VERIFY step
     (speculative decode): q (b, Kp1, h, d) — row ``j`` of sequence
