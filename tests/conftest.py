@@ -89,3 +89,27 @@ def aligned():
         start = -raw.ctypes.data % 64
         return raw[start:start + nbytes].view(dtype).reshape(shape)
     return make
+
+
+@pytest.fixture
+def mix_lists(monkeypatch):
+    """The kernel's form of ``ops.grouped.expert_mix`` in interpret mode
+    with a spy on it: the list this returns gains, for every RUN of it,
+    the length of the list of touched experts it walked (read it after
+    ``jax.effects_barrier()``)."""
+    import jax
+
+    from veles_tpu.config import root
+    from veles_tpu.ops import grouped
+    lengths = []
+    real = grouped._mix_pallas
+
+    def spy(x, weights, into, back, ids, count, interpret):
+        jax.debug.callback(lambda n: lengths.append(int(n)), count)
+        return real(x, weights, into, back, ids, count, interpret)
+
+    monkeypatch.setattr(grouped, "_mix_pallas", spy)
+    monkeypatch.setattr(root.common.engine, "interpret", True,
+                        raising=False)
+    return lengths
+

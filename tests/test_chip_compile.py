@@ -151,6 +151,18 @@ def _grouped(k, n, relu2, chip):
             chip((128,), i32))
 
 
+def _expert_mix(rows, k, f, held, matrices, chip):
+    """A decode step's experts at the two serving cells' sizes: all
+    rows through the touched experts, the result kept in fast memory."""
+    from veles_tpu.ops.grouped import expert_mix
+    return jax.jit(lambda x, weights, load, back, *into: expert_mix(
+        x, weights, list(into), back, load, use_pallas=True,
+        interpret=False)).lower(
+            chip((rows, k), bf16), chip((rows, held), f32),
+            chip((held,), i32), chip((held, f, k), bf16),
+            *[chip((held, k, f), bf16)] * matrices)
+
+
 def _prng_fill(chip):
     from veles_tpu.ops.random import _uniform_pallas_tpu
     return _uniform_pallas_tpu.lower(chip((), i32), shape=(4096, 4096))
@@ -224,6 +236,12 @@ CASES = {
         _grouped, 1024, 2688, True),
     "grouped_matmul_2688x1024": functools.partial(
         _grouped, 2688, 1024, False),
+    "expert_mix_relu2_64_rows_128_of_1024x2688": functools.partial(
+        _expert_mix, 64, 1024, 2688, 128, 1),
+    "expert_mix_relu2_a_256_bucket": functools.partial(
+        _expert_mix, 256, 1024, 2688, 128, 1),
+    "expert_mix_gated_24_rows_16_of_4096x4096": functools.partial(
+        _expert_mix, 24, 4096, 4096, 16, 2),
     "lm_config_decode_step": functools.partial(_lm_decode, False),
     "lm_config_paged_decode_step": functools.partial(_lm_decode, True),
 }
@@ -316,6 +334,22 @@ def test_alexnet_fused_step_compiles_for_v5e_and_fits(chip, topo):
                                                                   hbm)
 
 
+def _calls(text, kernel):
+    """The custom calls of a compiled program that run ``kernel``."""
+    return [line for line in text.splitlines()
+            if "custom-call(" in line and kernel in line]
+
+
+def _reading(text, shapes, kernel="veles_expert_mix"):
+    """The instructions of a compiled program, its parameters and the
+    calls of ``kernel`` apart, with an operand or a result of one of
+    ``shapes``: the held experts' whole arrays."""
+    return [line for line in text.splitlines()
+            if any(shape in line for shape in shapes)
+            and kernel not in line and " parameter(" not in line
+            and not line.startswith(("HloModule ", "ENTRY "))]
+
+
 def _hybrid_program(which, chip):
     """The benchmark's hybrid configuration as the GenerativeEngine
     compiles it: 64 slots, 2048 positions, bf16, the cache donated."""
@@ -354,9 +388,11 @@ def test_hybrid_programs_compile_for_v5e_fit_and_write_in_place(
     """Mamba-2 + latent experts + grouped-query attention at published
     widths, one chip's share: the program fits the v5e's HBM beside its
     9.3 GB of weights, the whole 1.5 GB cache (recurrent state, the
-    convolution's tails, K and V) is aliased in to out, and the long
-    prefill alone takes the grouped expert product: the kernel, two
-    calls a layer, whose float32 hidden array is never written."""
+    convolution's tails, K and V) is aliased in to out, the long
+    prefill alone takes the grouped expert product (the kernel, two
+    calls a layer, whose float32 hidden array is never written), and a
+    decode step and a short bucket take the touched experts' kernel,
+    one call a layer and the only reader of the held experts."""
     from veles_tpu.backends import device_hbm_bytes
     model, lowered = _hybrid_program(which, chip)
     compiled = lowered.compile()
@@ -370,12 +406,15 @@ def test_hybrid_programs_compile_for_v5e_fit_and_write_in_place(
         topo.devices[0].device_kind)
     assert mem.temp_size_in_bytes < 0.6e9
     text = compiled.as_text()
-    kernels = [line for line in text.splitlines()
-               if "custom-call(" in line and "veles_grouped_matmul" in line]
-    assert len(kernels) == (2 * model.pattern.count("E")
-                            if which == 1024 else 0)
+    assert len(_calls(text, "veles_grouped_matmul")) == (
+        2 * model.pattern.count("E") if which == 1024 else 0)
+    assert len(_calls(text, "veles_expert_mix")) == (
+        0 if which == 1024 else model.pattern.count("E"))
     assert "ragged-dot" not in text
     assert "f32[22528,2688]" not in text
+    if which == "decode":
+        assert not _reading(text, ("bf16[128,1024,2688]",
+                                   "bf16[128,2688,1024]"))
 
 
 def _window_moe_program(which, chip):
@@ -422,7 +461,9 @@ def test_window_moe_programs_compile_for_v5e_fit_and_write_in_place(
     slot) is aliased in to out, and the chunk alone takes the grouped
     expert product (the kernel, three calls a layer: gate, up, down) and
     the chunk attention that reads the rings and the full layer in
-    place (the kernel, one call a layer)."""
+    place (the kernel, one call a layer); a decode step takes the
+    touched experts' kernel, one call a layer and the only reader of
+    the held experts."""
     from veles_tpu.backends import device_hbm_bytes
     model, engine, lowered = _window_moe_program(which, chip)
     compiled = lowered.compile()
@@ -439,12 +480,12 @@ def test_window_moe_programs_compile_for_v5e_fit_and_write_in_place(
     assert mem.temp_size_in_bytes < (0.1e9 if which == "decode"
                                      else 0.6e9)
     text = compiled.as_text()
-    kernels = [line for line in text.splitlines()
-               if "custom-call(" in line and "veles_grouped_matmul" in line]
-    assert len(kernels) == (3 * len(model.pattern)
-                            if which == "chunk" else 0)
-    kernels = [line for line in text.splitlines()
-               if "custom-call(" in line
-               and "veles_attn_ring_chunk" in line]
-    assert len(kernels) == (len(model.pattern) if which == "chunk" else 0)
+    assert len(_calls(text, "veles_grouped_matmul")) == (
+        3 * len(model.pattern) if which == "chunk" else 0)
+    assert len(_calls(text, "veles_attn_ring_chunk")) == (
+        len(model.pattern) if which == "chunk" else 0)
+    assert len(_calls(text, "veles_expert_mix")) == (
+        0 if which == "chunk" else len(model.pattern))
     assert "ragged-dot" not in text
+    if which == "decode":
+        assert not _reading(text, ("bf16[16,4096,4096]",))

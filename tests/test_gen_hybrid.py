@@ -344,9 +344,11 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(config):
 
 # (e) ----------------------------------------------------------------------
 @pytest.mark.parametrize("dense_tokens,use_pallas", [
-    (0, False), (64, None), (0, True)])
+    (0, False), (64, None), (0, True), (64, True)])
 def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
         config, dense_tokens, use_pallas, interpret):
+    """Up to ``dense_tokens`` rows a row that is not valid chooses no
+    expert: it gets the shared expert's part alone."""
     one = dict(config, hybrid_override_pattern="E", num_hidden_layers=1,
                init_gain=8.0)
     dims = reference.dims(one)
@@ -363,8 +365,10 @@ def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
                            use_pallas=use_pallas)
     valid = jnp.arange(T) < 37
     out, counts = model._moe(layer, jnp.asarray(x), valid)
-    numpy.testing.assert_allclose(
-        out - x, reference.moe_layer(layer, u, dims), atol=2e-5)
+    want = reference.moe_layer(layer, u, dims)
+    if dense_tokens:
+        want = want.at[37:].set(reference.moe_shared(layer, u, dims)[37:])
+    numpy.testing.assert_allclose(out - x, want, atol=2e-5)
     counts = dict(zip(model.counters, (int(c) for c in counts)))
     assert counts["moe_expert_load_max"] == 37
     assert counts["moe_pairs_total"] == 37 * model.top_k
@@ -377,6 +381,46 @@ def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
         assert counts["moe_grouped_rows"] == counts["moe_local_pairs"]
         assert counts["moe_experts_touched"] <= \
             counts["moe_grouped_blocks"] <= counts["moe_local_pairs"]
+
+
+def test_a_served_batch_reads_the_touched_experts_and_says_the_dense_passes_tokens(
+        config, params, mix_lists):
+    """Three prompts in three slots, five decode steps of a pool three
+    quarters full: the kernel's form (every bucket and every decode step
+    here has few rows) serves the tokens of every held expert over every
+    row, and ``moe_experts_touched`` is the experts its lists held."""
+    held, served = mix_lists, {}
+    for pallas in (True, False):
+        model = HybridGenModel(serve_hybrid.program_config(config),
+                               use_pallas=pallas)
+        engine = GenerativeEngine(model, params=params, max_slots=SLOTS,
+                                  max_seq=MAX_SEQ, prefill_buckets=(8, 32))
+        try:
+            tokens = [engine.prefill(_tokens(40 + i, n))[1]
+                      for i, n in enumerate((5, 13, 8))]
+            jax.effects_barrier()
+            in_prefills = sum(held)
+            for _ in range(5):
+                out, active = engine.decode_step()
+                assert active.sum() == 3
+                tokens.extend(int(token) for token in out[active])
+            jax.effects_barrier()
+            counted = engine.counters
+        finally:
+            engine.close()
+        served[pallas] = tokens
+        if pallas:
+            layers = model.pattern.count("E")
+            assert len(held) == (3 + 5) * layers
+            assert 0 < counted["prefill"]["moe_experts_touched"] \
+                == in_prefills
+            assert 0 < counted["decode"]["moe_experts_touched"] \
+                == sum(held) - in_prefills
+            # a slot with no request chooses no expert
+            assert max(held) <= min(3 * model.top_k, model.held)
+            del held[:]
+    assert not held         # the dense form runs no kernel
+    assert served[True] == served[False]
 
 
 # (f) ----------------------------------------------------------------------

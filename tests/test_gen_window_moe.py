@@ -400,6 +400,42 @@ def test_the_engine_counts_the_experts_behind_every_chunk(model, engine):
         assert 0 < counted[kind]["moe_experts_touched"]
 
 
+def test_a_served_batch_reads_the_touched_experts_and_says_the_dense_passes_tokens(
+        config, params, model, mix_lists):
+    """Two prompts by chunks, five decode steps of a pool half full: the
+    kernel's form (a chunk and a decode step here have few rows) serves
+    the tokens of every held expert over every row, and
+    ``moe_experts_touched`` is the experts its lists held."""
+    held, served = mix_lists, {}
+    for pallas in (True, False):
+        engine = _engine(WindowMoEGenModel(model.cfg, use_pallas=pallas),
+                         params)
+        try:
+            tokens = [_admit(engine, _tokens(50 + i, n))[1]
+                      for i, n in enumerate((13, 6))]
+            jax.effects_barrier()
+            in_chunks = sum(held)
+            for _ in range(5):
+                out, active = engine.decode_step()
+                assert active.sum() == 2
+                tokens.extend(int(token) for token in out[active])
+            jax.effects_barrier()
+            counted = _counted(engine)
+        finally:
+            engine.close()
+        served[pallas] = tokens
+        if pallas:
+            layers = len(model.pattern)
+            assert len(held) == (4 + 2 + 5) * layers    # 13 = 4, 4, 4, 1
+            assert 0 < counted["prefill"]["moe_experts_touched"] \
+                == in_chunks
+            assert 0 < counted["decode"]["moe_experts_touched"] \
+                == sum(held) - in_chunks
+            del held[:]
+    assert not held         # the dense form runs no kernel
+    assert served[True] == served[False]
+
+
 # (e) ----------------------------------------------------------------------
 @pytest.mark.parametrize("mode,kwargs", [
     ("kv='paged'", {"kv": "paged", "block_size": 4}),

@@ -320,6 +320,11 @@ def _kernel_cases():
         "veles_grouped_matmul": (lambda a, w, sizes: grouped.grouped_matmul(
             a, w[None], sizes, tm=8, use_pallas=True, interpret=True),
             (a, w, jnp.full(1, 8, jnp.int32))),
+        # 8 rows through the one of two experts that some row chose
+        "veles_expert_mix": (lambda a, w, load: grouped.expert_mix(
+            a, jnp.ones((8, 2), f32), [jnp.stack([w.T, w.T])],
+            jnp.stack([w, w]), load, use_pallas=True, interpret=True),
+            (jnp.zeros((8, 8), f32), w, jnp.asarray([0, 8], jnp.int32))),
         # a chunk of 8 tokens, 2 KV heads of 2 query heads, a ring of 16
         "veles_attn_ring_chunk": (
             lambda q, k, c: attention.ring_chunk_attention(
@@ -335,7 +340,7 @@ def _kernel_cases():
     "veles_attn_decode", "veles_attn_paged_decode", "veles_matmul",
     "veles_qmatmul", "veles_gd_err_input", "veles_gd_update_w",
     "veles_gd_update_b", "veles_uniform", "veles_grouped_matmul",
-    "veles_attn_ring_chunk"])
+    "veles_expert_mix", "veles_attn_ring_chunk"])
 def test_every_pallas_call_carries_its_kernels_name(name):
     """The name a device trace shows for a Pallas kernel is the one
     its ``pallas_call`` was given: one name a kernel."""
@@ -362,7 +367,7 @@ def test_no_pallas_call_site_without_a_name():
             head = text[match.end():match.end() + 400]
             assert re.search(r'\bname="veles_[a-z_]+"', head), \
                 (fname, text[:match.start()].count("\n") + 1)
-    assert sites == 16
+    assert sites == 17
 
 
 def _scope_names(lowered):

@@ -120,3 +120,133 @@ def test_bf16_operands_accumulate_in_float32_and_cast_after_relu2():
         numpy.asarray(got.astype(jnp.float32)),
         numpy.asarray(jnp.asarray(want).astype(jnp.bfloat16)
                       .astype(jnp.float32)))
+
+
+# -- few rows through the experts they chose: ``expert_mix`` behind
+# ``gen.experts.mix``, the kernel in interpret mode ------------------------
+
+HELD, WIDTH, TOP_K = 8, 16, 4
+#: which held experts the valid rows may choose (an expert of 8..15 is
+#: held elsewhere); the rows that are not valid choose 7 and 2
+SHARES = {"none": [], "one": [5], "a_third": [1, 4, 6],
+          "all": [0, 1, 2, 3, 4, 5, 6, 7]}
+#: (rows, of them valid): a decode step of 1, 24 and 64 slots, a short
+#: bucket two thirds full
+ROWS = {"1_row": (1, 1), "24_rows_of_which_6_live": (24, 6),
+        "64_rows": (64, 64), "a_32_bucket_holding_21": (32, 21)}
+
+
+def _routing(seed, rows, live, may):
+    """``(local [rows, TOP_K], g, valid)``: every valid row chooses
+    ``TOP_K`` experts among ``may`` and those held elsewhere, each of
+    ``may`` by some row where the rows allow it."""
+    rng = numpy.random.default_rng(seed)
+    elsewhere = numpy.arange(HELD, WIDTH)
+    chosen = numpy.empty((rows, TOP_K), numpy.int64)
+    for row in range(rows):
+        if row >= live:
+            chosen[row] = [7, 2] + list(elsewhere[:TOP_K - 2])
+            continue
+        here = rng.permutation(may)[:rng.integers(1, TOP_K + 1)] \
+            if len(may) else numpy.zeros(0, numpy.int64)
+        if len(may):
+            here[0] = may[row % len(may)]
+            here = numpy.unique(here)
+        chosen[row] = numpy.concatenate(
+            [here, rng.permutation(elsewhere)[:TOP_K - len(here)]])
+    local = numpy.where(chosen < HELD, chosen, HELD).astype(numpy.int32)
+    g = rng.random((rows, TOP_K)).astype(numpy.float32)
+    return local, g / g.sum(1, keepdims=True), numpy.arange(rows) < live
+
+
+def _dense_arithmetic(form, p, x, local, g):
+    """What the dense pass computed: every held expert over every row,
+    weight 0 where the row did not choose it, in float32."""
+    rows = x.shape[0]
+    weights = numpy.zeros((rows, HELD + 1), numpy.float32)
+    weights[numpy.arange(rows)[:, None], local] = g
+    out = numpy.zeros_like(x)
+    for expert in range(HELD):
+        if form == "relu2":
+            hidden = numpy.square(numpy.maximum(x @ p["w1"][expert], 0))
+        else:
+            gate = x @ p["wg"][expert]
+            hidden = gate / (1 + numpy.exp(-gate)) * (x @ p["wu"][expert])
+        out += (hidden * weights[:, expert:expert + 1]) @ p[
+            "w2" if form == "relu2" else "wd"][expert]
+    return out
+
+
+@pytest.mark.parametrize("rows", sorted(ROWS))
+@pytest.mark.parametrize("share", sorted(SHARES))
+@pytest.mark.parametrize("form", ["relu2", "gated_silu"])
+def test_few_rows_go_through_the_experts_a_valid_row_chose_and_no_other(
+        form, share, rows, monkeypatch, mix_lists):
+    from veles_tpu.gen import experts
+    k, f = 128, 256
+    rows, live = ROWS[rows]
+    may = SHARES[share]
+    rng = numpy.random.default_rng(len(form) + 7 * rows + len(may))
+    local, g, valid = _routing(rows + len(may), rows, live, may)
+    into, back = experts.FORMS[form]
+    p = {name: (rng.standard_normal((HELD, k, f)) * 0.1)
+         .astype(numpy.float32) for name in into}
+    p[back] = (rng.standard_normal((HELD, f, k)) * 0.1) \
+        .astype(numpy.float32)
+    x = rng.standard_normal((rows, k)).astype(numpy.float32)
+    # the list the kernel walks is the experts the VALID rows chose: a
+    # row that is not valid reads none
+    want_list = sorted(set(local[:live].reshape(-1)) - {HELD})
+    if live < rows and share != "all":
+        assert 7 not in want_list and 2 not in want_list
+    loads = experts.load_counts(jnp.asarray(local), jnp.asarray(valid),
+                                HELD, TOP_K)
+    ids, count = (numpy.asarray(a) for a in grouped.touched_list(loads[1]))
+    assert list(ids[:count]) == want_list and not ids[count:].any()
+    assert int(loads[0][1]) == count == len(want_list)
+    # what the list leaves out is never multiplied: poison it
+    poisoned = {name: numpy.where(
+        numpy.isin(numpy.arange(HELD), want_list)[:, None, None], w,
+        numpy.nan) for name, w in p.items()}
+
+    def both(x, local, g, valid, p, poisoned):
+        return [experts.mix(form, weights, x, local, g, valid, loads, HELD,
+                            TOP_K, 256, jnp.float32, use_pallas=pallas)
+                for pallas, weights in ((True, poisoned), (False, p))]
+
+    # two tiles of the width, so that the sum runs over both grid axes
+    monkeypatch.setattr(grouped, "_width_tile", lambda *args: 128)
+    (kernel, counts), (dense, counts2) = jax.jit(both)(
+        x, local, g, valid, p, poisoned)
+    jax.effects_barrier()
+    assert mix_lists == [len(want_list)]    # the kernel's form ran, once
+    want = _dense_arithmetic(form, p, x, local, g)
+    # a row that is not valid chose no expert
+    want[live:] = 0
+    numpy.testing.assert_allclose(kernel, want, rtol=1e-5, atol=1e-5)
+    numpy.testing.assert_allclose(dense, want, rtol=1e-5, atol=1e-5)
+    assert list(numpy.asarray(counts)) == list(numpy.asarray(counts2)) \
+        == [int(((local < HELD) & valid[:, None]).sum()), len(want_list),
+            live * TOP_K, int(numpy.asarray(loads[1]).max()), 0, 0]
+
+
+def test_the_mix_takes_bf16_operands_and_sums_in_float32(monkeypatch):
+    monkeypatch.setattr(grouped, "_width_tile", lambda *args: 128)
+    rng = numpy.random.default_rng(11)
+    rows, k, f, held = 24, 128, 256, 4
+    x = jnp.asarray(rng.standard_normal((rows, k)), jnp.bfloat16)
+    into = [jnp.asarray(rng.standard_normal((held, k, f)) * 0.1,
+                        jnp.bfloat16) for _ in range(2)]
+    back = jnp.asarray(rng.standard_normal((held, f, k)) * 0.1,
+                       jnp.bfloat16)
+    chosen = rng.random((rows, held)) < 0.5
+    chosen[:, 2] = False
+    weights = jnp.asarray(numpy.where(chosen, rng.random((rows, held)), 0),
+                          jnp.float32)
+    load = jnp.asarray(chosen.sum(0), jnp.int32)
+    kernel, dense = (grouped.expert_mix(
+        x, weights, into, back, load, use_pallas=pallas, interpret=True)
+        for pallas in (True, False))
+    assert kernel.dtype == dense.dtype == jnp.float32
+    # the same bf16 hidden values either way; the sums' order differs
+    numpy.testing.assert_allclose(kernel, dense, rtol=2e-3, atol=2e-3)

@@ -5,15 +5,20 @@ The layer routes over ALL ``router_width`` experts (sigmoid scores,
 ``top_k``, weights normalised over all chosen) and computes the part of
 the result that the ``held`` experts it holds give, for the tokens
 routed to them, dropping none and with no capacity buffers.  On one
-chip it runs without its exchange.  Up to ``dense_tokens`` tokens (a
-decode step, a short prompt) every held expert runs over every token
-with a zero weight where it was not chosen: at that size the pass is
-bound by reading the experts' weights, which it reads once either way.
-More tokens sort their token-expert pairs by expert and take the
-grouped product (:func:`veles_tpu.ops.grouped.grouped_matmul`: on a TPU
-the kernel ``veles_grouped_matmul`` over row blocks of one expert each,
-which reads each touched expert's matrices once and no row past the
-last pair held here; elsewhere :func:`jax.lax.ragged_dot`).
+chip it runs without its exchange.  Up to ``dense_tokens`` rows (a
+decode step, a short prompt) ALL rows go through each held expert that
+a valid row chose, with a zero weight where a row did not choose it,
+and through no other (:func:`veles_tpu.ops.grouped.expert_mix`: on a
+TPU the kernel ``veles_expert_mix`` over the list of touched experts,
+which never fetches an untouched expert's matrices; elsewhere every
+held expert over every row).  At that size the pass is bound by reading
+the experts' weights, so it costs what the routing touched.  A row that
+is not valid (a slot with no request, a bucket's padding) chooses no
+expert there.  More rows sort their token-expert pairs by expert and
+take the grouped product (:func:`veles_tpu.ops.grouped.grouped_matmul`:
+on a TPU the kernel ``veles_grouped_matmul`` over row blocks of one
+expert each, which reads each touched expert's matrices once and no row
+past the last pair held here; elsewhere :func:`jax.lax.ragged_dot`).
 
 An expert has one of two FORMS, named by a string:
 
@@ -66,30 +71,30 @@ def route(u, router, top_k, held_from, held, e_bias=None, scale=1.0):
 
 
 def load_counts(local, valid, held, top_k):
-    """The first four of ``COUNTERS`` over the rows where ``valid
-    [T]``: pairs held here, held experts some token chose, all pairs,
-    the most pairs one held expert got."""
+    """``(the first four of COUNTERS, load [held])`` over the rows where
+    ``valid [T]``: pairs held here, held experts some token chose, all
+    pairs, the most pairs one held expert got; and the pairs each held
+    expert got."""
     pairs = (local < held) & valid[:, None]
     load = jnp.bincount(jnp.where(pairs, local, held).reshape(-1),
                         length=held + 1)[:held]
     return [pairs.sum(), (load > 0).sum(), valid.sum() * top_k,
-            load.max()]
+            load.max()], load
 
 
-def _dense(form, p, x, local, g, held, cd):
-    """Every held expert over every token, weight 0 where it was not
-    chosen."""
+def _few(form, p, x, local, g, valid, load, held, cd, use_pallas):
+    """ALL rows through each held expert that a valid row chose, weight
+    0 where a row did not choose it: the touched experts are those of
+    ``load``, which is what ``moe_experts_touched`` counts."""
     into, back = FORMS[form]
-    rows = jnp.arange(x.shape[0])[:, None]
-    weights = jnp.zeros((x.shape[0], held + 1), F32) \
-        .at[rows, local].set(g)[:, :held]
-    wide = [jnp.einsum("tl,elf->etf", x, p[name].astype(cd),
-                       preferred_element_type=F32) for name in into]
-    hidden = relu2(wide[0]) if form == "relu2" \
-        else jax.nn.silu(wide[0]) * wide[1]
-    hidden = (hidden * weights.T[:, :, None]).astype(cd)
-    return jnp.einsum("etf,efl->tl", hidden, p[back].astype(cd),
-                      preferred_element_type=F32)
+    # a row that is not valid chooses no expert; a row chooses an
+    # expert once, so the sum has one term
+    local = jnp.where(valid[:, None], local, held)
+    weights = jnp.where(local[:, :, None] == jnp.arange(held), g[:, :, None],
+                        0.0).sum(1)
+    return grouped.expert_mix(
+        x, weights, [p[name].astype(cd) for name in into],
+        p[back].astype(cd), load, use_pallas=use_pallas)
 
 
 def _grouped(form, p, x, local, g, valid, held, top_k, cd, use_pallas):
@@ -134,14 +139,17 @@ def _grouped(form, p, x, local, g, valid, held, top_k, cd, use_pallas):
         grouped.blocks_holding(blocks, marked)
 
 
-def mix(form, p, x, local, g, valid, counts, held, top_k, dense_tokens,
+def mix(form, p, x, local, g, valid, loads, held, top_k, dense_tokens,
         cd, use_pallas=None):
     """``x [T, k]`` through the held experts of ``form``, mixed by the
     routing of :func:`route`: ``(mixture [T, k] float32, COUNTERS
-    int32)``, ``counts`` being :func:`load_counts` of the same routing.
-    Dense up to ``dense_tokens`` rows, the grouped product above."""
+    int32)``, ``loads`` being :func:`load_counts` of the same routing.
+    The touched experts alone up to ``dense_tokens`` rows (the mixture
+    of a row that is not valid is then 0), the grouped product above."""
+    counts, load = loads
     if x.shape[0] <= dense_tokens:
-        mixed = _dense(form, p, x, local, g, held, cd)
+        mixed = _few(form, p, x, local, g, valid, load, held, cd,
+                     use_pallas)
         counts = counts + [0, 0]
     else:
         mixed, blocks = _grouped(form, p, x, local, g, valid, held, top_k,

@@ -148,7 +148,8 @@ class HybridGenModel(object):
         self.eps = float(cfg["norm_eps"])
         self.routed_scale = float(cfg["routed_scale"])
         self.compute_dtype = compute_dtype or jnp.float32
-        #: up to this many tokens the held experts run dense
+        #: up to this many rows ALL go through the held experts that a
+        #: valid row chose; above, the pairs are sorted by expert
         self.dense_tokens = int(dense_tokens)
         #: the grouped product's kernel: None lets the platform decide
         self.use_pallas = use_pallas
@@ -385,13 +386,13 @@ class HybridGenModel(object):
             local, g = experts.route(
                 u, p["router"], self.top_k, self.held_from, self.held,
                 e_bias=p["e_bias"], scale=self.routed_scale)
-            counts = experts.load_counts(local, valid, self.held,
-                                         self.top_k)
+            loads = experts.load_counts(local, valid, self.held,
+                                        self.top_k)
         with jax.named_scope("veles.hybrid.moe.latent"):
             latent = self._dot(u, p["w_down"]).astype(cd)
         with jax.named_scope("veles.hybrid.moe.experts"):
             mixed, counts = experts.mix(
-                "relu2", p, latent, local, g, valid, counts, self.held,
+                "relu2", p, latent, local, g, valid, loads, self.held,
                 self.top_k, self.dense_tokens, cd, self.use_pallas)
         with jax.named_scope("veles.hybrid.moe.latent"):
             x = x + self._dot(mixed, p["w_up"]).astype(x.dtype)
@@ -467,8 +468,8 @@ class HybridGenModel(object):
 
     def decode(self, params, cache, tokens, positions, active):
         """ONE decode step over every slot -> ``(cache', [slots tokens,
-        *COUNTERS])``.  Inactive slots ride along computing garbage;
-        none of their state moves."""
+        *COUNTERS])``.  Inactive slots ride along computing garbage
+        (they choose no expert); none of their state moves."""
         cache, x, total = self.decode_hidden(params, cache, tokens,
                                              positions, active)
         return cache, self._greedy(params, x, total)

@@ -132,7 +132,8 @@ class WindowMoEGenModel(object):
         self.eps = float(cfg["norm_eps"])
         self.logit_scale = float(cfg.get("logit_scale", 1.0))
         self.compute_dtype = compute_dtype or jnp.float32
-        #: up to this many tokens the held experts run dense
+        #: up to this many rows ALL go through the held experts that a
+        #: valid row chose; above, the pairs are sorted by expert
         self.dense_tokens = int(dense_tokens)
         #: the grouped product's and the chunk attention's kernels:
         #: None lets the platform decide
@@ -342,11 +343,11 @@ class WindowMoEGenModel(object):
             local, g = experts.route(u, p["router"], self.top_k,
                                      self.held_from, self.held)
             u = u.astype(cd)
-            counts = experts.load_counts(local, valid, self.held,
-                                         self.top_k)
+            loads = experts.load_counts(local, valid, self.held,
+                                        self.top_k)
         with jax.named_scope("veles.wmoe.moe.experts"):
             mixed, counts = experts.mix(
-                "gated_silu", p, u, local, g, valid, counts, self.held,
+                "gated_silu", p, u, local, g, valid, loads, self.held,
                 self.top_k, self.dense_tokens, cd, self.use_pallas)
         with jax.named_scope("veles.wmoe.moe.shared"):
             # the shared experts side by side: one wide gated product,
@@ -475,8 +476,8 @@ class WindowMoEGenModel(object):
 
     def decode(self, params, cache, tokens, positions, active):
         """ONE decode step over every slot -> ``(cache', [slots tokens,
-        *COUNTERS])``.  Inactive slots ride along computing garbage;
-        none of their rows moves."""
+        *COUNTERS])``.  Inactive slots ride along computing garbage
+        (they choose no expert); none of their rows moves."""
         cache, x, total = self.decode_hidden(params, cache, tokens,
                                              positions, active)
         return cache, self._greedy(params, x, total)

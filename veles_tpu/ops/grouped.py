@@ -1,4 +1,6 @@
-"""Grouped matrix product: rows sorted by group, one matrix a group.
+"""The products of an expert layer.  Grouped: rows sorted by group, one
+matrix a group.  Mixed (:func:`expert_mix`, at the end): few rows
+through the experts they chose and no other.
 
 ``grouped_matmul(rows [M, K], weights [E, K, N], sizes [E])`` multiplies
 the first ``sizes[0]`` rows by ``weights[0]``, the next ``sizes[1]`` by
@@ -165,3 +167,126 @@ def grouped_matmul(rows, weights, sizes, relu2=False, out_dtype=F32,
     out = _grouped_pallas(pad_axis(rows, tm, 0), weights, blocks, relu2,
                           out_dtype, tm, tn, interpret)
     return out[:m]
+
+
+# -- few rows: every row through every TOUCHED expert, mixed in place -------
+
+def touched_list(load):
+    """The experts with ``load [E] > 0`` first and in their order, then
+    zeros, and how many they are: ``(ids [E], count)``, both int32."""
+    touched = load > 0
+    place = jnp.cumsum(touched) - 1
+    slot = jnp.arange(load.shape[0], dtype=jnp.int32)
+    # one comparison a pair (the list is short) where a scatter would
+    # cost the chip more
+    ids = jnp.where(touched[None, :] & (place[None, :] == slot[:, None]),
+                    slot[None, :], 0).sum(1).astype(jnp.int32)
+    return ids, touched.sum().astype(jnp.int32)
+
+
+def _activate(wide):
+    """``relu(a)^2`` of one projection, ``silu(gate) * up`` of two, on
+    the float32 values."""
+    if len(wide) == 1:
+        return jnp.square(jnp.maximum(wide[0], 0.0))
+    return jax.nn.silu(wide[0]) * wide[1]
+
+
+def _mix_kernel(ids, x_ref, weights_ref, *refs):
+    into, back_ref, o_ref = refs[:-2], refs[-2], refs[-1]
+    step, tile = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((step == 0) & (tile == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    x = x_ref[...]
+    hidden = _activate([jnp.dot(x, ref[...], preferred_element_type=F32)
+                        for ref in into])
+    # this expert's column of the rows' weights: 0 where a row did not
+    # choose it
+    weights = weights_ref[...]
+    mine = jax.lax.broadcasted_iota(jnp.int32, weights.shape, 1) \
+        == ids[step]
+    weight = jnp.sum(jnp.where(mine, weights, 0.0), axis=1, keepdims=True)
+    o_ref[...] += jnp.dot((hidden * weight).astype(x.dtype), back_ref[...],
+                          preferred_element_type=F32)
+
+
+def _width_tile(f, k, rows, matrices, itemsize):
+    """The widest tile of an expert's width, a multiple of 128 that
+    divides ``f``, whose two buffers of every matrix's tile and the
+    rows' float32 hidden values fit half the kernel's fast memory (the
+    whole of ``f`` where it is no multiple of 128)."""
+    if f % 128:
+        return f
+    fits = [tf for tf in range(128, f + 1, 128)
+            if f % tf == 0 and tf * (2 * matrices * k * itemsize
+                                     + matrices * rows * 4)
+            <= _VMEM_LIMIT // 2]
+    return max(fits) if fits else 128
+
+
+def _mix_pallas(x, weights, into, back, ids, count, interpret):
+    rows, k = x.shape
+    f = back.shape[1]
+    tf = _width_tile(f, k, rows, len(into) + 1, back.dtype.itemsize)
+    whole = lambda e, j, _ids: (0, 0)  # noqa: E731
+    return pl.pallas_call(
+        _mix_kernel,
+        name="veles_expert_mix",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            # the touched experts alone, each by tiles of its width:
+            # the result stays in fast memory across the whole grid
+            grid=(count, f // tf),
+            in_specs=[pl.BlockSpec((rows, k), whole),
+                      pl.BlockSpec(weights.shape, whole)]
+            + [pl.BlockSpec((None, k, tf),
+                            lambda e, j, ids: (ids[e], 0, j))] * len(into)
+            + [pl.BlockSpec((None, tf, k),
+                            lambda e, j, ids: (ids[e], j, 0))],
+            out_specs=pl.BlockSpec((rows, k), whole)),
+        out_shape=jax.ShapeDtypeStruct((rows, k), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(ids, x, weights, *into, back)
+
+
+def expert_mix(x, weights, into, back, load, use_pallas=None,
+               interpret=None):
+    """``x [T, k]`` through the experts ``into`` (one ``[E, k, f]``
+    matrix: ``relu(x W1)^2``; two: ``silu(x Wg) * (x Wu)``) and ``back
+    [E, f, k]``, each row's results mixed by ``weights [T, E]``
+    (float32, 0 where the row did not choose the expert) -> ``[T, k]``
+    float32.  ``load [E]``: how many rows chose each expert; an expert
+    with none is not read.  Operands as they come (bf16 or float32), the
+    activation, the weight and the sums in float32.
+
+    On the TPU the kernel ``veles_expert_mix`` walks the list of
+    touched experts (:func:`touched_list`; list and length are scalar
+    arguments, the length the grid's bound), every step ALL ``T`` rows
+    through one tile of one expert's width and back, added into the one
+    result that stays in fast memory: no sort, no gather, no ``[E, T,
+    f]`` array.  Elsewhere (``use_pallas``: ``None`` lets the platform
+    decide) every expert runs over every row in two contractions."""
+    from veles_tpu.ops import on_tpu
+    pallas = use_pallas if use_pallas is not None else on_tpu()
+    if not pallas:
+        wide = [jnp.einsum("tl,elf->etf", x, w,
+                           preferred_element_type=F32) for w in into]
+        hidden = (_activate(wide) * weights.T[:, :, None]).astype(x.dtype)
+        return jnp.einsum("etf,efl->tl", hidden, back,
+                          preferred_element_type=F32)
+    if interpret is None:
+        from veles_tpu.config import root
+        interpret = bool(root.common.engine.get("interpret", False))
+    rows = x.shape[0]
+    ids, count = touched_list(load)
+    # whole sublane tiles of rows; a padded row has no weight
+    out = _mix_pallas(pad_axis(x, 16, 0), pad_axis(weights, 16, 0),
+                      tuple(into), back, ids, count, interpret)
+    # no expert touched: no grid step ran, nothing was written
+    return jnp.where(count > 0, out[:rows], 0.0)
