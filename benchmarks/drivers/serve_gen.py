@@ -15,7 +15,7 @@ import time
 
 import numpy
 
-from benchmarks import traffic
+from benchmarks import end_to_end, traffic
 
 
 def program_config(config):
@@ -243,6 +243,17 @@ class Run(object):
                 % (len(ordered), 1e3 * ordered[len(ordered) // 2],
                    1e3 * ordered[-2] if len(ordered) > 1 else 0.0,
                    1e3 * ordered[-1]))
+        if gaps:
+            slowest = sorted(gaps, reverse=True)[
+                :end_to_end.ceil_pct(len(gaps), 2)]
+            ctx.log("token gaps, n = %d: p95 %.3f ms, p97 %.3f ms; the "
+                    "slowest 2%%, k = %d, mean %.3f ms; %.2f%% above 40 "
+                    "ms, %.2f%% above 100 ms"
+                    % (len(gaps), end_to_end.gap_p95_ms(self.obs),
+                       end_to_end.gap_p97_ms(self.obs), len(slowest),
+                       1e3 * sum(slowest) / len(slowest),
+                       100.0 * sum(g > 0.040 for g in gaps) / len(gaps),
+                       100.0 * sum(g > 0.100 for g in gaps) / len(gaps)))
         self.due_in = due_in
         return self.obs
 

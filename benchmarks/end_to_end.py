@@ -5,14 +5,20 @@ A function returns None where the window gives it nothing to read.
 sweep; no cell reports it yet: PERF.md section 2 and Open questions.)"""
 
 
+def ceil_pct(n, pct):
+    """``pct`` percent of ``n``, rounded up in integer arithmetic, and
+    at least 1: the rank of a nearest-rank percentile, and the number of
+    values in a tail."""
+    return max(int(-(-pct * n // 100)), 1)
+
+
 def percentile(values, q):
     """The q-th percentile by the nearest-rank rule (no interpolation:
     a tail is a request that happened)."""
     if not values:
         return None
     ordered = sorted(values)
-    rank = max(int(-(-q * len(ordered) // 100)), 1)
-    return ordered[rank - 1]
+    return ordered[ceil_pct(len(ordered), q) - 1]
 
 
 def train_images_per_s(obs):
@@ -37,8 +43,29 @@ def gap_p95_ms(obs):
     return None if value is None else value * 1e3
 
 
+def gap_p97_ms(obs):
+    """The 97th percentile of all gaps between consecutive output tokens
+    whose later token fell in the window: in the expert cells, a decode
+    step that waited behind ONE prefill or chunk.
+
+    There the gaps are two populations, a plain decode step and a step
+    behind a prefill or a chunk (``command_a_plus_05_2026.mixed_len``:
+    medians 11-12 and 60-61 ms), and the stalled share of a window's
+    gaps was 4.4 to 7.3% over 32 windows on a TPU v5e: the 95th
+    percentile fell on the edge between them and read either one by the
+    window; the 97th lies inside the stalled population in each.  A
+    mean of the slowest 2% read that population's own top too: gaps
+    behind two or more prefills stacked in one scheduler step, 0.2 to
+    1.1% of all gaps, as many as the seed's order of arrivals makes meet,
+    so its sets of windows spread 10-79% where this percentile's
+    spread 0.6-3.5%."""
+    value = percentile(obs["gaps_s"], 97)
+    return None if value is None else value * 1e3
+
+
 METRICS = {
     "train_images_per_s": train_images_per_s,
     "out_tokens_per_s": out_tokens_per_s,
     "gap_p95_ms": gap_p95_ms,
+    "gap_p97_ms": gap_p97_ms,
 }

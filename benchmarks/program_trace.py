@@ -410,14 +410,36 @@ def spans_in_window(extracted, name=None, where=None):
     return out
 
 
+def nested_ns(spans, others):
+    """``[ns of each span that others cover]``: for each of ``spans``,
+    the union of those of ``others`` that lie inside it on its own
+    thread (the span itself left out).  ``others`` are sorted by start
+    once a thread, and a span looks only at those that start inside
+    it."""
+    import bisect
+    by_thread = {}
+    for other in others:
+        by_thread.setdefault(other[3], []).append(other)
+    index = {}
+    for thread, group in by_thread.items():
+        group.sort(key=lambda other: other[1])
+        index[thread] = ([other[1] for other in group], group)
+    out = []
+    for span in spans:
+        start, end = span[1], span[1] + span[2]
+        starts, group = index.get(span[3], ([], []))
+        first = bisect.bisect_left(starts, start)
+        last = bisect.bisect_right(starts, end)
+        inside = [(o[1], o[1] + o[2]) for o in group[first:last]
+                  if o is not span and o[1] + o[2] <= end]
+        out.append(sum(e - s for s, e in trace_reduce.merge(inside)))
+    return out
+
+
 def covered(span, others):
     """Nanoseconds of ``span`` that ``others`` cover: the union of
     those among them that lie inside it on its own thread."""
-    start, end = span[1], span[1] + span[2]
-    inside = [(o[1], o[1] + o[2]) for o in others
-              if o is not span and o[3] == span[3]
-              and o[1] >= start and o[1] + o[2] <= end]
-    return sum(e - s for s, e in trace_reduce.merge(inside))
+    return nested_ns([span], others)[0]
 
 
 def self_ns(span, spans):
@@ -431,22 +453,20 @@ def span_table(extracted):
     the window whole; a span's own time is its duration less what the
     spans nested in it on the same thread cover."""
     spans = spans_in_window(extracted)
-    by_thread = {}
-    for span in spans:
-        by_thread.setdefault(span[3], []).append(span)
     table = {}
-    for span in spans:
+    for span, nested in zip(spans, nested_ns(spans, spans)):
         count, total, own = table.get(span[0], (0, 0, 0))
         table[span[0]] = (count + 1, total + span[2],
-                          own + self_ns(span, by_thread[span[3]]))
+                          own + span[2] - nested)
     return table
 
 
 def idle_gaps(extracted, top=10):
     """The device's idle gaps in the window, each labelled by the
     innermost ``veles:`` span that covers at least half of it (the
-    shortest such span; failing that, the one that covers most):
-    ``[[label, seconds]]``, largest first."""
+    shortest such span; failing that, the one that covers most; the
+    rule is ``trace_reduce.label_gaps``): ``[[label, seconds]]``,
+    largest first."""
     window = window_of(extracted)
     if window is None:
         return []
@@ -455,20 +475,9 @@ def idle_gaps(extracted, top=10):
         (max(start, lo), min(start + duration, hi))
         for _p, _s, start, duration in extracted["ops"]
         if start + duration > lo and start < hi)
-    labelled, cursor = {}, lo
-    for start, end in busy + [[hi, hi]]:
-        if start > cursor:
-            best, best_key = "unattributed", None
-            for name, s_start, s_dur, _thread, _stats in \
-                    extracted["spans"]:
-                cover = min(start, s_start + s_dur) - max(cursor, s_start)
-                if cover <= 0:
-                    continue
-                key = (2 * cover >= start - cursor, -s_dur, cover)
-                if best_key is None or key > best_key:
-                    best, best_key = name[len(SPAN_PREFIX):], key
-            labelled[best] = labelled.get(best, 0) + (start - cursor)
-        cursor = max(cursor, end)
+    labelled = trace_reduce.label_gaps(
+        trace_reduce.idle_between(busy, lo, hi), extracted["spans"],
+        SPAN_PREFIX)
     return [[name, ns / 1e9] for name, ns in sorted(
         labelled.items(), key=lambda kv: -kv[1])[:top]]
 

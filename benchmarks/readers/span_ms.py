@@ -21,8 +21,7 @@ def read(view):
     total = sum(span[2] for span in spans)
     if args.get("minus"):
         inner = [s for s in extracted["spans"] if s[0] in args["minus"]]
-        total -= sum(program_trace.covered(span, inner)
-                     for span in spans)
+        total -= sum(program_trace.nested_ns(spans, inner))
     per = args.get("rest_of_window_per")
     if not per:
         return total / len(spans) / 1e6

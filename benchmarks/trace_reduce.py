@@ -122,6 +122,49 @@ def merge(intervals):
     return merged
 
 
+def idle_between(busy, lo, hi):
+    """``[(start, end)]`` of ``[lo, hi)`` that the sorted, disjoint
+    ``busy`` intervals leave uncovered."""
+    gaps, cursor = [], lo
+    for start, end in list(busy) + [[hi, hi]]:
+        if start > cursor:
+            gaps.append((cursor, start))
+        cursor = max(cursor, end)
+    return gaps
+
+
+def label_gaps(gaps, spans, prefix):
+    """``{label: ns}`` of the idle ``gaps`` (sorted and disjoint), each
+    put down to the shortest span that covers at least half of it (it
+    says most about the gap); failing that, to the span that covers
+    most of it; ``unattributed`` where no span reaches it.  A span is a
+    sequence whose first three items are name, start and duration; its
+    label is the name less ``prefix``.  Of spans that tie, the first in
+    ``spans`` wins.
+
+    One sweep: the spans in order of start, and beside the current gap
+    only those that overlap it, so the cost is the spans and the
+    overlaps, not spans x gaps."""
+    order = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    labelled, active, ahead = {}, [], 0
+    for start, end in gaps:
+        while ahead < len(order) and spans[order[ahead]][1] < end:
+            active.append(order[ahead])
+            ahead += 1
+        active = [i for i in active if spans[i][1] + spans[i][2] > start]
+        best, best_key = "unattributed", None
+        for i in active:
+            name, s_start, s_dur = spans[i][:3]
+            cover = min(end, s_start + s_dur) - max(start, s_start)
+            if cover <= 0:
+                continue
+            key = (2 * cover >= end - start, -s_dur, cover, -i)
+            if best_key is None or key > best_key:
+                best, best_key = name[len(prefix):], key
+        labelled[best] = labelled.get(best, 0) + (end - start)
+    return labelled
+
+
 def _clip(events, lo, hi):
     for name, start, duration in events:
         end = start + duration
@@ -140,8 +183,8 @@ def reduce(extracted, top=10):
     The window is the driver's ``bench:window`` span when the trace has
     one, else from the first to the last device event.  ``busy_s`` is
     the union of the device-operation intervals inside the window,
-    averaged over the chips; an idle gap is labelled by the driver span
-    that covers most of it."""
+    averaged over the chips; the first chip's idle gaps are labelled by
+    the driver's spans (:func:`label_gaps`)."""
     devices = extracted["devices"]
     if not devices:
         raise ValueError("the trace has no device plane with events")
@@ -160,7 +203,6 @@ def reduce(extracted, top=10):
     busy_ns = []
     per_op = {}
     programs = {}
-    gaps = []
     for index, name in enumerate(sorted(devices)):
         device = devices[name]
         ops = list(_clip(device["ops"] or device["modules"], lo, hi))
@@ -175,26 +217,10 @@ def reduce(extracted, top=10):
             programs.setdefault(program_name(event_name),
                                 []).append(duration)
         if index == 0:
-            cursor = lo
-            for start, end in merged + [[hi, hi]]:
-                if start > cursor:
-                    gaps.append((cursor, start))
-                cursor = max(cursor, end)
+            gaps = idle_between(merged, lo, hi)
 
-    labelled = {}
     others = [s for s in spans if s[0] != WINDOW_SPAN]
-    for start, end in gaps:
-        # the shortest span that covers at least half of the gap says
-        # most about it; failing that, the span that covers most of it
-        best, best_key = "unattributed", None
-        for name, s_start, s_dur in others:
-            cover = min(end, s_start + s_dur) - max(start, s_start)
-            if cover <= 0:
-                continue
-            key = (2 * cover >= end - start, -s_dur, cover)
-            if best_key is None or key > best_key:
-                best, best_key = name[len(SPAN_PREFIX):], key
-        labelled[best] = labelled.get(best, 0) + (end - start)
+    labelled = label_gaps(gaps, others, SPAN_PREFIX)
 
     by_span = {}
     for name, start, duration in others:

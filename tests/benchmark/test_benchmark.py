@@ -270,6 +270,54 @@ def test_percentile_is_a_request_that_happened():
     assert end_to_end.percentile([], 95) is None
 
 
+@pytest.mark.parametrize("n,rank", [
+    (1, 1), (49, 48), (50, 49), (51, 50), (100, 97)])
+def test_the_97th_percentile_of_the_gaps(n, rank):
+    """The rank is the ceiling of 97% of n, at least 1, by integer
+    arithmetic; the gap of that rank, whatever the order they came in."""
+    assert end_to_end.ceil_pct(n, 97) == rank
+    assert end_to_end.ceil_pct(n, 2) == (1 if n <= 50 else 2)
+    gaps = [i / 1000.0 for i in range(1, n + 1)]
+    gaps = gaps[1::2] + gaps[::2]
+    assert end_to_end.gap_p97_ms({"gaps_s": gaps}) == \
+        pytest.approx(float(rank), rel=1e-12)
+    assert end_to_end.gap_p97_ms({"gaps_s": []}) is None
+
+
+@pytest.mark.parametrize("slow_pct", [4.8, 5.2])
+def test_the_97th_percentile_stays_where_the_95th_jumps(slow_pct):
+    """Two populations, 11 ms and 60 ms, over 13,300 gaps: the 95th
+    percentile reads the plain step while the slow share is under 5%
+    and the stalled one above it, the 97th the stalled one in both."""
+    n = 13300
+    slow = int(round(n * slow_pct / 100))
+    gaps = [0.011] * (n - slow) + [0.060] * slow
+    obs = {"gaps_s": gaps}
+    assert end_to_end.gap_p95_ms(obs) == pytest.approx(
+        11.0 if slow_pct < 5 else 60.0)
+    assert end_to_end.gap_p97_ms(obs) == pytest.approx(60.0)
+    assert n - end_to_end.ceil_pct(n, 97) + 1 == 400 < slow
+
+
+def test_every_reported_metric_is_registered_and_listed_exactly(spec):
+    """Each cell's ``reports`` names a function of ``METRICS`` (or the
+    set-up time, which the run takes itself), and every end-to-end
+    metric's ``workloads`` are exactly the cells that report it; a
+    metric with no such list is reported by every cell."""
+    reporting = {}
+    for cell in spec["workloads"]:
+        params = harness.load_json(BENCH, "workloads",
+                                   cell["name"] + ".json")
+        for name in params["reports"]:
+            assert name == "setup_s" or name in end_to_end.METRICS, name
+            reporting.setdefault(name, []).append(cell["name"])
+    cells = [cell["name"] for cell in spec["workloads"]]
+    for metric in spec["end_to_end"]:
+        assert sorted(reporting.get(metric["name"], [])) == \
+            sorted(metric.get("workloads", cells)), metric["name"]
+    assert sorted(reporting) == sorted(m["name"] for m in spec["end_to_end"])
+
+
 # -- a rehearsal run of each driver ----------------------------------------
 
 def _rehearse(cell, extra=()):

@@ -17,7 +17,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
 
-from benchmarks import harness, program_trace  # noqa: E402
+from benchmarks import harness, program_trace, trace_reduce  # noqa: E402
 from benchmarks.readers import (scope_device_ms, scope_roofline,  # noqa: E402
                                 span_ms, span_stat)
 
@@ -336,3 +336,217 @@ def test_new_readers_on_the_fixture_cut_from_the_chips_trace(cell):
             "forward", "backward", "update", "unscoped"))
         assert parts == pytest.approx(whole, rel=0.02)
     assert json.dumps(fixture)      # plain data
+
+
+# -- the one idle-gap labeller, against the rule it replaced -----------------
+#
+# The oracles below are the quadratic loops that ``trace_reduce.reduce``,
+# ``program_trace.idle_gaps`` and ``program_trace.span_table`` ran before
+# they shared ``trace_reduce.label_gaps`` and ``program_trace.nested_ns``:
+# every gap against every span, every span against every span of its
+# thread.  The sweeps have to give the same numbers, letter for letter.
+
+def _old_label(gaps, spans, prefix):
+    labelled = {}
+    for start, end in gaps:
+        best, best_key = "unattributed", None
+        for span in spans:
+            name, s_start, s_dur = span[:3]
+            cover = min(end, s_start + s_dur) - max(start, s_start)
+            if cover <= 0:
+                continue
+            key = (2 * cover >= end - start, -s_dur, cover)
+            if best_key is None or key > best_key:
+                best, best_key = name[len(prefix):], key
+        labelled[best] = labelled.get(best, 0) + (end - start)
+    return labelled
+
+
+def _old_gaps(busy, lo, hi):
+    gaps, cursor = [], lo
+    for start, end in busy + [[hi, hi]]:
+        if start > cursor:
+            gaps.append((cursor, start))
+        cursor = max(cursor, end)
+    return gaps
+
+
+def _ranked(labelled, top=10):
+    return [[name, ns / 1e9] for name, ns in sorted(
+        labelled.items(), key=lambda kv: -kv[1])[:top]]
+
+
+def _old_idle_gaps(extracted):
+    lo, hi = program_trace.window_of(extracted)
+    busy = trace_reduce.merge(
+        (max(start, lo), min(start + duration, hi))
+        for _p, _s, start, duration in extracted["ops"]
+        if start + duration > lo and start < hi)
+    return _ranked(_old_label(_old_gaps(busy, lo, hi), extracted["spans"],
+                              program_trace.SPAN_PREFIX))
+
+
+def _old_covered(span, others):
+    start, end = span[1], span[1] + span[2]
+    inside = [(o[1], o[1] + o[2]) for o in others
+              if o is not span and o[3] == span[3]
+              and o[1] >= start and o[1] + o[2] <= end]
+    return sum(e - s for s, e in trace_reduce.merge(inside))
+
+
+def _old_span_table(extracted):
+    spans = program_trace.spans_in_window(extracted)
+    by_thread = {}
+    for span in spans:
+        by_thread.setdefault(span[3], []).append(span)
+    table = {}
+    for span in spans:
+        count, total, own = table.get(span[0], (0, 0, 0))
+        table[span[0]] = (count + 1, total + span[2], own + span[2]
+                          - _old_covered(span, by_thread[span[3]]))
+    return table
+
+
+def _old_reduce_idle_gaps(extracted):
+    spans = extracted["spans"]
+    window = [s for s in spans if s[0] == trace_reduce.WINDOW_SPAN][0]
+    lo, hi = window[1], window[1] + window[2]
+    first = extracted["devices"][sorted(extracted["devices"])[0]]
+    busy = trace_reduce.merge(
+        (max(start, lo), min(start + duration, hi))
+        for _n, start, duration in first["ops"] or first["modules"]
+        if start + duration > lo and start < hi)
+    others = [s for s in spans if s[0] != trace_reduce.WINDOW_SPAN]
+    return _ranked(_old_label(_old_gaps(busy, lo, hi), others,
+                              trace_reduce.SPAN_PREFIX))
+
+
+NAMES = ["gen/step", "gen/decode_fetch", "gen/prefill_fetch", "gen/admit",
+         "gen/emit", "gen/idle", "unit/trainer", "fused/wait"]
+
+
+def _random_spans(rng, lo, hi, threads=3):
+    """Spans on a few threads: trees of nested spans (children often
+    starting or ending with their parent), copies of a span under
+    another name (equal length, equal cover), spans of no length, and
+    spans that straddle gaps and the window's edges.  Times lie on a
+    coarse grid, so that keys tie often."""
+    spans = []
+
+    def tree(thread, start, end, depth):
+        cursor = start
+        while cursor < end and len(spans) < 400:
+            begin = cursor + 10 * rng.randint(0, 3)
+            length = 10 * rng.randint(0, max((end - begin) // 20, 1))
+            if begin + length > end:
+                break
+            spans.append([rng.choice(NAMES), begin, length, thread, {}])
+            if rng.random() < 0.2:
+                spans.append([rng.choice(NAMES), begin, length, thread, {}])
+            if depth < 3 and length >= 40 and rng.random() < 0.7:
+                tree(thread, begin, begin + length, depth + 1)
+            cursor = begin + length
+
+    for thread in range(threads):
+        tree(thread, lo - 200, hi + 200, 0)
+    for _ in range(rng.randint(0, 20)):
+        begin = 10 * rng.randint((lo - 500) // 10, (hi + 100) // 10)
+        spans.append([rng.choice(NAMES), begin, 10 * rng.randint(0, 60),
+                      rng.randrange(threads), {}])
+    rng.shuffle(spans)
+    return [["veles:" + name, start, length, thread, stats]
+            for name, start, length, thread, stats in spans]
+
+
+def _random_ops(rng, lo, hi):
+    ops, cursor = [], lo - 10 * rng.randint(0, 30)
+    while cursor < hi + 300:
+        length = 10 * rng.randint(1, 25)
+        ops.append(["jit_p", "", cursor, length])
+        cursor += length + 10 * rng.choice([0, 0, rng.randint(1, 40),
+                                            -rng.randint(0, 5)])
+    return ops
+
+
+def _random_trace(seed):
+    import random
+    rng = random.Random(seed)
+    lo, hi = 1000, 1000 + 10 * rng.randint(200, 600)
+    return {"window": [lo, hi], "spans": _random_spans(rng, lo, hi),
+            "ops": _random_ops(rng, lo, hi), "runs": [["jit_p", lo, 10]]}
+
+
+def _as_driver_trace(extracted):
+    """The same intervals in ``trace_reduce.extract``'s form: two
+    chips (the first one's gaps are labelled) and ``bench:`` spans."""
+    lo, hi = extracted["window"]
+    ops = [["fusion", start, length]
+           for _p, _s, start, length in extracted["ops"]]
+    spans = [["bench:" + name[len("veles:"):], start, length]
+             for name, start, length, _t, _s in extracted["spans"]]
+    return {"devices": {"/device:TPU:0": {"modules": [], "ops": ops},
+                        "/device:TPU:1": {"modules": [],
+                                          "ops": ops[::2]}},
+            "spans": spans + [[trace_reduce.WINDOW_SPAN, lo, hi - lo]]}
+
+
+def _report(extracted):
+    out = io.StringIO()
+    program_trace.report(extracted, out)
+    return out.getvalue()
+
+
+def _assert_same_as_the_old_rule(extracted, monkeypatch):
+    assert program_trace.idle_gaps(extracted) == _old_idle_gaps(extracted)
+    assert program_trace.span_table(extracted) == \
+        _old_span_table(extracted)
+    spans = extracted["spans"]
+    waits = [s for s in spans if s[0].endswith("fetch")]
+    assert program_trace.nested_ns(spans, waits) == \
+        [_old_covered(span, waits) for span in spans]
+    text = _report(extracted)
+    with monkeypatch.context() as patch:
+        patch.setattr(program_trace, "idle_gaps", _old_idle_gaps)
+        patch.setattr(program_trace, "span_table", _old_span_table)
+        assert _report(extracted) == text
+
+
+@pytest.mark.parametrize("seed", range(50))
+def test_the_sweeps_are_the_old_rule_on_random_traces(seed, monkeypatch):
+    extracted = _random_trace(seed)
+    assert len(extracted["spans"]) > 20 and program_trace.idle_gaps(
+        extracted)
+    _assert_same_as_the_old_rule(extracted, monkeypatch)
+    driver = _as_driver_trace(extracted)
+    assert trace_reduce.reduce(driver)["idle_gaps"] == \
+        _old_reduce_idle_gaps(driver)
+
+
+@pytest.mark.parametrize("name", sorted(FIXTURES.values()) + [
+    "handmade"])
+def test_the_sweeps_are_the_old_rule_on_the_fixtures(name, monkeypatch):
+    extracted = _handmade() if name == "handmade" else harness.load_json(
+        BENCH, "fixtures", name + ".json")["extracted"]
+    _assert_same_as_the_old_rule(extracted, monkeypatch)
+
+
+@pytest.mark.parametrize("name", ["alexnet_steps", "chat_steps"])
+def test_the_driver_spans_label_as_before_on_the_fixtures(name):
+    extracted = harness.load_json(BENCH, "fixtures",
+                                  name + ".json")["extracted"]
+    got = trace_reduce.reduce(extracted)["idle_gaps"]
+    assert got and got == _old_reduce_idle_gaps(extracted)
+
+
+def test_a_tie_goes_to_the_first_span_listed():
+    gaps = [(10, 20)]
+    spans = [["veles:b", 0, 30], ["veles:a", 0, 30], ["veles:c", 16, 8]]
+    assert trace_reduce.label_gaps(gaps, spans, "veles:") == {"b": 10}
+    assert trace_reduce.label_gaps(gaps, spans[1:], "veles:") == {"a": 10}
+    # a short span under half of the gap loses to a long one over half,
+    # and wins where it is alone; over half, the shorter span wins
+    assert trace_reduce.label_gaps(gaps, spans[2:], "veles:") == {"c": 10}
+    assert trace_reduce.label_gaps(
+        gaps, spans + [["veles:d", 14, 8]], "veles:") == {"d": 10}
+    assert trace_reduce.label_gaps([(40, 50)], spans, "veles:") == \
+        {"unattributed": 10}

@@ -113,7 +113,7 @@ def test_the_cell_is_the_issues_traffic(config):
     assert traffic["arrivals"]["process"] == "poisson"
     assert "shared_prefix" not in traffic
     assert params["lead_in_s"] == 16 and params["verify_sample"] == 6
-    assert params["reports"] == ["gap_p95_ms", "out_tokens_per_s",
+    assert params["reports"] == ["gap_p97_ms", "out_tokens_per_s",
                                  "setup_s"]
     assert params["limits"]["unanswered"] == 0
     assert params["limits"]["compiles_in_window"] == 0
