@@ -659,5 +659,7 @@ def test_session_spans_carry_their_keyword_arguments(program_session):
         ev[4]["active"] for ev in decodes)
     steps = _named(events, "gen/step")
     # 7 tokens in all: two come from the prefills, five from decodes
-    assert sum(ev[4]["emitted"] for ev in steps) == 7
+    assert sum(ev[4]["prefills"] for ev in steps) == 2
+    assert sum(ev[4]["decoded"] for ev in steps) == 5
+    assert not any("emitted" in ev[4] for ev in steps)
     assert sum(ev[4]["n"] for ev in emits) == 5

@@ -160,6 +160,11 @@ class GenerativeScheduler(Logger):
         self.shed_total = 0
         self.decode_steps = 0
         self.decode_slot_steps = 0   # active rows summed over steps
+        #: tokens decoded by a step that first ran one prefill or chunk
+        #: (or two and more): with ``tokens_total``, the share of token
+        #: gaps that waited behind a prefill
+        self.decode_tokens_stalled = 0
+        self.decode_tokens_multi_stalled = 0
         self._admit_counter = 0
         self._req_counter = 0        # GenRequest.req, under _cond
         #: submit → first streamed token (the prefill turnaround +
@@ -181,6 +186,11 @@ class GenerativeScheduler(Logger):
                                lambda: self.tokens_total)
         metrics.register_gauge("gen_batch_fill" + label,
                                self.batch_fill)
+        metrics.register_gauge("gen_decode_tokens_stalled_total" + label,
+                               lambda: self.decode_tokens_stalled)
+        metrics.register_gauge(
+            "gen_decode_tokens_multi_stalled_total" + label,
+            lambda: self.decode_tokens_multi_stalled)
         metrics.register_gauge(
             "gen_ttft_p99_ms" + label,
             lambda: round(self.ttft.percentile(99) * 1e3, 3))
@@ -219,8 +229,10 @@ class GenerativeScheduler(Logger):
         label = '{model="%s"}' % self.name
         gauges = ["gen_queue_depth", "gen_slot_occupancy",
                   "gen_admitted_total", "gen_tokens_total",
-                  "gen_batch_fill", "gen_ttft_p99_ms",
-                  "gen_preemptions_total", "gen_hbm_per_request_bytes"]
+                  "gen_batch_fill", "gen_decode_tokens_stalled_total",
+                  "gen_decode_tokens_multi_stalled_total",
+                  "gen_ttft_p99_ms", "gen_preemptions_total",
+                  "gen_hbm_per_request_bytes"]
         if getattr(self.engine, "kv_mode", "contiguous") == "paged":
             gauges += ["gen_blocks_total", "gen_blocks_free"]
         if getattr(self.engine, "prefix_cache", False):
@@ -249,6 +261,9 @@ class GenerativeScheduler(Logger):
             "admitted_total": self.admitted_total,
             "finished_total": self.finished_total,
             "tokens_total": self.tokens_total,
+            "decode_tokens_stalled": self.decode_tokens_stalled,
+            "decode_tokens_multi_stalled":
+                self.decode_tokens_multi_stalled,
             "preemptions_total": self.engine.preemptions_total,
             "ttft_p99_ms": round(self.ttft.percentile(99) * 1e3, 3),
         }
@@ -557,13 +572,16 @@ class GenerativeScheduler(Logger):
         per pending chunked prefill, preempt the youngest sequence on
         pool exhaustion, then one decode dispatch over the active set.
         Returns the amount of work done — tokens emitted plus chunks
-        fed (0 = idle)."""
+        fed (0 = idle).  The step's span carries ``prefills``, the
+        prefill and chunk dispatches its decode waited behind, and
+        ``decoded``, the tokens that decode emitted."""
         with trace.span("gen", "step", role="server") as span:
-            emitted = self._step()
-            span.set_metadata(emitted=emitted)
+            emitted, prefills, decoded = self._step()
+            span.set_metadata(prefills=prefills, decoded=decoded)
         return emitted
 
     def _step(self):
+        """``(emitted, prefills, decoded)`` of one iteration."""
         emitted = 0
         decode_steps_before = self.decode_steps
         drain = None
@@ -578,7 +596,8 @@ class GenerativeScheduler(Logger):
                 drain.set_result(self._drain_now())
             except Exception as exc:  # noqa: BLE001 - report, don't wedge
                 drain.set_exception(exc)
-            return 1                 # progress, not idle
+            return 1, 0, 0           # progress, not idle
+        prefill_calls = self.engine.prefill_calls
         # adopt shipped pages first: their prefill is already paid
         # for, so a waiting handoff beats a queued prompt to the pool
         while True:
@@ -701,6 +720,7 @@ class GenerativeScheduler(Logger):
                 del self._prefilling[slot]
                 self._active[slot] = request
                 self._emit(request, token)
+        prefills = self.engine.prefill_calls - prefill_calls
         # safety net for the max_seq edge: a saturated slot decodes
         # nothing — route it through the SHARED finish predicate (both
         # kv modes) instead of crashing the batch
@@ -722,9 +742,10 @@ class GenerativeScheduler(Logger):
                     "— pool smaller than one step's working set")
             self._preempt(max(victims, key=lambda r: r.admit_seq))
             emitted += 1                     # progress, not idle
+        decoded = 0
         if self._active:
             if getattr(self.engine, "proposer", None) is not None:
-                emitted += self._spec_decode()
+                decoded = self._spec_decode()
             else:
                 result = self.engine.decode_step()
                 if result is not None:
@@ -739,7 +760,11 @@ class GenerativeScheduler(Logger):
                                 self._active.items()):
                             if active[slot]:
                                 self._emit(request, out[slot])
-                                emitted += 1
+                                decoded += 1
+        if prefills and decoded:
+            self.decode_tokens_stalled += decoded
+            if prefills > 1:
+                self.decode_tokens_multi_stalled += decoded
         from veles_tpu import watch
         if watch.enabled() \
                 and self.decode_steps != decode_steps_before \
@@ -750,7 +775,7 @@ class GenerativeScheduler(Logger):
             # NOBLOCK publish, so a dead dashboard never costs a
             # decode step
             watch.publish("serve", self.watch_snapshot())
-        return emitted
+        return emitted + decoded, prefills, decoded
 
     def run_until_idle(self, max_steps=100000):
         """Pump until queue and slots drain (manual mode)."""
